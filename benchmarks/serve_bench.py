@@ -10,8 +10,8 @@ millions-of-users story in miniature: >= 64 concurrent streams must
 sustain steady-state throughput with ZERO per-request XLA compiles.
 
 Compiles are counted at the same seam the test suite's
-``compile_counter`` fixture uses (``jax._src.compiler.backend_compile``
-— the one funnel below jit / AOT lowering), installed AFTER
+``compile_counter`` fixture uses (``repro.testing.count_compiles`` — the
+one funnel below jit / AOT lowering), installed AFTER
 ``service.warmup()``: any nonzero count means a request re-traced or
 re-compiled something, which is exactly the cliff the envelope-keyed AOT
 executables exist to remove.  Results land in ``BENCH_serve.json``;
@@ -43,6 +43,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit
+from repro.testing import count_compiles
 
 # (case name, batch size, concurrent streams, requests per stream)
 CASES = [
@@ -93,32 +94,6 @@ def _fleet():
     return cfgs
 
 
-class _CompileSpy:
-    """Steady-state compile counter at the suite's ``compile_counter``
-    seam (``jax._src.compiler.backend_compile`` — the one funnel below
-    jit / AOT lowering).  Install AFTER ``service.warmup()``."""
-
-    def __init__(self):
-        self.count = 0
-
-    def __enter__(self):
-        from jax._src import compiler as _compiler
-
-        self._compiler = _compiler
-        self._orig = _compiler.backend_compile
-
-        def spy(*args, **kwargs):
-            self.count += 1
-            return self._orig(*args, **kwargs)
-
-        _compiler.backend_compile = spy
-        return self
-
-    def __exit__(self, *exc):
-        self._compiler.backend_compile = self._orig
-        return False
-
-
 def run_case(name: str, batch: int, streams: int, requests: int) -> dict:
     from repro.serve import ClusteringService
 
@@ -131,7 +106,7 @@ def run_case(name: str, batch: int, streams: int, requests: int) -> dict:
     rngs = [np.random.default_rng(s) for s in range(streams)]
     names = service.designs()
     handles = []
-    with _CompileSpy() as spy:
+    with count_compiles() as spy:
         t0 = time.perf_counter()
         for _ in range(requests):
             for s, rng in enumerate(rngs):
@@ -140,7 +115,7 @@ def run_case(name: str, batch: int, streams: int, requests: int) -> dict:
                 ))
         service.flush()
         elapsed = time.perf_counter() - t0
-    compiles = spy.count
+    compiles = spy.compiles
 
     lat = sorted(h.result().latency_s for h in handles)
     stats = service.stats()
@@ -178,7 +153,7 @@ def run_overload_case() -> dict:
     names = service.designs()
     handles = []
     shed_overloaded = 0
-    with _CompileSpy() as spy:
+    with count_compiles() as spy:
         t0 = time.perf_counter()
         for _ in range(rounds):
             # a burst far above capacity: max_pending < batch, so nothing
@@ -193,7 +168,7 @@ def run_overload_case() -> dict:
                     shed_overloaded += 1
             service.flush()
         elapsed = time.perf_counter() - t0
-    compiles = spy.count
+    compiles = spy.compiles
 
     lat = sorted(h.result().latency_s for h in handles)
     stats = service.stats()
@@ -245,7 +220,7 @@ def run_chaos_case() -> dict:
             ))
         service.flush()
 
-    with _CompileSpy() as spy:
+    with count_compiles() as spy:
         t0 = time.perf_counter()
         # phase 1: the re-fit path is down hard; serving must not be
         with faults.injected("fit_scan_padded", faults.fail_always,
@@ -259,7 +234,7 @@ def run_chaos_case() -> dict:
             drive_round()
             lift_rounds += 1
         elapsed = time.perf_counter() - t0
-    compiles = spy.count
+    compiles = spy.compiles
 
     stats = service.stats()
     assert stats.served == len(handles) and not stats.failed, stats

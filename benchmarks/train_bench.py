@@ -18,24 +18,19 @@ bucketed heterogeneous sweep case (``sweepbkt*``) times the envelope-
 bucketed front-end against the same sweep forced into one global envelope,
 and every padded case records its bucket/shard metadata.
 
-Since ISSUE 7 cold numbers are honest about the persistent compilation
-cache (``backend.compile_cache``): ``--cache fresh`` (the default) points
-the run at a brand-new empty directory so every cold row is a TRUE
-compile — a populated ``REPRO_COMPILE_CACHE`` inherited from the
-environment can no longer masquerade as a cold compile — and each padded
-row records the cache state it was measured under (``compile_cache``
-column, via ``common.cache_state``).  After the in-process run, ``main``
-re-measures the padded cold cases in fresh subprocesses against the
-now-POPULATED cache directory (``--cold-json`` child mode) and merges the
-results as ``warmproc_*`` columns: the warm-process cold start — compile
-once, pay disk reads forever after — must stay within measurement parity
-of the legacy path (>= ``WARMPROC_REGRESSION_MIN``, flagged
-WARMPROC-REGRESSION otherwise).  ``--check`` validates the committed
-floors for CI without re-running the bench.
+Cold numbers are first calls in this one process, measured against the
+persistent compilation cache ``backend.compile_cache()`` places
+(``JAX_COMPILATION_CACHE_DIR`` when set, the checkout's ``.jax_cache``
+otherwise); each padded row records the state that cache was in when the
+run started (``compile_cache`` column, via ``common.cache_state``), so a
+cold row means "compile" only when it says ``fresh``.  Every case runs in
+this process: an accelerator belongs to one process at a time, so the
+bench starts no child that would need it.  ``--check`` validates the
+committed floors for CI without re-running the bench.
 
 Since ISSUE 10 ``main`` activates the device calibration
-(``costmodel.load_or_calibrate``, persisted next to the compile cache so
-cold-json children resolve identical plans) and every padded case records
+(``costmodel.load_or_calibrate``, persisted in the compile cache directory
+so later runs resolve identical plans) and every padded case records
 its resolved ``ExecutionPlan`` plus a plan-vs-constants warm head-to-head
 (``plan_vs_const_speedup``, PLAN-REGRESSION below ``PLAN_REGRESSION_MIN``)
 and the cost model's predicted-vs-measured step-time ratio.  Emits
@@ -54,9 +49,6 @@ from __future__ import annotations
 import argparse
 import json
 import pathlib
-import subprocess
-import sys
-import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -110,19 +102,6 @@ WARM_REGRESSION_MIN = 0.95
 # and the ratio is ~1.0 by construction.
 PLAN_REGRESSION_MIN = 0.95
 
-# Warm-process cold floor: a fresh process against a POPULATED cache
-# deserializes instead of compiling, so the bucketed side must stay near
-# the global-envelope side.  Not 1.0: with equally-populated caches the
-# two sides measure within ~2% of each other in either direction on this
-# host (controls: plan- and constants-chosen executables both cold-start
-# at ~480ms from the same populated dir; the pre-costmodel floor passed
-# at 1.014 — inside the same noise band), so an exact-parity floor flags
-# deserialize jitter, not regressions.  What this floor exists to catch —
-# a cache miss forcing a real recompile — measures 0.3-0.6x, far below
-# it.  Raising it back requires a control like the ones above.
-WARMPROC_REGRESSION_MIN = 0.95
-
-
 def run() -> list:
     rows = []
     rng = np.random.default_rng(0)
@@ -163,28 +142,6 @@ def run() -> list:
     return rows
 
 
-def _cold_row(case, fused_fn, legacy_fn, volleys, cache, side) -> dict:
-    """One cold-measurement row for a ``--cold-json`` child.
-
-    ``side='fused'`` / ``'legacy'`` times ONLY that closure: the first
-    call in a process also pays shared one-time machinery (encode and
-    metric traces, dtype-cast helpers), so timing both sides in one
-    process hands that cost to whichever runs first and skews the ratio —
-    the parent spawns one child per side instead.  ``side='both'`` keeps
-    the single-process (order-skewed) measurement for ad-hoc debugging.
-    """
-    row = {"case": case, "compile_cache": cache}
-    if side in ("fused", "both"):
-        row["cold_us_per_volley"] = time_cold(fused_fn) / volleys
-    if side in ("legacy", "both"):
-        row["cold_legacy_us_per_volley"] = time_cold(legacy_fn) / volleys
-    if side == "both":
-        row["cold_speedup"] = row["cold_legacy_us_per_volley"] / max(
-            row["cold_us_per_volley"], 1e-9
-        )
-    return row
-
-
 # ------------------------------------------------------- padded design sweep
 SWEEP_B = 64  # volleys per epoch
 # heterogeneous candidates sharing one envelope: (q, t_max) per design,
@@ -193,9 +150,7 @@ SWEEP_P = 96
 SWEEP_DESIGNS = [(5, 32), (5, 64), (10, 32), (10, 64)]
 
 
-def run_sweep(
-    cold_only: bool = False, cache: str | None = None, side: str = "both"
-) -> dict:
+def run_sweep(cache: str | None = None) -> dict:
     """Padded heterogeneous design sweep: ONE fit_scan_padded program
     (runtime design operands, one trace for the whole batch) vs the legacy
     per-design loop (one fused fit per design, D separate compilations).
@@ -270,10 +225,6 @@ def run_sweep(
     if cache is None:
         cache = cache_state(backend.compile_cache_dir())
     volleys = EPOCHS * SWEEP_B * d
-    if cold_only:
-        return _cold_row(
-            f"sweep{d}x{SWEEP_P}p", padded, legacy, volleys, cache, side
-        )
     cold_padded_us = time_cold(padded)
     cold_legacy_us = time_cold(legacy)
 
@@ -335,9 +286,7 @@ BKT_P = 96
 BKT_DESIGNS = [(2, 32), (2, 32), (10, 64), (10, 64)]
 
 
-def run_bucketed_sweep(
-    cold_only: bool = False, cache: str | None = None, side: str = "both"
-) -> dict:
+def run_bucketed_sweep(cache: str | None = None) -> dict:
     """Envelope-bucketed heterogeneous sweep (the ISSUE 5 tentpole) vs the
     same sweep forced into one global envelope (waste_cap=inf — the
     pre-bucketing behavior).  Both sides run the full simulator front-end
@@ -365,11 +314,6 @@ def run_bucketed_sweep(
     if cache is None:
         cache = cache_state(backend.compile_cache_dir())
     volleys = EPOCHS * BKT_B * d
-    if cold_only:
-        return _cold_row(
-            f"sweepbkt{d}x{BKT_P}p", bucketed, global_env, volleys, cache,
-            side,
-        )
     cold_bkt_us = time_cold(bucketed)
     cold_glb_us = time_cold(global_env)
 
@@ -446,9 +390,7 @@ def _net_cfg() -> NetworkConfig:
     ), name="bench2layer")
 
 
-def run_network(
-    cold_only: bool = False, cache: str | None = None, side: str = "both"
-) -> dict:
+def run_network(cache: str | None = None) -> dict:
     """Fused per-layer scans (network.fit_greedy) vs the legacy untraced
     per-epoch Python loop they replaced (one vmapped train_step per epoch)."""
     net = _net_cfg()
@@ -503,10 +445,6 @@ def run_network(
     if cache is None:
         cache = cache_state(backend.compile_cache_dir())
     volleys = EPOCHS * NET_B
-    if cold_only:
-        return _cold_row(
-            "net96-4x8-1x5", fused, legacy, volleys, cache, side
-        )
     cold_fused_us = time_cold(fused)
     cold_legacy_us = time_cold(legacy)
 
@@ -577,144 +515,16 @@ def run_network(
     }
 
 
-# the padded cases whose cold floors CI tracks (``--check``): each must
-# hold cold_speedup >= COLD_REGRESSION_MIN against a FRESH cache dir and
-# warmproc_cold_speedup >= 1.0 against the populated one
+# the padded cases whose floors CI tracks (``--check``)
 TRACKED_COLD_CASES = ("sweep4x96p", "sweepbkt4x96p", "net96-4x8-1x5")
-
-
-def _enable_cache(mode: str):
-    """Resolve the ``--cache`` flag into a persistent-cache directory.
-
-    'fresh' (the default) creates a brand-new empty temp dir, so cold
-    rows measure true compiles even when the process inherited a warm
-    ``REPRO_COMPILE_CACHE``; 'off' leaves whatever the environment set up
-    untouched (honest only if that cache is absent or fresh — the rows'
-    ``compile_cache`` column records what it actually was); anything else
-    is used as the directory itself (the ``--cold-json`` children pass
-    the parent's now-populated dir this way).
-    """
-    if mode == "off":
-        return backend.compile_cache_dir()
-    if mode == "fresh":
-        mode = tempfile.mkdtemp(prefix="repro-train-bench-cache-")
-    return backend.compile_cache(mode)
-
-
-def _cold_child(case: str, side: str, cache_dir: str):
-    """One ``--cold-json`` child: cold-start a fresh process, time ONE
-    side of ONE case.  Returns (us_per_volley, cache_label) or None."""
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.train_bench", "--cold-json",
-         "--case", case, "--side", side, "--cache", cache_dir],
-        capture_output=True, text=True, timeout=1800,
-    )
-    if proc.returncode != 0:
-        print(f"cold child ({case}/{side}) failed:\n{proc.stderr[-2000:]}")
-        return None
-    row = json.loads(proc.stdout.strip().splitlines()[-1])[0]
-    us = row.get("cold_us_per_volley", row.get("cold_legacy_us_per_volley"))
-    return us, row["compile_cache"]
-
-
-def _isolated_cold(
-    cases, cache_mode: str, attempts: int, floor: float
-) -> dict[str, dict]:
-    """Measure each case's cold ratio with ONE child process PER SIDE.
-
-    Isolation is the whole point, twice over: measured in one process,
-    the cases contaminate each other (the homogeneous sweep compiles the
-    very global-envelope executable the bucketed case's legacy side then
-    gets for free), and within a case the first side to run pays the
-    shared one-time machinery (encode/metric traces) for both.  So every
-    (case, side) gets a fresh process; ``cache_mode='fresh'`` also gives
-    each child its own empty cache dir (true compile cliff), while a
-    directory path reuses it as-is (the warm-process measurement against
-    a populated cache).  Ambient interference only ever ADDS time, so
-    each side keeps its MINIMUM over up to ``attempts`` children (the
-    ``time_pair`` estimator, across processes) with an early stop once
-    the ratio clears ``floor``.
-    """
-    out: dict[str, dict] = {}
-    for case in cases:
-        if cache_mode not in ("fresh", "off"):
-            # warm phase: one UNTIMED child first to finish populating
-            # the cache — a handful of tiny-op executables only the
-            # child-side code path compiles (the parent ran in-process),
-            # which would otherwise be paid inside whichever side's
-            # timed region happens to run first
-            _cold_child(case, "fused", cache_mode)
-        fused = legacy = None
-        label = None
-        for _ in range(attempts):
-            cdir = (
-                tempfile.mkdtemp(prefix="repro-train-bench-cold-")
-                if cache_mode == "fresh" else cache_mode
-            )
-            got_f = _cold_child(case, "fused", cdir)
-            cdir = (
-                tempfile.mkdtemp(prefix="repro-train-bench-cold-")
-                if cache_mode == "fresh" else cache_mode
-            )
-            got_l = _cold_child(case, "legacy", cdir)
-            if got_f is None or got_l is None:
-                continue
-            fused = got_f[0] if fused is None else min(fused, got_f[0])
-            legacy = got_l[0] if legacy is None else min(legacy, got_l[0])
-            label = got_f[1]
-            if legacy / max(fused, 1e-9) >= floor:
-                break
-        if fused is not None and legacy is not None:
-            out[case] = {
-                "compile_cache": label,
-                "cold_us_per_volley": fused,
-                "cold_legacy_us_per_volley": legacy,
-                "cold_speedup": legacy / max(fused, 1e-9),
-            }
-    return out
-
-
-def _merge_cold(rows: list, cache_dir: str) -> None:
-    """Replace the in-process cold columns with the isolated per-side
-    child measurements and add the ``warmproc_*`` columns measured
-    against the parent's now-populated cache dir — the cost a user
-    actually pays on every run after the first."""
-    tracked = {r["case"]: r for r in rows if "cold_speedup" in r}
-    fresh = _isolated_cold(
-        tracked, "fresh", attempts=2, floor=COLD_REGRESSION_MIN
-    )
-    for case, row in fresh.items():
-        tracked[case].update(
-            compile_cache=row["compile_cache"],
-            cold_us_per_volley=row["cold_us_per_volley"],
-            cold_legacy_us_per_volley=row["cold_legacy_us_per_volley"],
-            cold_speedup=row["cold_speedup"],
-        )
-    # the warmproc ratio sits near 1.0 by construction (both sides just
-    # deserialize), so on a noisy host the min-estimator needs more
-    # attempts than the fresh-cold one; early-stop keeps the extra
-    # attempts free whenever the floor clears
-    warm = _isolated_cold(
-        tracked, cache_dir, attempts=6, floor=WARMPROC_REGRESSION_MIN
-    )
-    for case, row in warm.items():
-        tracked[case].update(
-            warmproc_compile_cache=row["compile_cache"],
-            warmproc_cold_us_per_volley=row["cold_us_per_volley"],
-            warmproc_cold_legacy_us_per_volley=(
-                row["cold_legacy_us_per_volley"]
-            ),
-            warmproc_cold_speedup=row["cold_speedup"],
-        )
 
 
 def check() -> int:
     """Validate the committed ``BENCH_train.json`` floors (CI smoke):
     every tracked padded case must hold warm speedup >=
-    WARM_REGRESSION_MIN, fresh-cache cold speedup >=
-    COLD_REGRESSION_MIN, and populated-cache warm-process
-    cold speedup >= WARMPROC_REGRESSION_MIN.  Returns a nonzero exit
-    status on any miss so the workflow step fails loudly."""
+    WARM_REGRESSION_MIN and fresh-cache cold speedup >=
+    COLD_REGRESSION_MIN.  Returns a nonzero exit status on any miss so
+    the workflow step fails loudly."""
     path = pathlib.Path("BENCH_train.json")
     rows = {r["case"]: r for r in json.loads(path.read_text())}
     failed = 0
@@ -728,8 +538,6 @@ def check() -> int:
             ("warm speedup", r.get("speedup"), WARM_REGRESSION_MIN),
             ("cold speedup (fresh cache)", r.get("cold_speedup"),
              COLD_REGRESSION_MIN),
-            ("warm-process cold speedup (populated cache)",
-             r.get("warmproc_cold_speedup"), WARMPROC_REGRESSION_MIN),
             ("plan-vs-constants warm speedup",
              r.get("plan_vs_const_speedup"), PLAN_REGRESSION_MIN),
         ]
@@ -749,44 +557,20 @@ def check() -> int:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
-        "--cache", default="fresh", metavar="off|fresh|DIR",
-        help="persistent compile cache: 'fresh' (default) = new empty "
-             "temp dir so cold rows are true compiles; 'off' = leave the "
-             "environment's cache config alone; DIR = use that directory",
-    )
-    ap.add_argument(
-        "--cold-json", action="store_true",
-        help="child mode: run ONLY the padded cold first-calls and print "
-             "one JSON line (used for the isolated cold / warm-process "
-             "re-measurements)",
-    )
-    ap.add_argument(
-        "--case", default=None, choices=TRACKED_COLD_CASES,
-        help="with --cold-json: restrict to one padded case, so cases "
-             "cannot warm each other's executables",
-    )
-    ap.add_argument(
-        "--side", default="both", choices=("fused", "legacy", "both"),
-        help="with --cold-json: time only one side of the case, so the "
-             "first side run cannot absorb the shared one-time machinery "
-             "for the other",
-    )
-    ap.add_argument(
         "--check", action="store_true",
         help="validate the committed BENCH_train.json floors and exit",
     )
     args = ap.parse_args(argv)
     if args.check:
         raise SystemExit(check())
-    cache_dir = _enable_cache(args.cache)
+    cache_dir = backend.compile_cache()
     # sample the label ONCE, before anything compiles: it describes the
     # state the run started from, which is what makes cold rows honest
     cache = cache_state(cache_dir)
     # activate the device calibration AFTER the cache dir is resolved: the
-    # calibration persists NEXT TO the compile cache (calibration.json),
-    # so the --cold-json children handed the parent's populated dir load
-    # the SAME profile -> resolve the SAME plans -> hit the SAME AOT keys.
-    # A fresh dir calibrates once (~seconds, before any timed region).
+    # calibration persists NEXT TO the compile cache (calibration.json), so
+    # later runs load the SAME profile -> resolve the SAME plans -> hit the
+    # SAME AOT keys.  A fresh dir calibrates once, before any timed region.
     try:
         prof = costmodel.load_or_calibrate()
         print(
@@ -796,31 +580,12 @@ def main(argv=None) -> None:
         )
     except Exception as e:  # constants fallback is always available
         print(f"device calibration unavailable ({e!r}); constants fallback")
-    if args.cold_json:
-        runners = {
-            "sweep4x96p": run_sweep,
-            "sweepbkt4x96p": run_bucketed_sweep,
-            "net96-4x8-1x5": run_network,
-        }
-        names = [args.case] if args.case else list(runners)
-        cold = [
-            runners[n](cold_only=True, cache=cache, side=args.side)
-            for n in names
-        ]
-        print(json.dumps(cold))
-        return
     if cache_dir:
         print(f"persistent compile cache: {cache_dir} ({cache})")
     rows = run()
     rows.append(run_sweep(cache=cache))
     rows.append(run_bucketed_sweep(cache=cache))
     rows.append(run_network(cache=cache))
-    # the in-process cold columns above are contaminated (earlier cases
-    # warm later cases' shared executables and the jit caches), so when a
-    # cache dir is in play they are REPLACED by per-case isolated child
-    # measurements, and the warm-process columns are added the same way
-    if cache_dir:
-        _merge_cold(rows, cache_dir)
     print("\n# Fused online-STDP training vs legacy per-epoch loop")
     print("| case | backend | fused us/volley | legacy us/volley | speedup | MXU flops/volley |")
     print("|---|---|---|---|---|---|")
@@ -872,20 +637,6 @@ def main(argv=None) -> None:
                 f"({r['plan_us_per_volley']:.1f} vs "
                 f"{r['const_us_per_volley']:.1f} us/volley, "
                 f"plan={r.get('plan')})"
-            )
-    # against a POPULATED persistent cache a fresh process reads its
-    # executables from disk instead of compiling — that cold start must
-    # beat the legacy path outright, or the cache isn't paying its way
-    for r in rows:
-        wp = r.get("warmproc_cold_speedup")
-        if wp is not None and wp < WARMPROC_REGRESSION_MIN:
-            print(
-                f"WARMPROC-REGRESSION: {r['case']} warm-process cold "
-                f"speedup {wp:.2f}x < {WARMPROC_REGRESSION_MIN}x vs "
-                f"legacy with a populated "
-                f"persistent cache ({r['warmproc_cold_us_per_volley']:.1f}"
-                f" vs {r['warmproc_cold_legacy_us_per_volley']:.1f} "
-                f"us/volley)"
             )
 
 

@@ -297,6 +297,18 @@ def lowering_ladder(start: str, cycle_exact: bool = False) -> tuple[str, ...]:
     return rungs + (("cycle",) if cycle_exact else ())
 
 
+def warn_step_down(where: str, lowering: str, error) -> None:
+    """Make one failed ladder rung loud: a ``RuntimeWarning`` naming the
+    rung and its error.  Every caller that walks ``lowering_ladder`` calls
+    this for each rung it leaves, so a kernel the device refuses can never
+    pass as a quiet fallback to a plainer lowering."""
+    warnings.warn(
+        f"{where}: lowering {lowering!r} failed: {error}",
+        RuntimeWarning,
+        stacklevel=3,
+    )
+
+
 # Degraded-mode backoff for the ONLINE re-fit path (the serving analogue
 # of MAX_EVAL_RETRIES): after the k-th consecutive re-fit failure a
 # bucket sits out 2^(k-1) re-fit windows — capped so a long outage never
@@ -458,7 +470,11 @@ def design_mesh(
     k = shards if shards is not None else design_shards(d, volume)
     if k <= 1:
         return None
-    return jax.make_mesh((k,), (DESIGN_AXIS,))
+    # Auto axis: make_mesh's default (Explicit) types shardings into the
+    # arrays, and its rules refuse the gathers the sweep runs on them
+    return jax.make_mesh(
+        (k,), (DESIGN_AXIS,), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
 
 def shard_design_axis(mesh, x: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
@@ -474,32 +490,81 @@ def shard_design_axis(mesh, x: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
     return jax.device_put(x, NamedSharding(mesh, spec))
 
 
+def shard_designs(mesh, fn, arg_axes: Sequence[int], **statics):
+    """``fn(*args, **statics)`` run per device on its slice of the design
+    axis: ``jax.shard_map`` over ``mesh``'s design axis, jitted.
+
+    ``arg_axes[i]`` is the design axis of positional argument ``i``; the
+    output's design axis is 0.  Per-design work needs no collectives, and
+    a Mosaic kernel cannot be partitioned by GSPMD at all, so the sharded
+    sweep maps the padded scans over the mesh explicitly — each device
+    runs the same program on its own designs, bit-identical to the
+    unsharded run.  The wrapper is memoized on (mesh, fn, axes, statics),
+    so repeated buckets reuse one trace (a bounded memo: a sweep touches a
+    handful of envelopes).
+    """
+    return _shard_designs(
+        mesh, fn, tuple(arg_axes), tuple(sorted(statics.items()))
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _shard_designs(mesh, fn, arg_axes: tuple, statics: tuple):
+    kw = dict(statics)
+    in_specs = tuple(
+        PartitionSpec(*((None,) * a + (DESIGN_AXIS,))) for a in arg_axes
+    )
+    return jax.jit(jax.shard_map(
+        lambda *args: fn(*args, **kw), mesh=mesh, in_specs=in_specs,
+        out_specs=PartitionSpec(DESIGN_AXIS),
+        # every operand is design-sharded and nothing is reduced across
+        # devices; pallas_call outputs carry no varying-axes annotation
+        check_vma=False,
+    ))
+
+
 # ----------------------------------------- persistent compilation cache
 # Compilation must be a one-time, cross-process cost: a bench restart, a
 # resumed DSE run, or a service process coming up must never re-pay XLA
-# compilation for an envelope any prior process already compiled.  This
-# is the ONE switch for JAX's persistent compilation cache — nothing else
-# in the tree touches ``jax_compilation_cache_dir``.
-COMPILE_CACHE_ENV = "REPRO_COMPILE_CACHE"
+# compilation for an envelope any prior process already compiled.  ONE
+# directory holds every compile artifact — JAX's persistent cache, the
+# serialized AOT executables (``aot/``) and the device calibration
+# (``calibration.json``) — and it is placed from outside: JAX's own
+# ``JAX_COMPILATION_CACHE_DIR`` when set (enabled at import, below),
+# otherwise one fixed path inside the checkout.  Nothing else in the tree
+# touches ``jax_compilation_cache_dir``.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))),
+    ".jax_cache",
+)
 
 _compile_cache_path: Optional[str] = None
 
 
-def compile_cache(dir) -> Optional[str]:
+def default_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else the checkout's
+    ``.jax_cache`` — the only cache directory entry points enable."""
+    return os.environ.get(CACHE_ENV) or REPO_CACHE_DIR
+
+
+def compile_cache(dir=None) -> Optional[str]:
     """Enable JAX's persistent compilation cache under ``dir``.
 
-    Opt-in, explicit: call this (or export ``REPRO_COMPILE_CACHE=<dir>``,
-    honored at import) to make every XLA compilation land in ``dir`` and
-    every later process that enables the same directory skip straight to
-    the cached executable — zero envelope compiles, bit-identical results
-    (pinned by ``tests/test_aot_cache.py``).  The same directory also
-    holds whole serialized AOT envelope executables (``aot/``, see
-    ``_aot_store``), which additionally skip tracing + lowering — the
-    cost JAX's own cache still pays every process.  ``dse.explore``
-    enables it automatically next to its journal.  The entry-size/
-    compile-time thresholds are dropped to zero because the padded
-    envelope traces are exactly the small-but-slow tail the defaults
-    would skip.
+    Entry points (``chip_smoke.py``, the benches, ``dse.explore``) call
+    this with no argument, which enables ``default_cache_dir()``; an
+    explicit ``dir`` exists for tests that need an isolated cache.  Every
+    XLA compilation then lands in the directory and every later process
+    that enables it skips straight to the cached executable — zero
+    envelope compiles, bit-identical results (pinned by
+    ``tests/test_aot_cache.py``).  The same directory also holds whole
+    serialized AOT envelope executables (``aot/``, see ``_aot_store``),
+    which additionally skip tracing + lowering — the cost JAX's own cache
+    still pays every process.  The entry-size/compile-time thresholds are
+    dropped to zero because the padded envelope traces are exactly the
+    small-but-slow tail the defaults would skip.
 
     The directory is created (and re-created — a deleted cache dir on a
     resumed run is repaired, not fatal) and probed for writability.  An
@@ -510,6 +575,8 @@ def compile_cache(dir) -> Optional[str]:
     options, so a stale directory is merely ignored, never wrong.
     """
     global _compile_cache_path
+    if dir is None:
+        dir = default_cache_dir()
     try:
         path = os.path.abspath(os.fspath(dir))
         os.makedirs(path, exist_ok=True)
@@ -1171,9 +1238,8 @@ def resolve(mode: str, cfg: ColumnConfig, training: bool = False) -> str:
     return "event"
 
 
-# Environment opt-in for the persistent compilation cache: launchers (CI,
-# bench, services) export REPRO_COMPILE_CACHE=<dir> instead of editing
-# code.  Runs at import so every compile in the process lands in the
-# cache, including ones issued before any explicit compile_cache() call.
-if os.environ.get(COMPILE_CACHE_ENV):
-    compile_cache(os.environ[COMPILE_CACHE_ENV])
+# A cache directory placed from outside is the one directory for every
+# compile artifact: enable it at import, so the AOT store and the
+# calibration land beside JAX's own cache from the first compile on.
+if os.environ.get(CACHE_ENV):
+    compile_cache(os.environ[CACHE_ENV])

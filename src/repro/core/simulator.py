@@ -280,7 +280,8 @@ def _sweep_bucket(
     so buckets with equal envelope shapes and member counts share ONE
     compiled executable across sweep calls in this process, and across
     processes once ``backend.compile_cache`` is enabled; sharded buckets
-    keep the jit path so GSPMD sees the design partitioning.
+    run the jitted scans per device on their own designs
+    (``backend.shard_designs``).
 
     Blocking and sharding come from the bucket's ``ExecutionPlan``
     (``backend.execution_plan``; the documented constants when no device
@@ -364,13 +365,13 @@ def _sweep_bucket(
             w0, xs, thresholds, t_maxes, q_actives, **fit_kw
         )
     else:
-        # sharded operands stay on the jit path: GSPMD propagates the
-        # design partitioning at trace time, which a sharding-free AOT
-        # executable would not; the plan rides along as a hashable static
-        w = fused_column.fit_scan_padded(
-            w0, xs, thresholds, t_maxes, q_actives, plan=fit_plan,
-            **fit_kw
-        )
+        # sharded operands: each device runs the jitted scan on its own
+        # designs (shard_map — Mosaic kernels cannot be auto-partitioned);
+        # the plan rides along as a hashable static
+        w = backend_lib.shard_designs(
+            mesh, fused_column.fit_scan_padded, (0, 1, 0, 0, 0),
+            plan=fit_plan, **fit_kw,
+        )(w0, xs, thresholds, t_maxes, q_actives)
     # assignment batches volleys (kernel grid / vmapped blocks); the kernel
     # fires on the integer weight grid, so it is only auto-selected when
     # the trained weights concretely sit on that grid (pure lowering
@@ -389,9 +390,9 @@ def _sweep_bucket(
         )
     else:
         asg = np.asarray(
-            fused_column.assign_padded(
-                w, xs, thresholds, t_maxes, q_actives, **asg_kw
-            )
+            backend_lib.shard_designs(
+                mesh, fused_column.assign_padded, (0, 1, 0, 0, 0), **asg_kw
+            )(w, xs, thresholds, t_maxes, q_actives)
         )
     w_out = [
         jnp.asarray(w[j, : cfgs[i].p, : cfgs[i].q])
@@ -486,6 +487,7 @@ def _eval_bucket_guarded(
             ]
         except Exception as e:  # noqa: BLE001 — the guard IS the feature
             attempts.append((low, repr(e)))
+            backend_lib.warn_step_down("sweep bucket", low, repr(e))
     out = []
     for i in idxs:
         c = cfgs[i]
@@ -513,6 +515,7 @@ def _eval_bucket_guarded(
                 break
             except Exception as e:  # noqa: BLE001
                 d_attempts.append((low, repr(e)))
+                backend_lib.warn_step_down(f"design {i}", low, repr(e))
         if done is None:
             out.append(
                 EvalFailure(

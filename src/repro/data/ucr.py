@@ -63,7 +63,7 @@ class Dataset:
 
 
 def _ucr_root() -> Optional[str]:
-    for cand in (os.environ.get("UCR_ROOT"), "data/UCR", "/root/data/UCR"):
+    for cand in (os.environ.get("UCR_ROOT"), "data/UCR"):
         if cand and os.path.isdir(cand):
             return cand
     return None

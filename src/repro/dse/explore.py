@@ -142,10 +142,10 @@ def explore(
         journal; every completed bucket is published atomically, so a
         killed run loses at most one bucket.  An existing journal
         requires ``resume=True``.  Journaled runs also enable the
-        persistent compilation cache in a ``compile_cache/`` directory
-        next to the journal (unless one is already configured — see
-        ``backend.compile_cache``), so resumed and repeated runs
-        compile zero envelope traces.
+        persistent compilation cache (``backend.compile_cache()``:
+        ``JAX_COMPILATION_CACHE_DIR`` when set, the checkout's
+        ``.jax_cache`` otherwise), so resumed and repeated runs compile
+        zero envelope traces.
       resume: skip candidates already in the journal (scored *and*
         quarantined); the resumed run's frontier is bit-identical to an
         uninterrupted one.
@@ -197,17 +197,15 @@ def explore(
             {"seed": int(seed), "epochs": int(epochs), "search": search},
             resume=resume,
         )
-        # journaled runs are the long-lived ones: default the persistent
-        # compilation cache next to the journal, so a resumed (or merely
-        # repeated) exploration re-pays ZERO envelope compiles.  A deleted
-        # cache dir is recreated (re-enabling our own default repairs it,
-        # even mid-process); an explicit compile_cache() /
-        # REPRO_COMPILE_CACHE choice made earlier wins.
-        default_cache = os.path.join(
-            os.path.dirname(os.path.abspath(jr.path)), "compile_cache"
-        )
-        if backend_lib.compile_cache_dir() in (None, default_cache):
-            backend_lib.compile_cache(default_cache)
+        # journaled runs are the long-lived ones: enable the persistent
+        # compilation cache (``backend.default_cache_dir()``), so a resumed
+        # (or merely repeated) exploration re-pays ZERO envelope compiles.
+        # A deleted cache dir is recreated (re-enabling the default repairs
+        # it, even mid-process); a cache a test enabled explicitly wins.
+        if backend_lib.compile_cache_dir() in (
+            None, os.path.abspath(backend_lib.default_cache_dir())
+        ):
+            backend_lib.compile_cache()
         # a device calibration saved next to the cache (costmodel.calibrate
         # once per host) upgrades every policy seam below from the
         # hand-tuned constants to the roofline plan.  Disk-load only —

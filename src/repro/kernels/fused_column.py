@@ -393,6 +393,11 @@ def design_operands(
 # the value-level helpers here — plain jnp on values, traced into each
 # kernel — so a change to the fire/WTA/STDP semantics lands in every
 # lowering path at once (the cross-lowering bit-identity contract).
+def _lane_iota(n: int) -> jnp.ndarray:
+    """[1, n] f32 lane indices 0..n-1 (Mosaic iotas are integer-only)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(jnp.float32)
+
+
 def _kernel_fire_counts(wi, ti_col, t0, threshold, t_max, *, t_blk, n_planes):
     """Sub-threshold cycle counts of one time block starting at ``t0``.
 
@@ -403,7 +408,7 @@ def _kernel_fire_counts(wi, ti_col, t0, threshold, t_max, *, t_blk, n_planes):
     runtime window ``t_max``.
     """
     q_pad = wi.shape[1]
-    tv = t0 + jax.lax.broadcasted_iota(jnp.float32, (1, t_blk), 1)
+    tv = t0 + _lane_iota(t_blk)
     a = jnp.maximum(tv - ti_col, 0.0)  # [p_pad, t_blk] ramps
     base = jnp.sum(a, axis=0, keepdims=True)  # [1, t_blk]
     acc = jnp.zeros((q_pad, t_blk), jnp.float32)
@@ -467,10 +472,10 @@ def _kernel_stdp(
 
 def _fused_kernel(
     scal_ref,  # [D, N_OPERANDS] f32 SMEM runtime design operands
-    t_ref,  # [1, p_pad]         f32 input volley (silent >= design t_max)
+    t_ref,  # [1, 1, p_pad]      f32 input volley (silent >= design t_max)
     w_ref,  # [1, p_pad, q_pad]  f32 resident weights
     w_out,  # [1, p_pad, q_pad]  f32 updated weights
-    y_out,  # [1, q_pad]         f32 counts accumulator -> winner times
+    y_out,  # [1, 1, q_pad]      f32 counts accumulator -> winner times
     *,
     t_blk: int,
     t_window: int,
@@ -505,10 +510,10 @@ def _fused_kernel(
         y_out[...] = jnp.zeros_like(y_out)
 
     # --- fire: accumulate sub-threshold cycle counts for this time block.
-    ti = t_ref[...].T  # [p_pad, 1] input times down the sublanes
+    ti = t_ref[0].T  # [p_pad, 1] input times down the sublanes
     w = w_ref[0]
     wi = jnp.round(jnp.clip(w, 0.0, float(w_max)))  # integer fire grid
-    y_out[...] += _kernel_fire_counts(
+    y_out[0] += _kernel_fire_counts(
         wi, ti, (i * t_blk).astype(jnp.float32), threshold, t_max,
         t_blk=t_blk, n_planes=n_planes,
     )
@@ -516,14 +521,14 @@ def _fused_kernel(
     # --- WTA + STDP once all time blocks have accumulated.
     @pl.when(i == last)
     def _finalize():
-        counts = y_out[...]  # [1, q_pad]
-        qi = jax.lax.broadcasted_iota(jnp.float32, (1, q_pad), 1)
+        counts = y_out[0]  # [1, q_pad]
+        qi = _lane_iota(q_pad)
         t_fire = jnp.minimum(counts, t_max)
         t_fire = jnp.where(qi < q_live, t_fire, t_max)  # pad neurons silent
         y = _kernel_wta(t_fire, qi, t_max, wta_k=wta_k, t_window=t_window)
-        y_out[...] = y
+        y_out[0] = y
         w_out[0] = _kernel_stdp(
-            w, t_ref[...].T, y, qi, t_max, q_live,
+            w, ti, y, qi, t_max, q_live,
             mu_capture, mu_backoff, mu_search,
             w_max=w_max, stabilize=stabilize,
         )
@@ -572,25 +577,27 @@ def fused_step_pallas_padded(
         w_max=w_max,
         stabilize=stabilize,
     )
+    # volleys and counts ride a unit middle axis, so every block's last
+    # two dims are whole array dims (the Mosaic (8, 128) tiling rule)
     w_new, y = pl.pallas_call(
         kern,
         grid=(d, t_pad // t_blk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, p_pad), lambda di, i: (di, 0)),
+            pl.BlockSpec((1, 1, p_pad), lambda di, i: (di, 0, 0)),
             pl.BlockSpec((1, p_pad, q_pad), lambda di, i: (di, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, p_pad, q_pad), lambda di, i: (di, 0, 0)),
-            pl.BlockSpec((1, q_pad), lambda di, i: (di, 0)),
+            pl.BlockSpec((1, 1, q_pad), lambda di, i: (di, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((d, p_pad, q_pad), jnp.float32),
-            jax.ShapeDtypeStruct((d, q_pad), jnp.float32),
+            jax.ShapeDtypeStruct((d, 1, q_pad), jnp.float32),
         ],
         interpret=interpret,
-    )(operands, t_in, w)
-    return w_new, y
+    )(operands, t_in[:, None, :], w)
+    return w_new, y[:, 0, :]
 
 
 def _fused_block_kernel(
@@ -633,12 +640,11 @@ def _fused_block_kernel(
     mu_backoff = scal_ref[d, 4]
     mu_search = scal_ref[d, 5]
 
-    t_all = t_ref[0]  # [v_blk, p_pad] resident volley block
-    qi = jax.lax.broadcasted_iota(jnp.float32, (1, q_pad), 1)
+    qi = _lane_iota(q_pad)
     n_tb = t_window // t_blk
 
     def volley(vi, w):
-        ti = jax.lax.dynamic_slice_in_dim(t_all, vi, 1, axis=0)  # [1, p_pad]
+        ti = t_ref[0, pl.ds(vi, 1), :]  # [1, p_pad]
         ti_col = ti.T  # [p_pad, 1] input times down the sublanes
         wi = jnp.round(jnp.clip(w, 0.0, float(w_max)))  # integer fire grid
 
@@ -1018,9 +1024,9 @@ def _fit_scan_padded_kernel(
 
 def _fire_block_kernel(
     scal_ref,  # [D, N_OPERANDS] f32 SMEM runtime design operands
-    t_ref,  # [1, 1, p_pad]      f32 one volley (silent >= design t_max)
+    t_ref,  # [1, 1, 1, p_pad]   f32 one volley (silent >= design t_max)
     w_ref,  # [1, p_pad, q_pad]  f32 frozen weights
-    y_out,  # [1, 1, q_pad]      f32 counts accumulator -> firing times
+    y_out,  # [1, 1, 1, q_pad]   f32 counts accumulator -> firing times
     *,
     t_blk: int,
     n_planes: int,
@@ -1048,16 +1054,16 @@ def _fire_block_kernel(
         y_out[...] = jnp.zeros_like(y_out)
 
     wi = jnp.round(jnp.clip(w_ref[0], 0.0, float(w_max)))
-    y_out[0] += _kernel_fire_counts(
-        wi, t_ref[0].T, (i * t_blk).astype(jnp.float32), threshold, t_max,
-        t_blk=t_blk, n_planes=n_planes,
+    y_out[0, 0] += _kernel_fire_counts(
+        wi, t_ref[0, 0].T, (i * t_blk).astype(jnp.float32), threshold,
+        t_max, t_blk=t_blk, n_planes=n_planes,
     )
 
     @pl.when(i == last)
     def _finalize():
-        qi = jax.lax.broadcasted_iota(jnp.float32, (1, q_pad), 1)
-        t_fire = jnp.minimum(y_out[0], t_max)
-        y_out[0] = jnp.where(qi < q_live, t_fire, t_max)
+        qi = _lane_iota(q_pad)
+        t_fire = jnp.minimum(y_out[0, 0], t_max)
+        y_out[0, 0] = jnp.where(qi < q_live, t_fire, t_max)
 
 
 def _ids_from_times(t_fire, t_maxes, q_actives):
@@ -1156,7 +1162,7 @@ def assign_padded(
         )
         xs_k = jnp.swapaxes(
             _pad_volleys_silent(xs, p_pad, t_window), 0, 1
-        )  # [D, N, p_pad]
+        )[:, :, None, :]  # [D, N, 1, p_pad]: unit axis keeps blocks whole
         kern = functools.partial(
             _fire_block_kernel,
             t_blk=t_blk, n_planes=w_max + 1, w_max=w_max,
@@ -1166,18 +1172,20 @@ def assign_padded(
             grid=(d, n, t_pad // t_blk),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, p_pad), lambda di, vi, ti: (di, vi, 0)),
+                pl.BlockSpec(
+                    (1, 1, 1, p_pad), lambda di, vi, ti: (di, vi, 0, 0)
+                ),
                 pl.BlockSpec(
                     (1, p_pad, q_pad), lambda di, vi, ti: (di, 0, 0)
                 ),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, q_pad), lambda di, vi, ti: (di, vi, 0)
+                (1, 1, 1, q_pad), lambda di, vi, ti: (di, vi, 0, 0)
             ),
-            out_shape=jax.ShapeDtypeStruct((d, n, q_pad), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((d, n, 1, q_pad), jnp.float32),
             interpret=lowering == "interpret",
         )(operands, xs_k, w_k)
-        return _ids_from_times(t_fire[:, :, :q_env], t_maxes, q_actives)
+        return _ids_from_times(t_fire[:, :, 0, :q_env], t_maxes, q_actives)
 
     qi = jnp.arange(w.shape[2], dtype=TIME_DTYPE)
     # tail rows are sliced away below, so the valid counts are unused here

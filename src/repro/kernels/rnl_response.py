@@ -64,9 +64,12 @@ def _rnl_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     # A[p, b*t] = relu(t - t_in[b, i]) with (b, t) folded into lanes.
-    tv = t0 + jax.lax.iota(jnp.float32, t_blk)  # [t_blk]
+    # Mosaic iotas are integer-only: build the lane index in i32, cast once
+    tv = t0 + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, t_blk), 2
+    ).astype(jnp.float32)  # [1, 1, t_blk]
     ti = t_in_ref[...].T  # [p_pad, B_blk]
-    a = jnp.maximum(tv[None, None, :] - ti[:, :, None], 0.0)  # [p, B, t]
+    a = jnp.maximum(tv - ti[:, :, None], 0.0)  # [p, B, t]
     a = a.reshape(p_pad, b_blk * t_blk)
 
     base = jnp.sum(a, axis=0, keepdims=True)  # [1, B*t]

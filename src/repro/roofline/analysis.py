@@ -2,10 +2,12 @@
 
 Hardware model: the peaks come from a ``costmodel.DeviceProfile`` — the
 *active* device calibration when one is loaded (``costmodel.calibrate`` /
-``load_profile``), the named ``'tpu-v5e'`` default profile otherwise:
-    PEAK_FLOPS = 197e12   bf16 FLOP/s   (v5e default)
-    HBM_BW     = 819e9    B/s           (v5e default)
-    LINK_BW    = 50e9     B/s per ICI link (v5e default)
+``load_profile``), otherwise the default profile of the device kind in
+question (``costmodel.PROFILES``, keyed by ``device_kind``; an unknown
+kind is an error).  The dry-run artifacts target a TPU v5e:
+    PEAK_FLOPS = 197e12   bf16 FLOP/s
+    HBM_BW     = 819e9    B/s
+    LINK_BW    = 50e9     B/s per ICI link
 
 Terms, in seconds per step (all quantities are PER DEVICE — XLA's
 ``cost_analysis`` reports the per-device SPMD module, and the collective
@@ -35,33 +37,42 @@ from typing import Optional
 
 from repro.roofline import costmodel
 
-# Module-level constants stay as the NAMED DEFAULT profile's numbers
-# (import-time snapshot of costmodel.PROFILES['tpu-v5e']) for callers that
-# want the classic v5e targets; analysis itself resolves peaks per call
-# through peaks(), which prefers the active device calibration.
-_DEFAULT_PROFILE = costmodel.PROFILES["tpu-v5e"]
-PEAK_FLOPS = _DEFAULT_PROFILE.peak_flops
-HBM_BW = _DEFAULT_PROFILE.hbm_bw
-LINK_BW = _DEFAULT_PROFILE.link_bw
+# The device the dry-run artifacts are compiled for; the module constants
+# are its peaks.  Analysis resolves peaks per call through peaks(), which
+# prefers the active device calibration.
+DRYRUN_DEVICE_KIND = costmodel.TPU_V5E
+_DRYRUN_PROFILE = costmodel.default_profile(DRYRUN_DEVICE_KIND)
+PEAK_FLOPS = _DRYRUN_PROFILE.peak_flops
+HBM_BW = _DRYRUN_PROFILE.hbm_bw
+LINK_BW = _DRYRUN_PROFILE.link_bw
 
 RESULTS_DIR = "results/dryrun"
 
 
 def peaks(
     prof: Optional[costmodel.DeviceProfile] = None,
+    device_kind: Optional[str] = None,
 ) -> tuple[float, float, float, str]:
     """``(peak_flops, hbm_bw, link_bw, profile_name)`` for the analysis.
 
     Resolution order: explicit ``prof`` argument, then the active
-    calibration (``costmodel.profile()``), then the ``'tpu-v5e'`` default.
-    A calibrated single-host profile has no measured interconnect, so a
-    zero/unset ``link_bw`` falls back to the v5e ICI number rather than
-    dividing by zero.
+    calibration (``costmodel.profile()``), then the default profile of
+    ``device_kind`` (default: the device this process runs on, as
+    ``jax.devices()[0].device_kind`` reports it).  A device kind with no
+    profile raises ``KeyError``.  A calibrated single-host profile has no
+    measured interconnect, so a zero/unset ``link_bw`` takes the default
+    profile's interconnect for the same device kind.
     """
     p = prof if prof is not None else costmodel.profile()
     if p is None:
-        p = _DEFAULT_PROFILE
-    link = p.link_bw if p.link_bw and p.link_bw > 0 else _DEFAULT_PROFILE.link_bw
+        if device_kind is None:
+            import jax
+
+            device_kind = jax.devices()[0].device_kind
+        p = costmodel.default_profile(device_kind)
+    link = p.link_bw
+    if not link or link <= 0:
+        link = costmodel.default_profile(p.device_kind).link_bw
     return p.peak_flops, p.hbm_bw, link, p.name
 
 
@@ -119,7 +130,9 @@ def analyze_cell(cell: dict) -> RooflineRow:
         "total", 0.0
     )
     n = cell["n_chips"]
-    peak_flops, hbm_bw, link_bw, prof_name = peaks()
+    peak_flops, hbm_bw, link_bw, prof_name = peaks(
+        device_kind=DRYRUN_DEVICE_KIND
+    )
     compute_s = flops / peak_flops
     memory_s = byts / hbm_bw
     collective_s = coll / link_bw

@@ -122,24 +122,40 @@ class DeviceProfile:
         return DeviceProfile(**d)
 
 
-# Named default profiles.  'tpu-v5e' carries the numbers
-# roofline/analysis.py used to hard-code (197 Tf/s bf16, 819 GB/s HBM,
-# 50 GB/s per ICI link) plus the ~16 MB/core VMEM bound; 'host-cpu' is a
-# deliberately conservative generic CPU (runs that want real numbers
-# calibrate).  Neither is ever *active* implicitly — they are reference
-# records and the roofline report's fallback, not a silent policy input.
+# Default profiles, keyed by the ``device_kind`` JAX reports for the
+# device (``jax.devices()[0].device_kind``; a TPU v5e reports
+# 'TPU v5 lite').  The v5e record carries Google Cloud's published v5e
+# peaks (197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI link) plus the
+# ~16 MB/core VMEM bound; 'cpu' is a deliberately conservative generic
+# host (runs that want real numbers calibrate).  Neither is ever *active*
+# implicitly — they are reference records for the roofline report, not a
+# silent policy input.  A device whose kind is not here has no peaks: see
+# ``default_profile``.
+TPU_V5E = "TPU v5 lite"
 PROFILES: dict[str, DeviceProfile] = {
-    "tpu-v5e": DeviceProfile(
-        name="tpu-v5e", platform="tpu", device_kind="TPU v5e",
+    TPU_V5E: DeviceProfile(
+        name="tpu-v5e", platform="tpu", device_kind=TPU_V5E,
         peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
         dispatch_s=5e-6, compile_s=2.0, footprint_bytes=16 * 2**20,
     ),
-    "host-cpu": DeviceProfile(
+    "cpu": DeviceProfile(
         name="host-cpu", platform="cpu", device_kind="cpu",
         peak_flops=5e10, hbm_bw=1e10, link_bw=1e10,
         dispatch_s=3e-5, compile_s=0.05, footprint_bytes=32 * 2**20,
     ),
 }
+
+
+def default_profile(device_kind: str) -> DeviceProfile:
+    """The default profile of ``device_kind``; an unknown device is an
+    error, never silently given another device's peaks."""
+    try:
+        return PROFILES[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peaks for device_kind {device_kind!r} "
+            f"(known: {sorted(PROFILES)})"
+        ) from None
 
 
 # ------------------------------------------------------------ activation
@@ -178,11 +194,10 @@ def override(p: Optional[DeviceProfile]):
 
 
 def calibration_path() -> Optional[str]:
-    """Where the calibration record persists: next to the persistent
-    compilation cache (``backend.compile_cache``), so the two caches
-    travel together (CI caches one directory and gets both).  None when
-    no cache directory is enabled — calibration then lives only in this
-    process."""
+    """Where the calibration record persists: inside the persistent
+    compilation cache directory (``backend.compile_cache``), so every
+    compile artifact lives in one place.  None when no cache directory is
+    enabled — calibration then lives only in this process."""
     from repro.core import backend as backend_lib
 
     root = backend_lib.compile_cache_dir()
@@ -314,45 +329,43 @@ def _probe_fused_eff(p: DeviceProfile) -> float:
     """Anchor the roofline to a REAL fused fit: run one small reference
     envelope warm and return predicted/measured.  Pinned ``v_blk``/
     ``t_blk`` so the probe never consults the (not yet active) plan
-    policy; any failure (instrumented entry points, missing kernels)
-    answers a neutral 1.0 — calibration must never be fatal."""
-    try:
-        import numpy as np
-        import jax
-        import jax.numpy as jnp
-        from repro.core import backend as backend_lib
-        from repro.core.types import TIME_DTYPE
+    policy.  A failing probe raises: a calibration that cannot run the
+    fused fit it calibrates is broken, and a neutral answer would hide
+    that."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from repro.core import backend as backend_lib
+    from repro.core.types import TIME_DTYPE
 
-        d, pp, qp, tw, nb, ep, vb = 2, 64, 8, 64, 32, 1, 2
-        rng = np.random.default_rng(0)
-        xs = jnp.asarray(rng.integers(0, tw, (nb, d, pp)), TIME_DTYPE)
-        thr = jnp.full((d,), float(pp) / 3, jnp.float32)
-        tm = jnp.full((d,), tw, TIME_DTYPE)
-        qa = jnp.full((d,), qp - 2, TIME_DTYPE)
-        kw = dict(
-            t_window=tw, w_max=7, wta_k=1, mu_capture=2.0, mu_backoff=1.0,
-            mu_search=1.0, stabilize=False, response="rnl", epochs=ep,
-            lowering="reference", t_blk=CONST_T_BLK, v_blk=vb,
-        )
+    d, pp, qp, tw, nb, ep, vb = 2, 64, 8, 64, 32, 1, 2
+    rng = np.random.default_rng(0)
+    xs = jnp.asarray(rng.integers(0, tw, (nb, d, pp)), TIME_DTYPE)
+    thr = jnp.full((d,), float(pp) / 3, jnp.float32)
+    tm = jnp.full((d,), tw, TIME_DTYPE)
+    qa = jnp.full((d,), qp - 2, TIME_DTYPE)
+    kw = dict(
+        t_window=tw, w_max=7, wta_k=1, mu_capture=2.0, mu_backoff=1.0,
+        mu_search=1.0, stabilize=False, response="rnl", epochs=ep,
+        lowering="reference", t_blk=CONST_T_BLK, v_blk=vb,
+    )
 
-        def run():
-            w = jnp.asarray(rng.integers(0, 8, (d, pp, qp)), jnp.float32)
-            t0 = time.perf_counter()
-            jax.block_until_ready(backend_lib.fit_padded(w, xs, thr, tm, qa, **kw))
-            return time.perf_counter() - t0
+    def run():
+        w = jnp.asarray(rng.integers(0, 8, (d, pp, qp)), jnp.float32)
+        t0 = time.perf_counter()
+        jax.block_until_ready(backend_lib.fit_padded(w, xs, thr, tm, qa, **kw))
+        return time.perf_counter() - t0
 
-        run()  # compile
-        measured = min(run() for _ in range(3)) / (nb * ep)
-        flops, byts, _ = envelope_cost(
-            d, pp, qp, tw, w_max=7, lowering="reference", t_blk=CONST_T_BLK
-        )
-        predicted = (
-            max(flops / p.peak_flops, byts / p.hbm_bw)
-            + p.dispatch_s / vb
-        )
-        return float(min(max(predicted / max(measured, 1e-9), 0.05), 50.0))
-    except Exception:
-        return 1.0
+    run()  # compile
+    measured = min(run() for _ in range(3)) / (nb * ep)
+    flops, byts, _ = envelope_cost(
+        d, pp, qp, tw, w_max=7, lowering="reference", t_blk=CONST_T_BLK
+    )
+    predicted = (
+        max(flops / p.peak_flops, byts / p.hbm_bw)
+        + p.dispatch_s / vb
+    )
+    return float(min(max(predicted / max(measured, 1e-9), 0.05), 50.0))
 
 
 def _footprint_bound(platform: str) -> float:
@@ -365,7 +378,7 @@ def _footprint_bound(platform: str) -> float:
 
 def calibrate(force: bool = False, persist: bool = True) -> DeviceProfile:
     """Measure this host's peaks, ACTIVATE the record, and persist it
-    next to the compile cache (when one is enabled).
+    in the compile cache directory (when one is enabled).
 
     Idempotent per process: an already-active calibrated profile is
     returned as-is unless ``force``.  The probe suite costs well under a
@@ -377,13 +390,15 @@ def calibrate(force: bool = False, persist: bool = True) -> DeviceProfile:
     if _ACTIVE is not None and _ACTIVE.calibrated and not force:
         return _ACTIVE
     platform = jax.default_backend()
+    kind = jax.devices()[0].device_kind
     p = DeviceProfile(
         name=f"calibrated-{platform}",
         platform=platform,
-        device_kind=jax.devices()[0].device_kind,
+        device_kind=kind,
         peak_flops=_probe_peak_flops(),
         hbm_bw=_probe_hbm_bw(),
-        link_bw=PROFILES["tpu-v5e"].link_bw if platform == "tpu" else 1e10,
+        # no interconnect probe: a TPU takes its kind's published link
+        link_bw=default_profile(kind).link_bw if platform == "tpu" else 1e10,
         dispatch_s=_probe_dispatch_s(),
         compile_s=_probe_compile_s(),
         footprint_bytes=_footprint_bound(platform),
@@ -918,8 +933,8 @@ def plan_is_valid(plan: ExecutionPlan) -> bool:
 
 
 def main(argv=None) -> int:
-    """CLI: calibrate this host and persist the record next to the
-    compile cache (``REPRO_COMPILE_CACHE`` honored at import)."""
+    """CLI: calibrate this host and persist the record in the compile
+    cache directory (``backend.compile_cache()``)."""
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -928,6 +943,9 @@ def main(argv=None) -> int:
         help="re-probe even if a persisted record matches this host",
     )
     args = ap.parse_args(argv)
+    from repro.core import backend as backend_lib
+
+    backend_lib.compile_cache()
     p = load_profile() if not args.force else None
     if p is None:
         p = calibrate(force=args.force)

@@ -145,6 +145,7 @@ class ServeStats:
     refits: int           # committed online re-fits
     refit_failures: int   # re-fit windows where every ladder rung failed
     refit_stalls: int     # rung attempts discarded by the watchdog budget
+    refit_retries: int    # failed rung attempts (raise, stall, non-finite)
     recoveries: int       # degraded buckets that re-fit successfully again
     degraded: int         # buckets currently serving from last-good weights
     stalls: int
@@ -209,8 +210,12 @@ class _Bucket:
         self.q_actives = jnp.asarray([c.q for c in cfgs], TIME_DTYPE)
         c0 = cfgs[0]
         self.fit_lowering = backend_lib.padded_lowering(c0.neuron.response)
+        # the rung the last committed re-fit ran on ('' before the first)
+        self.last_fit_lowering = ""
+        # the whole block decides: one off-grid design makes the integer-
+        # grid kernel a semantic switch for the bucket's shared executable
         self.asg_lowering = backend_lib.assign_lowering(
-            c0.neuron.response, self.w[0]
+            c0.neuron.response, self.w
         )
         self.queue: list[_Request] = []
         self.buffers: list[list[np.ndarray]] = [[] for _ in cfgs]
@@ -433,6 +438,7 @@ class ClusteringService:
         self._refits = 0
         self._refit_failures = 0
         self._refit_stalls = 0
+        self._refit_retries = 0
         self._recoveries = 0
         self._snapshots = 0
         self._replayed = 0
@@ -601,6 +607,9 @@ class ClusteringService:
                 "refit_shape": (
                     self.refit_window, len(b.names), b.envelope[0]
                 ),
+                "fit_lowering": b.fit_lowering,
+                "last_fit_lowering": b.last_fit_lowering,
+                "asg_lowering": b.asg_lowering,
                 "degraded": b.degraded,
                 "cooldown": b.cooldown,
                 "assign_plan": b.asg_plan.meta() if b.asg_plan else None,
@@ -629,6 +638,7 @@ class ClusteringService:
             refits=self._refits,
             refit_failures=self._refit_failures,
             refit_stalls=self._refit_stalls,
+            refit_retries=self._refit_retries,
             recoveries=self._recoveries,
             degraded=sum(1 for b in self._buckets if b.degraded),
             stalls=len(self.monitor.events),
@@ -857,8 +867,13 @@ class ClusteringService:
         t0 = time.perf_counter()
         try:
             ids = self._assign(bucket, self._batch_xs(bucket, reqs))
-        except Exception:
+        except Exception as e:
             self.monitor.stop()
+            warnings.warn(
+                f"assign of bucket {bucket.index} ({bucket.asg_lowering}) "
+                f"failed, isolating its {len(reqs)} request(s): {e!r}",
+                RuntimeWarning,
+            )
             self._isolate(bucket, reqs)
             return
         self.monitor.stop()
@@ -959,6 +974,14 @@ class ClusteringService:
         time over ``refit_budget_s``.  Returns ``(w_new, lowering,
         errors)`` — ``w_new`` is ``None`` when every rung failed."""
         errors: list[str] = []
+
+        def failed(low: str, why: str) -> None:
+            errors.append(f"{low}: {why}")
+            self._refit_retries += 1
+            backend_lib.warn_step_down(
+                f"{label} of bucket {bucket.index}", low, why
+            )
+
         for low in ladder:
             self.monitor.start(label)
             t0 = time.perf_counter()
@@ -966,7 +989,7 @@ class ClusteringService:
                 w_new = self._fit_window(bucket, xs_np, low)
             except Exception as e:
                 self.monitor.stop()
-                errors.append(f"{low}: {e!r}")
+                failed(low, repr(e))
                 continue
             self.monitor.stop()
             dt = time.perf_counter() - t0
@@ -976,13 +999,14 @@ class ClusteringService:
                 and dt > self.refit_budget_s
             ):
                 self._refit_stalls += 1
-                errors.append(
-                    f"{low}: stalled ({dt:.3f}s > refit_budget_s="
-                    f"{self.refit_budget_s:.3f}s) — result discarded"
+                failed(
+                    low,
+                    f"stalled ({dt:.3f}s > refit_budget_s="
+                    f"{self.refit_budget_s:.3f}s) — result discarded",
                 )
                 continue
             if not bool(jnp.isfinite(w_new).all()):
-                errors.append(f"{low}: non-finite weights (poisoned re-fit)")
+                failed(low, "non-finite weights (poisoned re-fit)")
                 continue
             return w_new, low, errors
         return None, None, errors
@@ -990,10 +1014,11 @@ class ClusteringService:
     def _commit_weights(self, bucket: _Bucket, w_new) -> None:
         bucket.w = jnp.asarray(w_new)
         # off the integer grid the assignment lowering stays 'reference'
-        # on every host; re-checking after each commit keeps the kernel
-        # available on TPU should the weights land back on the grid
+        # on every host; re-checking the whole block after each commit
+        # keeps the kernel available on TPU should the weights land back
+        # on the grid
         bucket.asg_lowering = backend_lib.assign_lowering(
-            self._statics["response"], bucket.w[0]
+            self._statics["response"], bucket.w
         )
 
     def _refit(self, bucket: _Bucket, warm: bool = False) -> None:
@@ -1024,6 +1049,7 @@ class ClusteringService:
                 bucket.last_refit_errors = errors
             else:
                 self._commit_weights(bucket, w_new)
+                bucket.last_fit_lowering = _low
                 self._refits += 1
                 self._refit_seq += 1
                 if bucket.degraded:

@@ -19,7 +19,6 @@ is installed it is used untouched.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import random
 import sys
@@ -29,77 +28,31 @@ import zlib
 import pytest
 
 
-class CompileCounter:
-    """Counts real XLA compilations and jit trace-cache growth.
-
-    ``compiles`` / ``names`` record every module that went through
-    ``jax._src.compiler.backend_compile`` — the one funnel below
-    ``jit``/``lower().compile()`` that persistent-cache HITS skip, so it
-    counts true compilation work, not tracing.  ``named(substr)`` filters
-    by HLO module name (e.g. ``'fit_scan_padded'``), which keeps
-    assertions robust against incidental helper modules (conversions,
-    broadcasts) the runtime compiles on the side.  ``expect_traces``
-    pins the *tracing* side via a jitted callable's ``_cache_size()``.
-    """
-
-    def __init__(self):
-        self.compiles = 0
-        self.names: list[str] = []
-
-    def named(self, substr: str) -> int:
-        return sum(1 for n in self.names if substr in n)
-
-    @staticmethod
-    def traces(fn) -> int:
-        return fn._cache_size()
-
-    @contextlib.contextmanager
-    def expect_traces(self, fn, n: int):
-        before = fn._cache_size()
-        yield
-        got = fn._cache_size() - before
-        assert got == n, (
-            f"expected exactly {n} new trace(s) of "
-            f"{getattr(fn, '__name__', fn)}, got {got}"
-        )
-
-
 @pytest.fixture
 def compile_counter(monkeypatch, tmp_path):
-    """Intercept compilation at the jax.jit / AOT lower seam.
+    """Count compilations at JAX's compile funnel
+    (``repro.testing.count_compiles``).
 
     Every trace-count / compile-count assertion in the suite goes through
     this fixture — one seam, one contract.  Both persistence layers — the
     JAX compilation cache AND the serialized-AOT-executable store keyed
     off ``backend.compile_cache_dir()`` — are pointed at a throwaway
     per-test directory for the fixture's lifetime, so counts are
-    deterministic regardless of whether the environment (e.g. CI) runs
-    the suite with a warm ``REPRO_COMPILE_CACHE``.
+    deterministic regardless of whether the environment runs the suite
+    with a warm ``JAX_COMPILATION_CACHE_DIR``.
     """
     import jax
-    from jax._src import compiler as _compiler
     from repro.core import backend as _backend
+    from repro.testing import count_compiles
 
-    counter = CompileCounter()
-    orig = _compiler.backend_compile
-
-    def spy(backend, module, *args, **kwargs):
-        try:
-            name = str(module.operation.attributes["sym_name"])
-        except Exception:
-            name = str(getattr(module, "name", ""))
-        counter.compiles += 1
-        counter.names.append(name)
-        return orig(backend, module, *args, **kwargs)
-
-    monkeypatch.setattr(_compiler, "backend_compile", spy)
     monkeypatch.setattr(
         _backend, "_compile_cache_path", str(tmp_path / "jaxcache")
     )
     prev = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", str(tmp_path / "jaxcache"))
     try:
-        yield counter
+        with count_compiles() as counter:
+            yield counter
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
 

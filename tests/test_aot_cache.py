@@ -150,54 +150,43 @@ def test_equal_envelopes_share_one_executable_but_not_results(
 _CHILD = textwrap.dedent("""
     import json, hashlib, sys
     import numpy as np
-    from jax._src import compiler as _compiler
-
-    counts = {"n": 0, "names": []}
-    _orig = _compiler.backend_compile
-    def _spy(backend, module, *a, **k):
-        counts["n"] += 1
-        try:
-            counts["names"].append(str(module.operation.attributes["sym_name"]))
-        except Exception:
-            counts["names"].append("")
-        return _orig(backend, module, *a, **k)
-    _compiler.backend_compile = _spy
-
     import jax.numpy as jnp
     from repro.core import backend
     from repro.core.types import TIME_DTYPE
+    from repro.testing import count_compiles
 
-    assert backend.compile_cache(sys.argv[1]) is not None
-    rng = np.random.default_rng(7)
-    w = jnp.asarray(rng.integers(0, 8, (2, 27, 3)), jnp.float32)
-    xs = jnp.asarray(rng.integers(0, 18, (5, 2, 27)), TIME_DTYPE)
-    th = jnp.asarray([6.0, 4.0], jnp.float32)
-    tm = jnp.asarray([18, 14], TIME_DTYPE)
-    qa = jnp.asarray([3, 2], TIME_DTYPE)
-    w2 = backend.fit_padded(
-        w, xs, th, tm, qa, t_window=18, w_max=7, wta_k=1, mu_capture=1.0,
-        mu_backoff=1.0, mu_search=1.0, stabilize=False, response="rnl",
-        epochs=2, lowering="reference",
-    )
-    ids = backend.assign_padded(
-        w2, xs, th, tm, qa, t_window=18, wta_k=1, response="rnl",
-        lowering="reference",
-    )
-    print(json.dumps({
-        "compiles": counts["n"],
-        "fit_compiles": sum(1 for n in counts["names"]
-                            if "fit_scan_padded" in n),
-        "digest": hashlib.sha256(
+    with count_compiles() as counts:
+        assert backend.compile_cache(sys.argv[1]) is not None
+        rng = np.random.default_rng(7)
+        w = jnp.asarray(rng.integers(0, 8, (2, 27, 3)), jnp.float32)
+        xs = jnp.asarray(rng.integers(0, 18, (5, 2, 27)), TIME_DTYPE)
+        th = jnp.asarray([6.0, 4.0], jnp.float32)
+        tm = jnp.asarray([18, 14], TIME_DTYPE)
+        qa = jnp.asarray([3, 2], TIME_DTYPE)
+        w2 = backend.fit_padded(
+            w, xs, th, tm, qa, t_window=18, w_max=7, wta_k=1,
+            mu_capture=1.0, mu_backoff=1.0, mu_search=1.0,
+            stabilize=False, response="rnl", epochs=2, lowering="reference",
+        )
+        ids = backend.assign_padded(
+            w2, xs, th, tm, qa, t_window=18, wta_k=1, response="rnl",
+            lowering="reference",
+        )
+        digest = hashlib.sha256(
             np.asarray(w2).tobytes() + np.asarray(ids).tobytes()
-        ).hexdigest(),
+        ).hexdigest()
+    print(json.dumps({
+        "compiles": counts.compiles,
+        "fit_compiles": counts.named("fit_scan_padded"),
+        "digest": digest,
     }))
 """)
 
 
 def _run_child(cache_dir: str) -> dict:
     env = dict(os.environ, PYTHONPATH="src")
-    # the child owns its cache dir; a CI-level cache must not leak in
-    env.pop("REPRO_COMPILE_CACHE", None)
+    # the child owns its cache dir; an outer cache must not leak in
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run(
         [sys.executable, "-c", _CHILD, cache_dir],
         capture_output=True, text=True, cwd=REPO, env=env, timeout=600,

@@ -162,9 +162,11 @@ def test_kernel_failure_degrades_to_reference_bit_identically(monkeypatch):
         fused_column, "fit_scan_padded",
         faults.fail_on_lowering(fused_column.fit_scan_padded, ("mosaic",)),
     )
-    res = simulator.cluster_time_series_many(
-        x, y, cfgs, epochs=2, seed=3, on_error="isolate"
-    )
+    # every rung the sweep leaves is loud, naming the rung and its error
+    with pytest.warns(RuntimeWarning, match="'mosaic' failed.*injected"):
+        res = simulator.cluster_time_series_many(
+            x, y, cfgs, epochs=2, seed=3, on_error="isolate"
+        )
     for i, (a, b) in enumerate(zip(res, clean)):
         assert isinstance(a, simulator.ClusteringResult)
         assert a.lowering == "reference" and a.retries == 1
@@ -461,17 +463,20 @@ def test_journal_torn_line_followed_by_valid_record(tmp_path):
     assert '"cc"' not in raw
 
 
-def test_explore_resume_with_deleted_compile_cache_dir(tmp_path):
-    """Journaled explorations default the persistent compilation cache to
-    ``compile_cache/`` next to the journal.  Resuming with a matching
-    meta-header after that directory vanished (cleaned scratch space)
-    must repair the directory and reproduce the run, never fail."""
+def test_explore_resume_with_deleted_compile_cache_dir(
+    tmp_path, monkeypatch
+):
+    """Journaled explorations enable the persistent compilation cache
+    placed from outside (``JAX_COMPILATION_CACHE_DIR``).  Resuming with a
+    matching meta-header after that directory vanished (cleaned scratch
+    space) must repair the directory and reproduce the run, never fail."""
     import shutil
 
     x, y = _stream(n=10, seed=13)
     space = dse.DesignSpace(q=(2, 3), t_max=(16,))
     path = tmp_path / "dse.jsonl"
     cache_dir = tmp_path / "compile_cache"
+    monkeypatch.setenv(backend.CACHE_ENV, str(cache_dir))
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_path = backend._compile_cache_path
     backend._compile_cache_path = None  # a fresh process picks the default

@@ -481,6 +481,35 @@ def test_refit_watchdog_discards_stalled_attempt(monkeypatch):
     assert np.array_equal(service.weights("d0"), w0)
 
 
+def test_refit_step_down_is_loud_and_counted(monkeypatch):
+    """A re-fit that leaves its first-choice lowering warns with the error,
+    counts the failed rung and records the rung it landed on — a kernel
+    the device refuses never passes as a quiet fallback."""
+    monkeypatch.setattr(backend, "padded_lowering", lambda r: "interpret")
+    monkeypatch.setattr(backend, "assign_lowering", lambda r, w: "reference")
+    service = ClusteringService(
+        _fleet(1), batch_size=4, refit_every=4, refit_window=4, seed=2,
+    )
+    service.warmup()
+    rng = np.random.default_rng(5)
+    with monkeypatch.context() as m:
+        m.setattr(
+            fused_column, "fit_scan_padded",
+            faults.fail_on_lowering(
+                fused_column.fit_scan_padded, ("interpret",)
+            ),
+        )
+        with pytest.warns(RuntimeWarning, match="'interpret' failed"):
+            for s in _stream(rng, 4):
+                service.submit(s, "d0")
+    stats = service.stats()
+    assert stats.refits == 1 and stats.refit_retries == 1
+    assert stats.refit_failures == 0 and not stats.degraded
+    (b,) = service.buckets()
+    assert b["fit_lowering"] == "interpret"
+    assert b["last_fit_lowering"] == "reference"
+
+
 # ------------------------------------------------- seams used by serving
 def test_pad_stream_silent_seam():
     xs = np.arange(12, dtype=np.int32).reshape(2, 2, 3)
