@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
 from repro.core import encoding
@@ -295,110 +296,125 @@ def _sweep_bucket(
     p_env, q_env, t_window = envelope
     db = len(idxs)
     n = enc[idxs[0]].shape[0]
+    with obs.span("sim.bucket", envelope=envelope, designs=db, volleys=n,
+                  lowering=lowering):
+        with obs.span("sim.pad"):
+            # Stack padded volleys [Db, N, p_env] in ONE shot: the members'
+            # encodes are stacked and the whole [Db, N, p] block lands in the
+            # silent-padded buffer with a single set — no per-design
+            # ``.at[i].set`` dispatch chain, O(1) graph nodes however many
+            # designs ride the bucket.  (Designs currently share p — the
+            # encoder pins it — so the stack is uniform; the single set keeps
+            # the p < p_env envelope case working should a future per-design
+            # front-end relax that.)
+            encb = jnp.stack([enc[i] for i in idxs])  # [Db, N, p]
+            xs = jnp.full((db, n, p_env), t_window, TIME_DTYPE)
+            xs = xs.at[:, :, : encb.shape[-1]].set(encb)
+            xs = jnp.swapaxes(xs, 0, 1)  # scan axis first: [N, Db, p_env]
 
-    # Stack padded volleys [Db, N, p_env] in ONE shot: the members' encodes
-    # are stacked and the whole [Db, N, p] block lands in the silent-padded
-    # buffer with a single set — no per-design ``.at[i].set`` dispatch
-    # chain, O(1) graph nodes however many designs ride the bucket.
-    # (Designs currently share p — the encoder pins it — so the stack is
-    # uniform; the single set keeps the p < p_env envelope case working
-    # should a future per-design front-end relax that.)
-    encb = jnp.stack([enc[i] for i in idxs])  # [Db, N, p]
-    xs = jnp.full((db, n, p_env), t_window, TIME_DTYPE)
-    xs = xs.at[:, :, : encb.shape[-1]].set(encb)
-    xs = jnp.swapaxes(xs, 0, 1)  # scan axis leading: [N, Db, p_env]
-
-    # Per-design init draws stay per-(key, shape) — seed semantics — but
-    # the padded stack is assembled host-side and shipped as ONE buffer
-    # instead of a D-deep ``.at[i].set`` graph.
-    w0_np = np.zeros((db, p_env, q_env), np.float32)
-    for j, i in enumerate(idxs):
-        c = cfgs[i]
-        w0_np[j, : c.p, : c.q] = w_init[i]
-    w0 = jnp.asarray(w0_np)
-    thresholds = jnp.asarray(
-        [cfgs[i].neuron.threshold for i in idxs], jnp.float32
-    )
-    t_maxes = jnp.asarray([cfgs[i].t_max for i in idxs], TIME_DTYPE)
-    q_actives = jnp.asarray([cfgs[i].q for i in idxs], TIME_DTYPE)
-
-    # the bucket's execution plan: blocking + sharding for this envelope
-    # (cost model when calibrated, the documented constants otherwise);
-    # returned as metadata so ClusteringResult/DSE journals record WHY
-    fit_plan = backend_lib.execution_plan(
-        "fit", lowering, db, p_env, q_env, t_window, n, epochs,
-        w_max=c0.neuron.w_max, response=c0.neuron.response,
-    )
-
-    # shard the design axis across local devices: per-design work is
-    # independent, so GSPMD splits the jitted scans with no collectives;
-    # mesh=None (single device / indivisible Db) leaves every array put.
-    # The mesh is built from the plan's shard count — ONE policy output,
-    # so the recorded plan and the actual placement cannot disagree.  The
-    # legacy call shape is kept whenever the plan agrees with the default
-    # divisor policy (always, uncalibrated) so tests stubbing
-    # ``design_mesh`` to force the unsharded path keep working.
-    if fit_plan.shards == backend_lib.design_shards(db):
-        mesh = backend_lib.design_mesh(db)
-    else:
-        mesh = backend_lib.design_mesh(db, shards=fit_plan.shards)
-    shards = fit_plan.shards if mesh is not None else 1
-    w0 = backend_lib.shard_design_axis(mesh, w0, axis=0)
-    xs = backend_lib.shard_design_axis(mesh, xs, axis=1)
-    thresholds = backend_lib.shard_design_axis(mesh, thresholds)
-    t_maxes = backend_lib.shard_design_axis(mesh, t_maxes)
-    q_actives = backend_lib.shard_design_axis(mesh, q_actives)
-
-    fit_kw = dict(
-        t_window=t_window, w_max=c0.neuron.w_max, wta_k=c0.wta.k,
-        mu_capture=c0.stdp.mu_capture, mu_backoff=c0.stdp.mu_backoff,
-        mu_search=c0.stdp.mu_search,
-        stabilize=c0.stdp.stabilizer == "half",
-        response=c0.neuron.response, epochs=epochs, lowering=lowering,
-        # v_blk defaults to the central backend.volley_block policy
-    )
-    if mesh is None:
-        # single-device: go through the envelope-keyed AOT executable
-        # cache, so equal-envelope buckets share ONE executable across
-        # sweep calls (and across processes under backend.compile_cache)
-        w = backend_lib.fit_padded(
-            w0, xs, thresholds, t_maxes, q_actives, **fit_kw
-        )
-    else:
-        # sharded operands: each device runs the jitted scan on its own
-        # designs (shard_map — Mosaic kernels cannot be auto-partitioned);
-        # the plan rides along as a hashable static
-        w = backend_lib.shard_designs(
-            mesh, fused_column.fit_scan_padded, (0, 1, 0, 0, 0),
-            plan=fit_plan, **fit_kw,
-        )(w0, xs, thresholds, t_maxes, q_actives)
-    # assignment batches volleys (kernel grid / vmapped blocks); the kernel
-    # fires on the integer weight grid, so it is only auto-selected when
-    # the trained weights concretely sit on that grid (pure lowering
-    # choice) — float weights keep the reference fire on every host.
-    asg_lowering = backend_lib.assign_lowering(c0.neuron.response, w)
-    asg_kw = dict(
-        t_window=t_window, wta_k=c0.wta.k,
-        response=c0.neuron.response, lowering=asg_lowering,
-        w_max=c0.neuron.w_max,
-    )
-    if mesh is None:
-        asg = np.asarray(
-            backend_lib.assign_padded(
-                w, xs, thresholds, t_maxes, q_actives, **asg_kw
+            # Per-design init draws stay per-(key, shape) — seed semantics —
+            # but the padded stack is assembled host-side and shipped as ONE
+            # buffer instead of a D-deep ``.at[i].set`` graph.
+            w0_np = np.zeros((db, p_env, q_env), np.float32)
+            for j, i in enumerate(idxs):
+                c = cfgs[i]
+                w0_np[j, : c.p, : c.q] = w_init[i]
+            w0 = jnp.asarray(w0_np)
+            thresholds = jnp.asarray(
+                [cfgs[i].neuron.threshold for i in idxs], jnp.float32
             )
+            t_maxes = jnp.asarray(
+                [cfgs[i].t_max for i in idxs], TIME_DTYPE
+            )
+            q_actives = jnp.asarray([cfgs[i].q for i in idxs], TIME_DTYPE)
+
+            # the bucket's execution plan: blocking + sharding for this
+            # envelope (cost model when calibrated, the documented constants
+            # otherwise); returned as metadata so ClusteringResult/DSE
+            # journals record WHY
+            fit_plan = backend_lib.execution_plan(
+                "fit", lowering, db, p_env, q_env, t_window, n, epochs,
+                w_max=c0.neuron.w_max, response=c0.neuron.response,
+            )
+
+            # shard the design axis across local devices: per-design work is
+            # independent, so GSPMD splits the jitted scans with no
+            # collectives; mesh=None (single device / indivisible Db) leaves
+            # every array put.  The mesh is built from the plan's shard count
+            # — ONE policy output, so the recorded plan and the actual
+            # placement cannot disagree.  The legacy call shape is kept
+            # whenever the plan agrees with the default divisor policy
+            # (always, uncalibrated) so tests stubbing ``design_mesh`` to
+            # force the unsharded path keep working.
+            if fit_plan.shards == backend_lib.design_shards(db):
+                mesh = backend_lib.design_mesh(db)
+            else:
+                mesh = backend_lib.design_mesh(db, shards=fit_plan.shards)
+            shards = fit_plan.shards if mesh is not None else 1
+            w0 = backend_lib.shard_design_axis(mesh, w0, axis=0)
+            xs = backend_lib.shard_design_axis(mesh, xs, axis=1)
+            thresholds = backend_lib.shard_design_axis(mesh, thresholds)
+            t_maxes = backend_lib.shard_design_axis(mesh, t_maxes)
+            q_actives = backend_lib.shard_design_axis(mesh, q_actives)
+
+        fit_kw = dict(
+            t_window=t_window, w_max=c0.neuron.w_max, wta_k=c0.wta.k,
+            mu_capture=c0.stdp.mu_capture, mu_backoff=c0.stdp.mu_backoff,
+            mu_search=c0.stdp.mu_search,
+            stabilize=c0.stdp.stabilizer == "half",
+            response=c0.neuron.response, epochs=epochs, lowering=lowering,
+            # v_blk defaults to the central backend.volley_block policy
         )
-    else:
-        asg = np.asarray(
-            backend_lib.shard_designs(
-                mesh, fused_column.assign_padded, (0, 1, 0, 0, 0), **asg_kw
-            )(w, xs, thresholds, t_maxes, q_actives)
+        with obs.span("sim.fit"):
+            if mesh is None:
+                # single-device: go through the envelope-keyed AOT
+                # executable cache, so equal-envelope buckets share ONE
+                # executable across sweep calls (and across processes under
+                # backend.compile_cache)
+                w = backend_lib.fit_padded(
+                    w0, xs, thresholds, t_maxes, q_actives, **fit_kw
+                )
+            else:
+                # sharded operands: each device runs the jitted scan on its
+                # own designs (shard_map — Mosaic kernels cannot be
+                # auto-partitioned); the plan rides along as a hashable static
+                w = backend_lib.shard_designs(
+                    mesh, fused_column.fit_scan_padded, (0, 1, 0, 0, 0),
+                    plan=fit_plan, **fit_kw,
+                )(w0, xs, thresholds, t_maxes, q_actives)
+        # assignment batches volleys (kernel grid / vmapped blocks); the
+        # kernel fires on the integer weight grid, so it is only
+        # auto-selected when the trained weights concretely sit on that grid
+        # (pure lowering choice) — float weights keep the reference fire on
+        # every host.  On a kernel host this is the first wait on the fit.
+        with obs.span("sim.lowering"):
+            asg_lowering = backend_lib.assign_lowering(
+                c0.neuron.response, w
+            )
+        asg_kw = dict(
+            t_window=t_window, wta_k=c0.wta.k,
+            response=c0.neuron.response, lowering=asg_lowering,
+            w_max=c0.neuron.w_max,
         )
-    w_out = [
-        jnp.asarray(w[j, : cfgs[i].p, : cfgs[i].q])
-        for j, i in enumerate(idxs)
-    ]
-    return asg, w_out, shards, fit_plan.meta()
+        with obs.span("sim.assign"):
+            if mesh is None:
+                asg = np.asarray(
+                    backend_lib.assign_padded(
+                        w, xs, thresholds, t_maxes, q_actives, **asg_kw
+                    )
+                )
+            else:
+                asg = np.asarray(
+                    backend_lib.shard_designs(
+                        mesh, fused_column.assign_padded, (0, 1, 0, 0, 0),
+                        **asg_kw,
+                    )(w, xs, thresholds, t_maxes, q_actives)
+                )
+        w_out = [
+            jnp.asarray(w[j, : cfgs[i].p, : cfgs[i].q])
+            for j, i in enumerate(idxs)
+        ]
+        return asg, w_out, shards, fit_plan.meta()
 
 
 def _eval_design_solver(
@@ -642,107 +658,110 @@ def cluster_time_series_many(
                 "w_max, STDP and WTA configs"
             )
 
-    x = jnp.asarray(series)
-    if x.shape[0] == 0:
-        raise ValueError(
-            "cluster_time_series_many needs a non-empty stream (got N=0 "
-            "series)"
-        )
-    d = len(cfgs)
-
-    # Encode + init per design BEFORE bucketing: the per-design PRNG key
-    # assignment (and with it every result) is a function of the input
-    # order alone, never of how designs were bucketed.
-    enc = [_encode(x, c, encoder) for c in cfgs]  # D x [N, p]
-    if w_init is None:
-        rng = jax.random.key(seed)
-        rng, init_key = jax.random.split(rng)
-        keys = jax.random.split(init_key, d)
-        w_init = [
-            np.asarray(column_lib.init_params(k, c)["w"])
-            for k, c in zip(keys, cfgs)
-        ]
-    else:
-        if len(w_init) != d:
+    with obs.span("sim.many", designs=len(cfgs)):
+        x = jnp.asarray(series)
+        if x.shape[0] == 0:
             raise ValueError(
-                f"w_init must provide one array per config "
-                f"({len(w_init)} != {d})"
+                "cluster_time_series_many needs a non-empty stream (got N=0 "
+                "series)"
             )
-        w_init = [np.asarray(w, np.float32) for w in w_init]
-        for w, c in zip(w_init, cfgs):
-            if w.shape != (c.p, c.q):
-                raise ValueError(
-                    f"w_init shape {w.shape} != design shape {(c.p, c.q)}"
-                )
+        d = len(cfgs)
 
-    buckets = backend_lib.envelope_buckets(
-        [(c.p, c.q, c.t_max) for c in cfgs],
-        waste_cap=waste_cap, max_bucket=max_bucket,
-        # stream-length hint: lets a calibrated host derive the waste cap
-        # from the compile-vs-recurring-waste break-even (constants cap
-        # otherwise; an explicit waste_cap always wins either way)
-        n_volleys=series.shape[0], epochs=epochs,
-    )
-
-    out: list[Optional[SweepOutcome]] = [None] * d
-    n_buckets = len(buckets)
-    t0 = time.perf_counter()
-    for envelope, idxs in buckets:
-        if monitor is not None:
-            monitor.start()
-        if on_error == "isolate":
-            evals = _eval_bucket_guarded(
-                cfgs, idxs, envelope, enc, w_init, epochs, lowering
-            )
-        else:
-            asg_b, w_b, shards, plan_meta = _sweep_bucket(
-                cfgs, idxs, envelope, enc, w_init, epochs, lowering
-            )
-            evals = [
-                ("ok", asg_b[j], w_b[j], shards, lowering, 0, plan_meta)
-                for j in range(len(idxs))
+        # Encode + init per design BEFORE bucketing: the per-design PRNG key
+        # assignment (and with it every result) is a function of the input
+        # order alone, never of how designs were bucketed.
+        with obs.span("sim.encode"):
+            enc = [_encode(x, c, encoder) for c in cfgs]  # D x [N, p]
+        if w_init is None:
+            rng = jax.random.key(seed)
+            rng, init_key = jax.random.split(rng)
+            keys = jax.random.split(init_key, d)
+            w_init = [
+                np.asarray(column_lib.init_params(k, c)["w"])
+                for k, c in zip(keys, cfgs)
             ]
-        bucket_out: list[SweepOutcome] = []
-        for j, i in enumerate(idxs):
-            ev = evals[j]
-            if isinstance(ev, EvalFailure):
-                out[i] = ev
-                bucket_out.append(ev)
-                continue
-            _, asg_i, w_i, shards_i, low_i, retries_i, plan_i = ev
-            if on_error == "isolate":
-                bad = _design_guard(cfgs[i], asg_i, w_i)
-                if bad is not None:
-                    out[i] = EvalFailure(
-                        index=i, stage=bad[0], error=bad[1],
-                        lowerings=(low_i,), retries=retries_i,
-                    )
-                    bucket_out.append(out[i])
-                    continue
-            ri = float("nan")
-            if labels is not None:
-                ri = float(
-                    rand_index_fn(np.asarray(labels), np.asarray(asg_i))
+        else:
+            if len(w_init) != d:
+                raise ValueError(
+                    f"w_init must provide one array per config "
+                    f"({len(w_init)} != {d})"
                 )
-            res = ClusteringResult(
-                np.asarray(asg_i), ri, {"w": w_i}, 0.0, "pallas", low_i,
-                buckets=n_buckets, shards=shards_i, retries=retries_i,
-                plan=plan_i,
-            )
-            out[i] = res
-            bucket_out.append(res)
-        if monitor is not None:
-            monitor.stop()
-        if bucket_callback is not None:
-            bucket_callback(list(idxs), bucket_out)
-    train_seconds = time.perf_counter() - t0
-    # every result reports the whole sweep's wall time (documented
-    # contract) — patched after the loop so bucket callbacks always see
-    # otherwise-final records
-    for r in out:
-        if isinstance(r, ClusteringResult):
-            r.train_seconds = train_seconds
-    return out
+            w_init = [np.asarray(w, np.float32) for w in w_init]
+            for w, c in zip(w_init, cfgs):
+                if w.shape != (c.p, c.q):
+                    raise ValueError(
+                        f"w_init shape {w.shape} != design shape {(c.p, c.q)}"
+                    )
+
+        buckets = backend_lib.envelope_buckets(
+            [(c.p, c.q, c.t_max) for c in cfgs],
+            waste_cap=waste_cap, max_bucket=max_bucket,
+            # stream-length hint: lets a calibrated host derive the waste cap
+            # from the compile-vs-recurring-waste break-even (constants cap
+            # otherwise; an explicit waste_cap always wins either way)
+            n_volleys=series.shape[0], epochs=epochs,
+        )
+
+        out: list[Optional[SweepOutcome]] = [None] * d
+        n_buckets = len(buckets)
+        t0 = time.perf_counter()
+        for envelope, idxs in buckets:
+            if monitor is not None:
+                monitor.start()
+            if on_error == "isolate":
+                evals = _eval_bucket_guarded(
+                    cfgs, idxs, envelope, enc, w_init, epochs, lowering
+                )
+            else:
+                asg_b, w_b, shards, plan_meta = _sweep_bucket(
+                    cfgs, idxs, envelope, enc, w_init, epochs, lowering
+                )
+                evals = [
+                    ("ok", asg_b[j], w_b[j], shards, lowering, 0, plan_meta)
+                    for j in range(len(idxs))
+                ]
+            bucket_out: list[SweepOutcome] = []
+            with obs.span("sim.score"):
+                for j, i in enumerate(idxs):
+                    ev = evals[j]
+                    if isinstance(ev, EvalFailure):
+                        out[i] = ev
+                        bucket_out.append(ev)
+                        continue
+                    _, asg_i, w_i, shards_i, low_i, retries_i, plan_i = ev
+                    if on_error == "isolate":
+                        bad = _design_guard(cfgs[i], asg_i, w_i)
+                        if bad is not None:
+                            out[i] = EvalFailure(
+                                index=i, stage=bad[0], error=bad[1],
+                                lowerings=(low_i,), retries=retries_i,
+                            )
+                            bucket_out.append(out[i])
+                            continue
+                    ri = float("nan")
+                    if labels is not None:
+                        ri = float(rand_index_fn(
+                            np.asarray(labels), np.asarray(asg_i)
+                        ))
+                    res = ClusteringResult(
+                        np.asarray(asg_i), ri, {"w": w_i}, 0.0, "pallas",
+                        low_i, buckets=n_buckets, shards=shards_i,
+                        retries=retries_i, plan=plan_i,
+                    )
+                    out[i] = res
+                    bucket_out.append(res)
+            if monitor is not None:
+                monitor.stop()
+            if bucket_callback is not None:
+                bucket_callback(list(idxs), bucket_out)
+        train_seconds = time.perf_counter() - t0
+        # every result reports the whole sweep's wall time (documented
+        # contract) — patched after the loop so bucket callbacks always see
+        # otherwise-final records
+        for r in out:
+            if isinstance(r, ClusteringResult):
+                r.train_seconds = train_seconds
+        return out
 
 
 # --------------------------------------------------- multi-layer networks

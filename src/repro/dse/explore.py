@@ -29,6 +29,7 @@ from typing import Optional, Union
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
 from repro.core import simulator
@@ -180,208 +181,220 @@ def explore(
     else:
         raise ValueError(f"unknown search: {search!r} (grid | random)")
 
-    series = np.asarray(series)
-    n_cand = len(candidates)
-    cfgs_all = [candidate_config(c, series.shape[1]) for c in candidates]
-    fps = [
-        journal_lib.candidate_fingerprint(cfg, c.encoder, seed, epochs)
-        for cfg, c in zip(cfgs_all, candidates)
-    ]
-
-    jr = journal
-    if jr is not None and not isinstance(jr, journal_lib.Journal):
-        jr = journal_lib.Journal(jr)
-    restored: dict = {}
-    if jr is not None:
-        restored = jr.begin(
-            {"seed": int(seed), "epochs": int(epochs), "search": search},
-            resume=resume,
-        )
-        # journaled runs are the long-lived ones: enable the persistent
-        # compilation cache (``backend.default_cache_dir()``), so a resumed
-        # (or merely repeated) exploration re-pays ZERO envelope compiles.
-        # A deleted cache dir is recreated (re-enabling the default repairs
-        # it, even mid-process); a cache a test enabled explicitly wins.
-        if backend_lib.compile_cache_dir() in (
-            None, os.path.abspath(backend_lib.default_cache_dir())
-        ):
-            backend_lib.compile_cache()
-        # a device calibration saved next to the cache (costmodel.calibrate
-        # once per host) upgrades every policy seam below from the
-        # hand-tuned constants to the roofline plan.  Disk-load only —
-        # exploration never probes the device itself, so an uncalibrated
-        # host just keeps the constants fallback.
-        try:
-            costmodel.load_profile()
-        except Exception:
-            pass
-    mon = monitor if monitor is not None else StepMonitor(
-        threshold=4.0, warmup=3
-    )
-
-    points: list[Optional[DesignPoint]] = [None] * n_cand
-    failures: list[dict] = []
-    resumed = 0
-    pending: list[int] = []
-    for i, (cand, cfg, fp) in enumerate(zip(candidates, cfgs_all, fps)):
-        rec = restored.get(fp)
-        if rec is None:
-            pending.append(i)
-            continue
-        resumed += 1
-        if rec["kind"] == "point":
-            points[i] = DesignPoint(
-                index=i,
-                cfg=cfg,
-                encoder=cand.encoder,
-                rand_index=float(rec["rand_index"]),
-                synapses=int(rec["synapses"]),
-                area_um2=float(rec["area_um2"]),
-                leakage_uw=float(rec["leakage_uw"]),
-                params={"w": np.asarray(rec["w"], np.float32)},
-                lowering=rec.get("lowering", ""),
-                buckets=int(rec.get("buckets", 1)),
-                shards=int(rec.get("shards", 1)),
-                fingerprint=fp,
-                retries=int(rec.get("retries", 0)),
-                plan=rec.get("plan"),
-            )
-        else:
-            failures.append(
-                {
-                    "index": i,
-                    "encoder": cand.encoder,
-                    "stage": rec.get("stage", ""),
-                    "error": rec.get("error", ""),
-                    "lowerings": list(rec.get("lowerings", ())),
-                    "retries": int(rec.get("retries", 0)),
-                    "restored": True,
-                }
-            )
-
-    # init weights keyed per CANDIDATE index (fold_in), not per sweep
-    # position: a resumed partial sweep hands every design the same init
-    # the full sweep would have, so resume is bit-identical
-    _, init_key = jax.random.split(jax.random.key(seed))
-
-    t0 = time.perf_counter()
-    for encoder in dict.fromkeys(candidates[i].encoder for i in pending):
-        idxs = [i for i in pending if candidates[i].encoder == encoder]
-        cfgs = [cfgs_all[i] for i in idxs]
-        w_init = [
-            np.asarray(
-                column_lib.init_params(
-                    jax.random.fold_in(init_key, i), cfgs_all[i]
-                )["w"]
-            )
-            for i in idxs
+    with obs.span("dse.explore", candidates=len(candidates)):
+        series = np.asarray(series)
+        n_cand = len(candidates)
+        cfgs_all = [candidate_config(c, series.shape[1]) for c in candidates]
+        fps = [
+            journal_lib.candidate_fingerprint(cfg, c.encoder, seed, epochs)
+            for cfg, c in zip(cfgs_all, candidates)
         ]
 
-        def on_bucket(local_idxs, results, idxs=idxs, encoder=encoder):
-            recs = []
-            for li, r in zip(local_idxs, results):
-                gi = idxs[li]
-                if isinstance(r, simulator.EvalFailure):
-                    f = {
-                        "index": gi,
-                        "encoder": encoder,
-                        "stage": r.stage,
-                        "error": r.error,
-                        "lowerings": list(r.lowerings),
-                        "retries": r.retries,
-                    }
-                    failures.append({**f, "restored": False})
-                    recs.append({"kind": "failure", "fp": fps[gi], **f})
-                    continue
-                syn = cfgs_all[gi].synapse_count
-                p = DesignPoint(
-                    index=gi,
-                    cfg=cfgs_all[gi],
-                    encoder=encoder,
-                    rand_index=r.rand_index,
-                    synapses=syn,
-                    area_um2=float(forecaster.area_um2(syn)),
-                    leakage_uw=float(forecaster.leakage_uw(syn)),
-                    params=r.params,
-                    lowering=r.lowering,
-                    buckets=r.buckets,
-                    shards=r.shards,
-                    fingerprint=fps[gi],
-                    retries=r.retries,
-                    plan=r.plan,
+        jr = journal
+        if jr is not None and not isinstance(jr, journal_lib.Journal):
+            jr = journal_lib.Journal(jr)
+        restored: dict = {}
+        if jr is not None:
+            restored = jr.begin(
+                {"seed": int(seed), "epochs": int(epochs), "search": search},
+                resume=resume,
+            )
+            # journaled runs are the long-lived ones: enable the persistent
+            # compilation cache (``backend.default_cache_dir()``), so a resumed
+            # (or merely repeated) exploration re-pays ZERO envelope compiles.
+            # A deleted cache dir is recreated (re-enabling the default repairs
+            # it, even mid-process); a cache a test enabled explicitly wins.
+            if backend_lib.compile_cache_dir() in (
+                None, os.path.abspath(backend_lib.default_cache_dir())
+            ):
+                backend_lib.compile_cache()
+            # a device calibration saved next to the cache (costmodel.calibrate
+            # once per host) upgrades every policy seam below from the
+            # hand-tuned constants to the roofline plan.  Disk-load only —
+            # exploration never probes the device itself, so an uncalibrated
+            # host just keeps the constants fallback.
+            try:
+                costmodel.load_profile()
+            except Exception:
+                pass
+        mon = monitor if monitor is not None else StepMonitor(
+            threshold=4.0, warmup=3
+        )
+
+        points: list[Optional[DesignPoint]] = [None] * n_cand
+        failures: list[dict] = []
+        resumed = 0
+        pending: list[int] = []
+        for i, (cand, cfg, fp) in enumerate(zip(candidates, cfgs_all, fps)):
+            rec = restored.get(fp)
+            if rec is None:
+                pending.append(i)
+                continue
+            resumed += 1
+            if rec["kind"] == "point":
+                points[i] = DesignPoint(
+                    index=i,
+                    cfg=cfg,
+                    encoder=cand.encoder,
+                    rand_index=float(rec["rand_index"]),
+                    synapses=int(rec["synapses"]),
+                    area_um2=float(rec["area_um2"]),
+                    leakage_uw=float(rec["leakage_uw"]),
+                    params={"w": np.asarray(rec["w"], np.float32)},
+                    lowering=rec.get("lowering", ""),
+                    buckets=int(rec.get("buckets", 1)),
+                    shards=int(rec.get("shards", 1)),
+                    fingerprint=fp,
+                    retries=int(rec.get("retries", 0)),
+                    plan=rec.get("plan"),
                 )
-                points[gi] = p
-                recs.append(
+            else:
+                failures.append(
                     {
-                        "kind": "point",
-                        "fp": fps[gi],
-                        "index": gi,
-                        "encoder": encoder,
-                        "cand": dataclasses.asdict(candidates[gi]),
-                        "rand_index": p.rand_index,
-                        "synapses": p.synapses,
-                        "area_um2": p.area_um2,
-                        "leakage_uw": p.leakage_uw,
-                        "lowering": p.lowering,
-                        "buckets": p.buckets,
-                        "shards": p.shards,
-                        "retries": p.retries,
-                        "plan": p.plan,
-                        "w": np.asarray(r.params["w"], np.float32).tolist(),
+                        "index": i,
+                        "encoder": cand.encoder,
+                        "stage": rec.get("stage", ""),
+                        "error": rec.get("error", ""),
+                        "lowerings": list(rec.get("lowerings", ())),
+                        "retries": int(rec.get("retries", 0)),
+                        "restored": True,
                     }
                 )
-            if jr is not None:
-                jr.append(recs)
 
-        simulator.cluster_time_series_many(
-            series, labels, cfgs, epochs=epochs, seed=seed, encoder=encoder,
-            waste_cap=waste_cap, max_bucket=max_bucket, on_error=on_error,
-            w_init=w_init, bucket_callback=on_bucket, monitor=mon,
-        )
-    seconds = time.perf_counter() - t0
+        # init weights keyed per CANDIDATE index (fold_in), not per sweep
+        # position: a resumed partial sweep hands every design the same init
+        # the full sweep would have, so resume is bit-identical
+        _, init_key = jax.random.split(jax.random.key(seed))
 
-    done = [p for p in points if p is not None]
-    encoders = list(dict.fromkeys(c.encoder for c in candidates))
-    lowering_by_encoder = {
-        e: ",".join(
-            sorted({p.lowering for p in done if p.encoder == e and p.lowering})
+        t0 = time.perf_counter()
+        for encoder in dict.fromkeys(candidates[i].encoder for i in pending):
+            idxs = [i for i in pending if candidates[i].encoder == encoder]
+            cfgs = [cfgs_all[i] for i in idxs]
+            with obs.span("dse.init"):
+                w_init = [
+                    np.asarray(
+                        column_lib.init_params(
+                            jax.random.fold_in(init_key, i), cfgs_all[i]
+                        )["w"]
+                    )
+                    for i in idxs
+                ]
+
+            def on_bucket(local_idxs, results, idxs=idxs, encoder=encoder):
+                with obs.span("dse.record"):
+                    recs = []
+                    for li, r in zip(local_idxs, results):
+                        gi = idxs[li]
+                        if isinstance(r, simulator.EvalFailure):
+                            f = {
+                                "index": gi,
+                                "encoder": encoder,
+                                "stage": r.stage,
+                                "error": r.error,
+                                "lowerings": list(r.lowerings),
+                                "retries": r.retries,
+                            }
+                            failures.append({**f, "restored": False})
+                            recs.append(
+                                {"kind": "failure", "fp": fps[gi], **f}
+                            )
+                            continue
+                        syn = cfgs_all[gi].synapse_count
+                        p = DesignPoint(
+                            index=gi,
+                            cfg=cfgs_all[gi],
+                            encoder=encoder,
+                            rand_index=r.rand_index,
+                            synapses=syn,
+                            area_um2=float(forecaster.area_um2(syn)),
+                            leakage_uw=float(forecaster.leakage_uw(syn)),
+                            params=r.params,
+                            lowering=r.lowering,
+                            buckets=r.buckets,
+                            shards=r.shards,
+                            fingerprint=fps[gi],
+                            retries=r.retries,
+                            plan=r.plan,
+                        )
+                        points[gi] = p
+                        recs.append(
+                            {
+                                "kind": "point",
+                                "fp": fps[gi],
+                                "index": gi,
+                                "encoder": encoder,
+                                "cand": dataclasses.asdict(candidates[gi]),
+                                "rand_index": p.rand_index,
+                                "synapses": p.synapses,
+                                "area_um2": p.area_um2,
+                                "leakage_uw": p.leakage_uw,
+                                "lowering": p.lowering,
+                                "buckets": p.buckets,
+                                "shards": p.shards,
+                                "retries": p.retries,
+                                "plan": p.plan,
+                                "w": np.asarray(
+                                    r.params["w"], np.float32
+                                ).tolist(),
+                            }
+                        )
+                    if jr is not None:
+                        jr.append(recs)
+
+            simulator.cluster_time_series_many(
+                series, labels, cfgs, epochs=epochs, seed=seed,
+                encoder=encoder, waste_cap=waste_cap, max_bucket=max_bucket,
+                on_error=on_error, w_init=w_init, bucket_callback=on_bucket,
+                monitor=mon,
+            )
+        seconds = time.perf_counter() - t0
+
+        done = [p for p in points if p is not None]
+        encoders = list(dict.fromkeys(c.encoder for c in candidates))
+        lowering_by_encoder = {
+            e: ",".join(
+                sorted({
+                    p.lowering for p in done if p.encoder == e and p.lowering
+                })
+            )
+            for e in encoders
+            if any(p.encoder == e for p in done)
+        }
+        buckets_by_encoder = {
+            e: max(p.buckets for p in done if p.encoder == e)
+            for e in encoders
+            if any(p.encoder == e for p in done)
+        }
+        with obs.span("dse.pareto"):
+            pareto = pareto_front(done)
+        return DSEResult(
+            points=done,
+            pareto=pareto,
+            seconds=seconds,
+            meta={
+                "search": search,
+                "candidates": len(done),
+                "buckets": buckets_by_encoder,
+                "shards": max((p.shards for p in done), default=1),
+                "lowering": lowering_by_encoder,
+                "epochs": epochs,
+                "seed": seed,
+                "on_error": on_error,
+                "failures": failures,
+                "quarantined": len(failures),
+                "retries": (
+                    sum(p.retries for p in done)
+                    + sum(f["retries"] for f in failures)
+                ),
+                "fallbacks": sum(1 for p in done if p.retries > 0),
+                "stalls": [dataclasses.asdict(ev) for ev in mon.events],
+                "resumed": resumed,
+                "journal": jr.path if jr is not None else None,
+                # '' = constants fallback; otherwise the calibrated
+                # DeviceProfile whose cost model chose every bucket's blocking
+                "profile": getattr(costmodel.profile(), "name", ""),
+            },
         )
-        for e in encoders
-        if any(p.encoder == e for p in done)
-    }
-    buckets_by_encoder = {
-        e: max(p.buckets for p in done if p.encoder == e)
-        for e in encoders
-        if any(p.encoder == e for p in done)
-    }
-    return DSEResult(
-        points=done,
-        pareto=pareto_front(done),
-        seconds=seconds,
-        meta={
-            "search": search,
-            "candidates": len(done),
-            "buckets": buckets_by_encoder,
-            "shards": max((p.shards for p in done), default=1),
-            "lowering": lowering_by_encoder,
-            "epochs": epochs,
-            "seed": seed,
-            "on_error": on_error,
-            "failures": failures,
-            "quarantined": len(failures),
-            "retries": (
-                sum(p.retries for p in done)
-                + sum(f["retries"] for f in failures)
-            ),
-            "fallbacks": sum(1 for p in done if p.retries > 0),
-            "stalls": [dataclasses.asdict(ev) for ev in mon.events],
-            "resumed": resumed,
-            "journal": jr.path if jr is not None else None,
-            # '' = constants fallback; otherwise the calibrated
-            # DeviceProfile whose cost model chose every bucket's blocking
-            "profile": getattr(costmodel.profile(), "name", ""),
-        },
-    )
 
 
 def summarize(result: DSEResult) -> str:

@@ -596,6 +596,7 @@ def fused_step_pallas_padded(
             jax.ShapeDtypeStruct((d, 1, q_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_column_step",
     )(operands, t_in[:, None, :], w)
     return w_new, y[:, 0, :]
 
@@ -728,6 +729,7 @@ def fused_block_pallas_padded(
         out_specs=pl.BlockSpec((1, p_pad, q_pad), lambda di: (di, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((d, p_pad, q_pad), jnp.float32),
         interpret=interpret,
+        name="fit_block",
     )(operands, n_valid.astype(TIME_DTYPE), t_in, w)
 
 
@@ -928,49 +930,52 @@ def fit_scan_padded(
         from repro.core import backend  # late: backend imports this module
 
         v_blk = backend.volley_block(lowering, xs.shape[0], d=w.shape[0])
-    if lowering != "reference":
-        if response not in fire_responses(lowering):
-            raise ValueError(
-                f"the padded kernel lowering supports response "
-                f"{fire_responses(lowering)}, got {response!r}; use "
-                "lowering='reference'"
+    # a stable name for the fit's operations in profiles
+    with jax.named_scope("fit_scan_padded"):
+        if lowering != "reference":
+            if response not in fire_responses(lowering):
+                raise ValueError(
+                    f"the padded kernel lowering supports response "
+                    f"{fire_responses(lowering)}, got {response!r}; use "
+                    "lowering='reference'"
+                )
+            return _fit_scan_padded_kernel(
+                w, xs, thresholds, t_maxes, q_actives,
+                t_window, w_max, wta_k, mu_capture, mu_backoff, mu_search,
+                stabilize, epochs, lowering, t_blk, v_blk,
             )
-        return _fit_scan_padded_kernel(
-            w, xs, thresholds, t_maxes, q_actives,
-            t_window, w_max, wta_k, mu_capture, mu_backoff, mu_search,
-            stabilize, epochs, lowering, t_blk, v_blk,
+
+        # [S, v_blk, D, p]
+        xsb, n_valid = _pad_volley_blocks(xs, v_blk, t_window)
+        kw = dict(
+            t_window=t_window, w_max=w_max, wta_k=wta_k,
+            mu_capture=mu_capture, mu_backoff=mu_backoff,
+            mu_search=mu_search, stabilize=stabilize, response=response,
         )
 
-    xsb, n_valid = _pad_volley_blocks(xs, v_blk, t_window)  # [S, v_blk, D, p]
-    kw = dict(
-        t_window=t_window, w_max=w_max, wta_k=wta_k, mu_capture=mu_capture,
-        mu_backoff=mu_backoff, mu_search=mu_search, stabilize=stabilize,
-        response=response,
-    )
+        def block(wc, inp):  # wc: [D, p, q]; xt_blk: [v_blk, D, p]
+            xt_blk, nv = inp
+            # the input-side step transient of the whole block at once — the
+            # reference analogue of the kernel's VMEM-resident volley block:
+            # only the cumulative weight planes, one GEMM and the plane delays
+            # stay inside the sequential (unrolled) loop
+            s = _masked_steps(
+                xt_blk, t_maxes[None, :, None], t_window
+            )  # [v_blk, D, p, T]
+            for i in range(v_blk):  # static unroll: one fused XLA body
+                valid = i < nv  # tail volleys fold nothing
+                wc = jax.vmap(
+                    lambda wd, sd, xd, th, tm, qa: _block_step_ref(
+                        wd, sd, xd, th, tm, qa, valid=valid, **kw
+                    )
+                )(wc, s[i], xt_blk[i], thresholds, t_maxes, q_actives)
+            return wc, None
 
-    def block(wc, inp):  # wc: [D, p, q]; xt_blk: [v_blk, D, p]
-        xt_blk, nv = inp
-        # the input-side step transient of the whole block at once — the
-        # reference analogue of the kernel's VMEM-resident volley block:
-        # only the cumulative weight planes, one GEMM and the plane delays
-        # stay inside the sequential (unrolled) loop
-        s = _masked_steps(
-            xt_blk, t_maxes[None, :, None], t_window
-        )  # [v_blk, D, p, T]
-        for i in range(v_blk):  # static unroll: one fused XLA body
-            valid = i < nv  # tail volleys fold nothing
-            wc = jax.vmap(
-                lambda wd, sd, xd, th, tm, qa: _block_step_ref(
-                    wd, sd, xd, th, tm, qa, valid=valid, **kw
-                )
-            )(wc, s[i], xt_blk[i], thresholds, t_maxes, q_actives)
-        return wc, None
+        def epoch(wc, _):
+            return jax.lax.scan(block, wc, (xsb, n_valid))
 
-    def epoch(wc, _):
-        return jax.lax.scan(block, wc, (xsb, n_valid))
-
-    w, _ = jax.lax.scan(epoch, w, None, length=epochs)
-    return w
+        w, _ = jax.lax.scan(epoch, w, None, length=epochs)
+        return w
 
 
 def _fit_scan_padded_kernel(
@@ -1135,81 +1140,86 @@ def assign_padded(
         from repro.core import backend  # late: backend imports this module
 
         v_blk = backend.volley_block(lowering, xs.shape[0])
-    n = xs.shape[0]
-    if lowering != "reference":
-        if response not in fire_responses(lowering):
-            raise ValueError(
-                f"the padded kernel lowering supports response "
-                f"{fire_responses(lowering)}, got {response!r}; use "
-                "lowering='reference'"
+    # a stable name for the assignment's operations in profiles
+    with jax.named_scope("assign_padded"):
+        n = xs.shape[0]
+        if lowering != "reference":
+            if response not in fire_responses(lowering):
+                raise ValueError(
+                    f"the padded kernel lowering supports response "
+                    f"{fire_responses(lowering)}, got {response!r}; use "
+                    "lowering='reference'"
+                )
+            if w_max is None:
+                raise ValueError(
+                    "the kernel assign lowering needs w_max (integer-grid "
+                    "weight planes)"
+                )
+            d, p_env, q_env = w.shape
+            p_pad = _pad_to(p_env, LANE)
+            q_pad = _pad_to(q_env, SUBLANE)
+            t_pad = _pad_to(t_window, t_blk)
+            operands = design_operands(
+                thresholds, t_maxes, q_actives, 0.0, 0.0, 0.0
             )
-        if w_max is None:
-            raise ValueError(
-                "the kernel assign lowering needs w_max (integer-grid "
-                "weight planes)"
+            w_k = (
+                jnp.zeros((d, p_pad, q_pad), jnp.float32)
+                .at[:, :p_env, :q_env]
+                .set(w.astype(jnp.float32))
             )
-        d, p_env, q_env = w.shape
-        p_pad = _pad_to(p_env, LANE)
-        q_pad = _pad_to(q_env, SUBLANE)
-        t_pad = _pad_to(t_window, t_blk)
-        operands = design_operands(
-            thresholds, t_maxes, q_actives, 0.0, 0.0, 0.0
-        )
-        w_k = (
-            jnp.zeros((d, p_pad, q_pad), jnp.float32)
-            .at[:, :p_env, :q_env]
-            .set(w.astype(jnp.float32))
-        )
-        xs_k = jnp.swapaxes(
-            _pad_volleys_silent(xs, p_pad, t_window), 0, 1
-        )[:, :, None, :]  # [D, N, 1, p_pad]: unit axis keeps blocks whole
-        kern = functools.partial(
-            _fire_block_kernel,
-            t_blk=t_blk, n_planes=w_max + 1, w_max=w_max,
-        )
-        t_fire = pl.pallas_call(
-            kern,
-            grid=(d, n, t_pad // t_blk),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(
-                    (1, 1, 1, p_pad), lambda di, vi, ti: (di, vi, 0, 0)
+            xs_k = jnp.swapaxes(
+                _pad_volleys_silent(xs, p_pad, t_window), 0, 1
+            )[:, :, None, :]  # [D, N, 1, p_pad]: unit axis keeps blocks whole
+            kern = functools.partial(
+                _fire_block_kernel,
+                t_blk=t_blk, n_planes=w_max + 1, w_max=w_max,
+            )
+            t_fire = pl.pallas_call(
+                kern,
+                grid=(d, n, t_pad // t_blk),
+                in_specs=[
+                    pl.BlockSpec(memory_space=pltpu.SMEM),
+                    pl.BlockSpec(
+                        (1, 1, 1, p_pad), lambda di, vi, ti: (di, vi, 0, 0)
+                    ),
+                    pl.BlockSpec(
+                        (1, p_pad, q_pad), lambda di, vi, ti: (di, 0, 0)
+                    ),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, 1, 1, q_pad), lambda di, vi, ti: (di, vi, 0, 0)
                 ),
-                pl.BlockSpec(
-                    (1, p_pad, q_pad), lambda di, vi, ti: (di, 0, 0)
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, 1, q_pad), lambda di, vi, ti: (di, vi, 0, 0)
-            ),
-            out_shape=jax.ShapeDtypeStruct((d, n, 1, q_pad), jnp.float32),
-            interpret=lowering == "interpret",
-        )(operands, xs_k, w_k)
-        return _ids_from_times(t_fire[:, :, 0, :q_env], t_maxes, q_actives)
-
-    qi = jnp.arange(w.shape[2], dtype=TIME_DTYPE)
-    # tail rows are sliced away below, so the valid counts are unused here
-    xsb, _ = _pad_volley_blocks(xs, v_blk, t_window)  # [S, v_blk, D, p]
-
-    def block(xt_blk):  # [v_blk, D, p] -> [v_blk, D, q]
-        def one(wd, xd, th, tm, qa):
-            # float-weight dense fire: the established assignment
-            # arithmetic, volley for volley (only the batching is new)
-            t = fire_dense_ref(
-                wd, xd, th, t_window, t_max=tm, response=response
+                out_shape=jax.ShapeDtypeStruct((d, n, 1, q_pad), jnp.float32),
+                interpret=lowering == "interpret",
+                name="assign_fire",
+            )(operands, xs_k, w_k)
+            return _ids_from_times(
+                t_fire[:, :, 0, :q_env], t_maxes, q_actives
             )
-            return jnp.where(qi < qa, t, tm)
 
-        return jax.vmap(  # volleys in the block
-            jax.vmap(one, in_axes=(0, 0, 0, 0, 0)),  # designs
-            in_axes=(None, 0, None, None, None),
-        )(w, xt_blk, thresholds, t_maxes, q_actives)
+        qi = jnp.arange(w.shape[2], dtype=TIME_DTYPE)
+        # tail rows are sliced away below, so the valid counts are unused
+        xsb, _ = _pad_volley_blocks(xs, v_blk, t_window)  # [S, v_blk, D, p]
 
-    t_all = jax.lax.map(block, xsb)  # [S, v_blk, D, q]
-    t_all = t_all.reshape((-1,) + t_all.shape[2:])[:n]  # [N, D, q]
-    return _ids_from_times(
-        jnp.moveaxis(t_all, 0, 1), t_maxes, q_actives
-    )
+        def block(xt_blk):  # [v_blk, D, p] -> [v_blk, D, q]
+            def one(wd, xd, th, tm, qa):
+                # float-weight dense fire: the established assignment
+                # arithmetic, volley for volley (only the batching is new)
+                t = fire_dense_ref(
+                    wd, xd, th, t_window, t_max=tm, response=response
+                )
+                return jnp.where(qi < qa, t, tm)
+
+            return jax.vmap(  # volleys in the block
+                jax.vmap(one, in_axes=(0, 0, 0, 0, 0)),  # designs
+                in_axes=(None, 0, None, None, None),
+            )(w, xt_blk, thresholds, t_maxes, q_actives)
+
+        t_all = jax.lax.map(block, xsb)  # [S, v_blk, D, q]
+        t_all = t_all.reshape((-1,) + t_all.shape[2:])[:n]  # [N, D, q]
+        return _ids_from_times(
+            jnp.moveaxis(t_all, 0, 1), t_maxes, q_actives
+        )
 
 
 # -------------------------------------------------- AOT precompilation

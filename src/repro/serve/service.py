@@ -55,6 +55,9 @@ interleaved logical streams multiplexed by the caller (see
 ``benchmarks/serve_bench.py``, which sustains 64+ of them).  Stage
 timings feed a ``distributed.straggler.StepMonitor`` (stages labelled
 ``'assign'`` / ``'refit'``) so stalls are observable through ``stats()``.
+Under a JAX profiler trace every stage is also a ``repro.obs`` span
+(``serve.submit`` > ``serve.admit`` / ``serve.encode`` /
+``serve.execute`` > ...; see ``docs/serving.md``).
 """
 from __future__ import annotations
 
@@ -67,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
 from repro.core import encoding
@@ -734,6 +738,31 @@ class ClusteringService:
         admission if the predicted queue wait already exceeds it, and at
         dispatch if it expired while queued.
         """
+        route = self._route.get(design)
+        with obs.span("serve.submit", request=self._next_id, design=design,
+                      bucket=-1 if route is None else route[0].index):
+            with obs.span("serve.admit"):
+                bucket, lane, cfg, x, deadline = self._admit(
+                    series, design, deadline_s
+                )
+            with obs.span("serve.encode"):
+                enc = np.asarray(
+                    encoding.encode(jnp.asarray(x), cfg.t_max, self.encoder)
+                )
+            pending = PendingRequest(self, self._next_id, design)
+            self._next_id += 1
+            self._submitted += 1
+            bucket.queue.append(
+                _Request(pending, lane, enc, time.perf_counter(), deadline)
+            )
+            if len(bucket.queue) >= self.batch_size:
+                self._execute(bucket)
+        return pending
+
+    def _admit(self, series, design: str, deadline_s: Optional[float]):
+        """Admission: drain and overload checks, route, width, finiteness
+        and the deadline estimate; returns ``(bucket, lane, cfg, x,
+        deadline)`` or raises ``RequestRejected``."""
         self._offered += 1
         if self._draining:
             self._reject(
@@ -787,27 +816,17 @@ class ClusteringService:
                     f"{deadline:.4f}s",
                     retry_after_s=est,
                 )
-        enc = np.asarray(
-            encoding.encode(jnp.asarray(x), cfg.t_max, self.encoder)
-        )
-        pending = PendingRequest(self, self._next_id, design)
-        self._next_id += 1
-        self._submitted += 1
-        bucket.queue.append(
-            _Request(pending, lane, enc, time.perf_counter(), deadline)
-        )
-        if len(bucket.queue) >= self.batch_size:
-            self._execute(bucket)
-        return pending
+        return bucket, lane, cfg, x, deadline
 
     def flush(self, design: Optional[str] = None) -> None:
         """Execute partial batches now (all buckets, or ``design``'s)."""
         buckets = (
             self._buckets if design is None else [self._route[design][0]]
         )
-        for b in buckets:
-            while b.queue:
-                self._execute(b)
+        with obs.span("serve.flush"):
+            for b in buckets:
+                while b.queue:
+                    self._execute(b)
 
     # --------------------------------------------------------- execution
     def _silent_batch(self, bucket: _Bucket) -> np.ndarray:
@@ -863,36 +882,45 @@ class ClusteringService:
         reqs = self._shed_expired(reqs)
         if not reqs:
             return
-        self.monitor.start("assign")
-        t0 = time.perf_counter()
-        try:
-            ids = self._assign(bucket, self._batch_xs(bucket, reqs))
-        except Exception as e:
+        with obs.span("serve.execute", bucket=bucket.index,
+                      batch=self._batches, live=len(reqs)):
+            obs.count("serve.rows_live", len(reqs))
+            obs.count("serve.rows_slots", self.batch_size)
+            self.monitor.start("assign")
+            t0 = time.perf_counter()
+            try:
+                with obs.span("serve.batch_xs"):
+                    xs = self._batch_xs(bucket, reqs)
+                with obs.span("serve.assign"):
+                    ids = self._assign(bucket, xs)
+            except Exception as e:
+                self.monitor.stop()
+                warnings.warn(
+                    f"assign of bucket {bucket.index} "
+                    f"({bucket.asg_lowering}) failed, isolating its "
+                    f"{len(reqs)} request(s): {e!r}",
+                    RuntimeWarning,
+                )
+                self._isolate(bucket, reqs)
+                return
             self.monitor.stop()
-            warnings.warn(
-                f"assign of bucket {bucket.index} ({bucket.asg_lowering}) "
-                f"failed, isolating its {len(reqs)} request(s): {e!r}",
-                RuntimeWarning,
+            done = time.perf_counter()
+            dt = done - t0
+            self._batch_ewma = (
+                dt if self._batch_ewma is None
+                else 0.8 * self._batch_ewma + 0.2 * dt
             )
-            self._isolate(bucket, reqs)
-            return
-        self.monitor.stop()
-        done = time.perf_counter()
-        dt = done - t0
-        self._batch_ewma = (
-            dt if self._batch_ewma is None
-            else 0.8 * self._batch_ewma + 0.2 * dt
-        )
-        self._batches += 1
-        for n, r in enumerate(reqs):
-            self._complete(
-                bucket, r,
-                ServeResult(
-                    r.pending.id, r.pending.design,
-                    int(ids[r.lane, n]), done - r.t_submit,
-                ),
-            )
-        self._maybe_refit(bucket)
+            self._batches += 1
+            with obs.span("serve.complete"):
+                for n, r in enumerate(reqs):
+                    self._complete(
+                        bucket, r,
+                        ServeResult(
+                            r.pending.id, r.pending.design,
+                            int(ids[r.lane, n]), done - r.t_submit,
+                        ),
+                    )
+            self._maybe_refit(bucket)
 
     def _isolate(self, bucket: _Bucket, reqs: list[_Request]) -> None:
         """Quarantine: re-run each request of a failed batch alone against
@@ -986,7 +1014,8 @@ class ClusteringService:
             self.monitor.start(label)
             t0 = time.perf_counter()
             try:
-                w_new = self._fit_window(bucket, xs_np, low)
+                with obs.span("serve.fit", lowering=low):
+                    w_new = self._fit_window(bucket, xs_np, low)
             except Exception as e:
                 self.monitor.stop()
                 failed(low, repr(e))
@@ -1005,7 +1034,9 @@ class ClusteringService:
                     f"{self.refit_budget_s:.3f}s) — result discarded",
                 )
                 continue
-            if not bool(jnp.isfinite(w_new).all()):
+            with obs.span("serve.refit_check"):
+                finite = bool(jnp.isfinite(w_new).all())
+            if not finite:
                 failed(low, "non-finite weights (poisoned re-fit)")
                 continue
             return w_new, low, errors
@@ -1022,52 +1053,57 @@ class ClusteringService:
         )
 
     def _refit(self, bucket: _Bucket, warm: bool = False) -> None:
-        xs = self._refit_xs(bucket)
-        if warm:
-            # warmup's all-silent window: single rung, no budget (first
-            # dispatch may still be cold), no WAL, no counters
-            w_new, _, _ = self._attempt_window(
-                bucket, xs, ladder=(bucket.fit_lowering,),
-                enforce_budget=False,
-            )
-            if w_new is not None:
-                self._commit_weights(bucket, w_new)
-        else:
-            w_new, _low, errors = self._attempt_window(
-                bucket, xs,
-                ladder=backend_lib.lowering_ladder(bucket.fit_lowering),
-            )
-            if w_new is None:
-                # degraded mode: keep serving from last-good weights;
-                # retry after an exponentially growing number of windows
-                self._refit_failures += 1
-                bucket.failed_refits += 1
-                bucket.cooldown = backend_lib.refit_backoff(
-                    bucket.failed_refits
+        with obs.span("serve.refit", bucket=bucket.index):
+            with obs.span("serve.refit_xs"):
+                xs = self._refit_xs(bucket)
+            if warm:
+                # warmup's all-silent window: single rung, no budget (first
+                # dispatch may still be cold), no WAL, no counters
+                w_new, _, _ = self._attempt_window(
+                    bucket, xs, ladder=(bucket.fit_lowering,),
+                    enforce_budget=False,
                 )
-                bucket.degraded = True
-                bucket.last_refit_errors = errors
+                if w_new is not None:
+                    with obs.span("serve.commit"):
+                        self._commit_weights(bucket, w_new)
             else:
-                self._commit_weights(bucket, w_new)
-                bucket.last_fit_lowering = _low
-                self._refits += 1
-                self._refit_seq += 1
-                if bucket.degraded:
-                    bucket.degraded = False
-                    bucket.failed_refits = 0
-                    bucket.cooldown = 0
-                    bucket.last_refit_errors = []
-                    self._recoveries += 1
-                if self._store is not None:
-                    self._store.log_refit(
-                        self._refit_seq, bucket.index, self.refit_epochs,
-                        _low, xs,
+                w_new, _low, errors = self._attempt_window(
+                    bucket, xs,
+                    ladder=backend_lib.lowering_ladder(bucket.fit_lowering),
+                )
+                if w_new is None:
+                    # degraded mode: keep serving from last-good weights;
+                    # retry after an exponentially growing number of windows
+                    self._refit_failures += 1
+                    bucket.failed_refits += 1
+                    bucket.cooldown = backend_lib.refit_backoff(
+                        bucket.failed_refits
                     )
-                    if self._refit_seq % self.snapshot_every == 0:
-                        self._snapshot()
-        for buf in bucket.buffers:
-            buf.clear()
-        bucket.served_since_refit = 0
+                    bucket.degraded = True
+                    bucket.last_refit_errors = errors
+                else:
+                    with obs.span("serve.commit"):
+                        self._commit_weights(bucket, w_new)
+                    bucket.last_fit_lowering = _low
+                    self._refits += 1
+                    self._refit_seq += 1
+                    if bucket.degraded:
+                        bucket.degraded = False
+                        bucket.failed_refits = 0
+                        bucket.cooldown = 0
+                        bucket.last_refit_errors = []
+                        self._recoveries += 1
+                    if self._store is not None:
+                        with obs.span("serve.wal"):
+                            self._store.log_refit(
+                                self._refit_seq, bucket.index,
+                                self.refit_epochs, _low, xs,
+                            )
+                            if self._refit_seq % self.snapshot_every == 0:
+                                self._snapshot()
+            for buf in bucket.buffers:
+                buf.clear()
+            bucket.served_since_refit = 0
 
     def _maybe_refit(self, bucket: _Bucket) -> None:
         if (

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import dse
+from repro import dse, obs
 from repro.core import backend, simulator
 from repro.core.types import ColumnConfig, TIME_DTYPE
 from repro.hwgen.forecast import PaperForecaster
@@ -367,3 +367,43 @@ def test_pareto_front_excludes_dominated_and_nan():
     assert front == [a, c]
     assert dse.dominates(a, b) and not dse.dominates(b, a)
     assert not dse.dominates(a, c)
+
+
+# ---------------------------------------------------------------- spans
+def test_traced_explore_gives_the_span_tree(monkeypatch, tmp_path):
+    """Under the profiler an exploration is one ``dse.explore`` root; each
+    envelope bucket one ``sim.bucket`` with its pad, fit dispatch,
+    lowering wait and assign, then one ``sim.score`` and the journal
+    callback's ``dse.record``.  Untraced, nothing is recorded."""
+    monkeypatch.setattr(backend, "pallas_lowering", lambda: "interpret")
+    x, y = _stream(n=12, length=10)
+    space = dse.DesignSpace(q=(2, 3), t_max=(8, 32),
+                            threshold_scale=(1.0,))
+    obs.reset()
+    dse.explore(x, y, space, epochs=1, seed=4)
+    assert obs.snapshot().spans == ()
+
+    with jax.profiler.trace(str(tmp_path)):
+        res = dse.explore(x, y, space, epochs=1, seed=5)
+    snap = obs.snapshot()
+    obs.reset()
+
+    def kids(span):
+        return [s.name for s in snap.spans if s.parent == span.id]
+
+    root, = [s for s in snap.spans if s.parent == 0]
+    assert root.name == "dse.explore" and root.attrs == {"candidates": 4}
+    assert kids(root) == ["dse.init", "sim.many", "dse.pareto"]
+    many, = [s for s in snap.spans if s.name == "sim.many"]
+    n_buckets = res.meta["buckets"]["latency"]
+    assert n_buckets == 2
+    assert kids(many) == ["sim.encode"] + [
+        "sim.bucket", "sim.score", "dse.record"
+    ] * n_buckets
+    buckets = [s for s in snap.spans if s.name == "sim.bucket"]
+    for b in buckets:
+        assert kids(b) == ["sim.pad", "sim.fit", "sim.lowering", "sim.assign"]
+        assert b.attrs["lowering"] == "interpret"
+        assert b.attrs["volleys"] == len(x)
+    assert sum(b.attrs["designs"] for b in buckets) == 4
+

@@ -28,10 +28,12 @@ from __future__ import annotations
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import backend, encoding, simulator
 from repro.core.types import ColumnConfig, TIME_DTYPE
 from repro.kernels import fused_column
@@ -582,3 +584,67 @@ def test_assign_time_series_single_and_micro_batch():
     for i in range(5):
         one = simulator.assign_time_series(batch[i], cfg, params)
         assert int(one) == int(ids[i])  # micro-batch == single requests
+
+
+# ---------------------------------------------------------------- spans
+def _children(snap, span):
+    return [s.name for s in snap.spans if s.parent == span.id]
+
+
+def test_traced_requests_give_the_span_tree(monkeypatch, tmp_path):
+    """Under the profiler every request is one ``serve.submit`` holding
+    its admission and encode; each batch one ``serve.execute`` holding
+    assembly, assign and completion; each re-fit one ``serve.refit``
+    holding its window, fit rung, check and commit.  Untraced, nothing
+    is recorded."""
+    monkeypatch.setattr(backend, "padded_lowering", lambda r: "interpret")
+    service = ClusteringService(
+        _fleet(2), batch_size=4, refit_every=4, refit_window=4
+    )
+    service.warmup()
+    rng = np.random.default_rng(3)
+    obs.reset()
+    for i, s in enumerate(_stream(rng, 6)):
+        service.submit(s, f"d{i % 2}")
+    service.flush()
+    assert obs.snapshot().spans == ()
+
+    before = service.stats()
+    with jax.profiler.trace(str(tmp_path)):
+        for i, s in enumerate(_stream(rng, 10)):
+            service.submit(s, f"d{i % 2}")
+        service.flush()
+    after = service.stats()
+    snap = obs.snapshot()
+    obs.reset()
+
+    submits = [s for s in snap.spans if s.name == "serve.submit"]
+    assert [s.attrs["request"] for s in submits] == list(
+        range(before.submitted, after.submitted)
+    )
+    for s in submits:
+        assert s.parent == 0 and s.attrs["bucket"] == 0
+        kids = _children(snap, s)
+        assert kids[:2] == ["serve.admit", "serve.encode"]
+        assert kids[2:] in ([], ["serve.execute"])
+    executes = [s for s in snap.spans if s.name == "serve.execute"]
+    assert len(executes) == after.batches - before.batches == 3
+    flush, = [s for s in snap.spans if s.name == "serve.flush"]
+    parents = {s.id: s.name for s in snap.spans}
+    assert sorted(parents[s.parent] for s in executes) == [
+        "serve.flush", "serve.submit", "serve.submit"
+    ]
+    for s in executes:
+        kids = _children(snap, s)
+        assert kids[:3] == ["serve.batch_xs", "serve.assign", "serve.complete"]
+        assert kids[3:] in ([], ["serve.refit"])
+    refits = [s for s in snap.spans if s.name == "serve.refit"]
+    assert len(refits) == after.refits - before.refits >= 1
+    for s in refits:
+        assert _children(snap, s) == [
+            "serve.refit_xs", "serve.fit", "serve.refit_check", "serve.commit"
+        ]
+    assert {s.attrs["lowering"] for s in snap.spans
+            if s.name == "serve.fit"} == {"interpret"}
+    assert snap.counters == {"serve.rows_live": 10, "serve.rows_slots": 12}
+
