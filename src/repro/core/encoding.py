@@ -8,6 +8,7 @@ deviations, mirroring DoG receptive fields in sensory pathways.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.types import TIME_DTYPE
@@ -69,7 +70,11 @@ def onoff_encode(x: jnp.ndarray, t_max: int) -> jnp.ndarray:
     the off channel for negative deviations; the silent channel of each pair
     emits no spike (t_max).
     """
-    mu = x.mean(axis=-1, keepdims=True)
+    return _onoff_around(x, x.mean(axis=-1, keepdims=True), t_max)
+
+
+def _onoff_around(x: jnp.ndarray, mu: jnp.ndarray, t_max: int) -> jnp.ndarray:
+    """``onoff_encode`` of ``x`` given its mean ``mu`` [..., 1]."""
     dev = x - mu
     mag = minmax_normalize(jnp.abs(dev))
     t = jnp.round((1.0 - mag) * (t_max - 1)).astype(TIME_DTYPE)
@@ -77,3 +82,25 @@ def onoff_encode(x: jnp.ndarray, t_max: int) -> jnp.ndarray:
     on = jnp.where(dev >= 0, t, no)
     off = jnp.where(dev < 0, t, no)
     return jnp.concatenate([on, off], axis=-1)
+
+
+_latency_jit = jax.jit(latency_encode, static_argnames=("t_max",))
+_mean_jit = jax.jit(lambda x: x.mean(axis=-1, keepdims=True))
+_onoff_around_jit = jax.jit(_onoff_around, static_argnames=("t_max",))
+
+
+def encode_jit(x, t_max: int, encoder: str = "latency") -> jax.Array:
+    """``encode`` as compiled programs, one per (series shape, t_max,
+    encoder): the same jnp ops in f32, bit-identical to the op-by-op call.
+
+    The on/off mean is a program of its own.  Fused with the deviation
+    ``x - mean``, XLA:CPU contracts the mean's multiply by 1/L into an FMA
+    in some fusions and not in others, so a near-constant series would
+    normalise against a range its own deviations do not share.
+    """
+    if encoder == "latency":
+        return _latency_jit(x, t_max)
+    if encoder == "onoff":
+        x = jnp.asarray(x)
+        return _onoff_around_jit(x, _mean_jit(x), t_max)
+    raise ValueError(f"unknown encoder: {encoder!r} (have {ENCODERS})")

@@ -12,7 +12,8 @@ The serving pipeline, stage by stage (each independently testable):
   deadline budget sheds with ``reason='deadline'`` when the predicted
   queue wait already exceeds it.
 * **encode** — the series becomes a spike volley via the central encoder
-  dispatch (``encoding.encode``), using the target design's gamma window.
+  dispatch, compiled once per design shape (``encoding.encode_jit``),
+  using the target design's gamma window, then fetched to the host.
 * **bucket dispatch** — designs are packed into shared padding envelopes
   at construction (``backend.envelope_buckets``); a request rides the
   queue of its design's bucket and is batched with requests for *any*
@@ -661,26 +662,26 @@ class ClusteringService:
 
     # ------------------------------------------------------------ warmup
     def warmup(self) -> dict:
-        """Compile (or disk-load) every executable and warm every eager-op
-        shape the steady state dispatches, so traffic performs ZERO XLA
-        compiles afterwards.
+        """Compile (or disk-load) every executable and warm every shape
+        the steady state dispatches, so traffic performs ZERO XLA compiles
+        afterwards.
 
-        Per bucket: the batch-shaped assignment executable and the
+        Per design: the jitted encode of its series length and gamma
+        window.  Per bucket: the batch-shaped assignment executable and the
         window-shaped re-fit executable become resident via the backend
         ``warm_*`` pre-compilers, then one all-silent batch and one
         all-silent re-fit run end-to-end through the real serving path —
         silent volleys assign to "unclustered" (discarded) and are exact
         weight no-ops, so warmup changes no answers and no weights while
-        exercising the same ops as live traffic (including the
-        per-design encode shapes).
+        exercising the same ops as live traffic.
         """
         t0 = time.perf_counter()
         hot = 0
         for name, c in self._cfgs.items():
             length = c.p if self.encoder == "latency" else c.p // 2
-            np.asarray(encoding.encode(
-                jnp.asarray(np.zeros(length)), c.t_max, self.encoder
-            ))
+            np.asarray(
+                encoding.encode_jit(np.zeros(length), c.t_max, self.encoder)
+            )
         for b in self._buckets:
             db = len(b.names)
             p_env, q_env, t_window = b.envelope
@@ -747,7 +748,7 @@ class ClusteringService:
                 )
             with obs.span("serve.encode"):
                 enc = np.asarray(
-                    encoding.encode(jnp.asarray(x), cfg.t_max, self.encoder)
+                    encoding.encode_jit(x, cfg.t_max, self.encoder)
                 )
             pending = PendingRequest(self, self._next_id, design)
             self._next_id += 1

@@ -120,3 +120,27 @@ def test_encode_dispatch_matches_width_contract():
         encoding.encoded_width(10, "morse")
     with pytest.raises(ValueError, match="unknown encoder"):
         encoding.encode(x, 16, "morse")
+
+
+# The seven Table II designs' (series length, gamma window) pairs.
+TABLE2_SHAPES = [(65, 64), (96, 64), (152, 64), (343, 64), (637, 64),
+                 (470, 64), (270, 64)]
+SERIES_KINDS = {
+    "random": lambda n: np.random.default_rng(n).normal(2.0, 3.0, size=n),
+    "constant": lambda n: np.full(n, 0.75),  # hi == lo: the eps guard
+    "ramp": lambda n: np.linspace(-1.0, 1.0, n),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(SERIES_KINDS))
+@pytest.mark.parametrize("length,t_max", TABLE2_SHAPES)
+@pytest.mark.parametrize("encoder", encoding.ENCODERS)
+def test_jitted_encode_is_bit_identical_to_eager(encoder, length, t_max, kind):
+    """The service's jitted encode, fed the float64 host array admission
+    makes, returns the op-by-op ``encode``'s spike times bit for bit."""
+    x = SERIES_KINDS[kind](length)
+    eager = np.asarray(encoding.encode(jnp.asarray(x), t_max, encoder))
+    jitted = np.asarray(encoding.encode_jit(x, t_max, encoder))
+    assert jitted.dtype == eager.dtype == TIME_DTYPE
+    assert jitted.shape == (encoding.encoded_width(length, encoder),)
+    np.testing.assert_array_equal(jitted, eager)

@@ -109,14 +109,18 @@ def test_results_match_single_design_assignment_entry():
         assert h.result().cluster == int(expect)
 
 
-def test_steady_state_is_compile_free(compile_counter):
+@pytest.mark.parametrize("encoder", ["latency", "onoff"])
+def test_steady_state_is_compile_free(compile_counter, encoder):
     """The acceptance bar: after warmup, a traffic mix spanning full
     batches, partial flushes and online re-fits performs ZERO XLA
-    compiles — one resident executable per (bucket, shape)."""
+    compiles — one resident executable per (bucket, shape), the jitted
+    encode of every design shape included."""
     service = ClusteringService(
         _fleet(4), batch_size=8, refit_every=16, refit_window=16, seed=0,
         waste_cap=2.0,  # two buckets: steady state spans both executables
+        encoder=encoder,
     )
+    length = P if encoder == "latency" else P // 2
     service.warmup()
     assert compile_counter.compiles > 0  # warmup did the compiling
     base = compile_counter.compiles
@@ -126,7 +130,7 @@ def test_steady_state_is_compile_free(compile_counter):
     for r in range(3):
         for s in range(24):
             handles.append(service.submit(
-                rng.normal(size=P), names[s % len(names)]
+                rng.normal(size=length), names[s % len(names)]
             ))
         service.flush()  # partial batches ride the same executables
     stats = service.stats()
