@@ -10,7 +10,8 @@ policy now lives here:
     'cycle'  — cycle-accurate lax.scan (bit-identical to generated RTL,
                required for LIF).
     'pallas' — the fused column step (``kernels/fused_column.py``): RNL fire
-               + k-WTA + expected STDP in one kernel invocation.
+               + k-WTA + expected or stochastic STDP in one kernel
+               invocation.
   Each backend provides ``fire`` (batched post-WTA forward) and ``fit``
   (online STDP training as ONE jitted, donated lax.scan over epochs x
   volleys — a single compilation per config, no per-epoch dispatch).
@@ -29,7 +30,8 @@ policy now lives here:
   ``mode`` knob ('auto' | 'event' | 'cycle' | 'pallas') to a registry name.
   'auto' keeps the paper's hybrid forward semantics (event where exact,
   cycle for LIF) and routes *training* to the fused path whenever the
-  config fits its contract (RNL, expected STDP, index tie-break).
+  config fits its contract (RNL, expected or stochastic STDP, index
+  tie-break).
 
 Multi-layer networks (``repro.core.network``) resolve here too, layer by
 layer against each layer's column config.  The full contract is documented
@@ -330,16 +332,19 @@ def cycle_exact(cfg: ColumnConfig, w0) -> bool:
 
     The fused fire rounds weights to the integer grid {0..w_max}; the
     solvers fire on float weights.  The two coincide exactly when
-    training keeps the weights on the grid: integer STDP steps, no
-    stabilizer, and init weights already integral (checked concretely,
-    like ``assign_lowering`` — abstract weights answer False).
+    training keeps the weights on the grid and the init weights are
+    already integral (checked concretely, like ``assign_lowering`` —
+    abstract weights answer False): stochastic STDP always keeps them
+    there (unit steps from the shared stream, ``stdp.stochastic_update``),
+    expected STDP only with integer mus and no stabilizer.
     """
     s = cfg.stdp
-    if s.stabilizer != "none" or s.mode != "expected":
-        return False
-    if not all(
-        float(mu).is_integer()
-        for mu in (s.mu_capture, s.mu_backoff, s.mu_search)
+    if s.mode != "stochastic" and (
+        s.stabilizer != "none"
+        or not all(
+            float(mu).is_integer()
+            for mu in (s.mu_capture, s.mu_backoff, s.mu_search)
+        )
     ):
         return False
     try:
@@ -501,7 +506,8 @@ def shard_designs(mesh, fn, arg_axes: Sequence[int], **statics):
     runs the same program on its own designs, bit-identical to the
     unsharded run.  The wrapper is memoized on (mesh, fn, axes, statics),
     so repeated buckets reuse one trace (a bounded memo: a sweep touches a
-    handful of envelopes).
+    handful of envelopes).  The program takes ``fn``'s name
+    (``jit_<name>``), so profiles tell the sharded fit from the assign.
     """
     return _shard_designs(
         mesh, fn, tuple(arg_axes), tuple(sorted(statics.items()))
@@ -514,8 +520,13 @@ def _shard_designs(mesh, fn, arg_axes: tuple, statics: tuple):
     in_specs = tuple(
         PartitionSpec(*((None,) * a + (DESIGN_AXIS,))) for a in arg_axes
     )
+
+    def per_device(*args):
+        return fn(*args, **kw)
+
+    per_device.__name__ = per_device.__qualname__ = fn.__name__
     return jax.jit(jax.shard_map(
-        lambda *args: fn(*args, **kw), mesh=mesh, in_specs=in_specs,
+        per_device, mesh=mesh, in_specs=in_specs,
         out_specs=PartitionSpec(DESIGN_AXIS),
         # every operand is design-sharded and nothing is reduced across
         # devices; pallas_call outputs carry no varying-axes annotation
@@ -697,13 +708,16 @@ def _coerce(x, dtype):
 
 def _fit_key(
     w_shape, xs_shape, t_window, w_max, wta_k, stabilize, response,
-    epochs, lowering, t_blk, v_blk,
+    epochs, lowering, t_blk, v_blk, stochastic=False,
 ) -> tuple:
-    """AOT cache key for one fit envelope: shapes + statics, never values."""
+    """AOT cache key for one fit envelope: shapes + statics, never values.
+    A stochastic envelope extends the key with its flag and the names of
+    the stream operands its executable takes (only when set, so an
+    expected-mode envelope keeps its key)."""
     return (
         "fit", tuple(w_shape), tuple(xs_shape), t_window, w_max, wta_k,
         bool(stabilize), response, epochs, lowering, t_blk, v_blk,
-    )
+    ) + (("stochastic", "keys") if stochastic else ())
 
 
 def _assign_key(
@@ -853,6 +867,7 @@ def fit_padded(
     lowering: str,
     t_blk: Optional[int] = None,
     v_blk: Optional[int] = None,
+    keys=None,
 ):
     """Envelope-cached AOT front door to ``fused_column.fit_scan_padded``.
 
@@ -873,7 +888,11 @@ def fit_padded(
     Callers with sharded operands must use ``fit_scan_padded`` directly —
     these executables are compiled against unsharded specs, while the jit
     path lets GSPMD propagate the design partitioning at trace time.
+
+    ``keys`` ([D, 2] i32 stream keys, one per design) selects stochastic
+    STDP — the envelope's static flag.
     """
+    stochastic = keys is not None
     w = _coerce(w, jnp.float32)
     xs = _coerce(xs, TIME_DTYPE)
     thresholds = _coerce(thresholds, jnp.float32)
@@ -884,6 +903,9 @@ def fit_padded(
         "fit", lowering, d, p_pad, q_pad, t_window, xs.shape[0], epochs,
         w_max, response, v_blk, t_blk,
     )
+    stream = {}
+    if stochastic:
+        stream = dict(keys=_coerce(keys, jnp.int32))
     if not hasattr(fused_column.fit_scan_padded, "lower"):
         # the module entry point has been replaced by a plain callable —
         # the fault-injection / instrumentation seam the fault tests (and
@@ -896,10 +918,11 @@ def fit_padded(
             mu_capture=mu_capture, mu_backoff=mu_backoff,
             mu_search=mu_search, stabilize=stabilize, response=response,
             epochs=epochs, lowering=lowering, t_blk=t_blk, v_blk=v_blk,
+            **(dict(stochastic=True, **stream) if stochastic else {}),
         )
     key = _fit_key(
         w.shape, xs.shape, t_window, w_max, wta_k, stabilize, response,
-        epochs, lowering, t_blk, v_blk,
+        epochs, lowering, t_blk, v_blk, stochastic,
     )
     exe = _resolve_executable(
         key,
@@ -908,15 +931,17 @@ def fit_padded(
             t_window=t_window, w_max=w_max, wta_k=wta_k,
             stabilize=bool(stabilize), response=response, epochs=epochs,
             lowering=lowering, t_blk=t_blk, v_blk=v_blk,
+            stochastic=stochastic,
         ),
     )
     # the call must mirror the precompile specs exactly: five positional
-    # arrays, mus by keyword, as f32 scalars
+    # arrays, mus (and a stochastic fit's keys) by keyword
     return exe(
         w, xs, thresholds, t_maxes, q_actives,
         mu_capture=_f32_scalar(float(mu_capture)),
         mu_backoff=_f32_scalar(float(mu_backoff)),
         mu_search=_f32_scalar(float(mu_search)),
+        **stream,
     )
 
 
@@ -980,14 +1005,18 @@ def solver_volley_step(
     cfg: ColumnConfig,
     solver_mode: str,
     y_target: Optional[jnp.ndarray] = None,
+    stream=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One online-STDP step on the event/cycle solvers: fire -> WTA -> STDP.
 
     This is the shared scan body of the generic (non-fused) training path —
     ``_solver_fit_scan`` folds it over a column's volleys and
     ``network._layer_solver_fit_scan`` additionally ``vmap``s it over a
-    layer's columns.  ``key`` must already be folded per volley; it is split
-    here for the WTA tie-break and stochastic STDP independently.
+    layer's columns.  ``key`` must already be folded per volley; it feeds
+    the random WTA tie-break.  Stochastic STDP draws from the design's
+    counter-based stream instead: ``stream`` is ``(stream key, global
+    volley index)`` (``stdp.stream_key`` / ``stdp.stream_uniform``), the
+    bits the fused kernels draw for the same synapse and volley.
 
     Returns (updated weights [p, q], post-WTA winner times [q]).
     """
@@ -996,16 +1025,17 @@ def solver_volley_step(
         if solver_mode == "event"
         else neuron.fire_times_cycle
     )
-    k_wta, k_stdp = jax.random.split(key)
+    k_wta, _ = jax.random.split(key)
     t = solver(x_t[None], w, cfg.neuron, cfg.t_max)[0]
     y, _ = wta.wta(
         t, cfg.wta, cfg.t_max,
         rng=k_wta if cfg.wta.tie_break == "random" else None,
     )
     teacher = y if y_target is None else y_target
+    s_key, volley = (None, 0) if stream is None else stream
     w2 = stdp.stdp_update(
         w, x_t, teacher, cfg.stdp, cfg.neuron.w_max, cfg.t_max,
-        rng=k_stdp if cfg.stdp.mode == "stochastic" else None,
+        rng=s_key if cfg.stdp.mode == "stochastic" else None, volley=volley,
     )
     return w2, y
 
@@ -1029,16 +1059,20 @@ def _solver_fit_scan(
     """Online STDP as one compiled scan using the event/cycle solvers.
 
     Handles the full config surface (LIF, stochastic STDP, random/all WTA
-    tie-breaks, supervised targets) that the fused step does not.
+    tie-breaks, supervised targets).  Stochastic STDP draws volley n of
+    epoch e at stream index e * N + n under ``stdp.stream_key(rng)`` — the
+    stream the fused path draws — so 'cycle' is its bit-exact reference.
     """
     n = xs.shape[0]
+    s_key = stdp.stream_key(rng)
 
     def volley(carry, inp):
         wc, key = carry
-        xt, yt, i = inp
+        xt, yt, i, v = inp
         kv = jax.random.fold_in(key, i)
         w2, y = solver_volley_step(
-            wc, xt, kv, cfg, mode, y_target=yt if supervised else None
+            wc, xt, kv, cfg, mode, y_target=yt if supervised else None,
+            stream=(s_key, v),
         )
         return (w2, key), (y if trace else None)
 
@@ -1047,8 +1081,9 @@ def _solver_fit_scan(
     def epoch(carry, e):
         wc, key = carry
         ke = jax.random.fold_in(key, e)
+        idx = jnp.arange(n, dtype=jnp.int32)
         (w2, _), ys = jax.lax.scan(
-            volley, (wc, ke), (xs, yts, jnp.arange(n))
+            volley, (wc, ke), (xs, yts, idx, e * n + idx)
         )
         return (w2, key), ys
 
@@ -1148,7 +1183,7 @@ def _pallas_fit(params, x, cfg, mode, epochs, rng, trace, y_target=None):
         )
     return fused_column.fit_fused(
         params, x, cfg, epochs,
-        lowering=padded_lowering(cfg.neuron.response), trace=trace,
+        lowering=padded_lowering(cfg.neuron.response), trace=trace, rng=rng,
     )
 
 

@@ -35,7 +35,19 @@ from repro.core.types import ColumnConfig, TIME_DTYPE, WEIGHT_DTYPE
 
 def init_params(rng: jax.Array, cfg: ColumnConfig) -> dict:
     """Initialize weights uniformly over [0, w_max] (hardware reset state
-    randomizes the unary counters)."""
+    randomizes the unary counters).
+
+    Stochastic-STDP designs start from *integer* counters, uniform on
+    {0..w_max}, as the hardware's reset does: their unit updates then keep
+    every weight on the integer grid, where the fused integer-grid fire
+    and the float-weight solvers agree exactly.  Expected mode draws
+    floats, as before.
+    """
+    if cfg.stdp.mode == "stochastic":
+        w = jax.random.randint(
+            rng, (cfg.p, cfg.q), 0, cfg.neuron.w_max + 1
+        ).astype(WEIGHT_DTYPE)
+        return {"w": w}
     w = jax.random.uniform(
         rng, (cfg.p, cfg.q), WEIGHT_DTYPE, 0.0, float(cfg.neuron.w_max)
     )
@@ -128,6 +140,9 @@ def fit(
 
     The whole run — every epoch, every volley — is one compiled scan with a
     donated weight buffer; nothing is re-traced or re-padded per volley.
+    Stochastic STDP draws from the stream of ``rng`` (required then;
+    ``stdp.stream_key``), volley n of epoch e at index e * N + n, on every
+    backend alike.
     """
     name = backend_lib.resolve(mode, cfg, training=True)
     new_params, _ = backend_lib.get(name).fit(

@@ -31,8 +31,8 @@ donated ``lax.scan``:
   are runtime SMEM operands of one static envelope), the jnp reference
   body of the same algebra elsewhere — bit-identical on integer weight
   grids either way;
-* layers that resolve to 'event' / 'cycle' (LIF, stochastic STDP, random
-  tie-break, ...) run the same solver volley body as ``column.fit``
+* layers that resolve to 'event' / 'cycle' (LIF, random tie-break,
+  supervised, ...) run the same solver volley body as ``column.fit``
   (``backend.solver_volley_step``) scanned over epochs x volleys and
   ``vmap``-ed over columns — one compilation per layer *config* (the
   solver scan specializes on the full column config, threshold included).
@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
+from repro.core import stdp
 from repro.core.types import (
     ColumnConfig,
     LayerConfig,
@@ -243,6 +244,7 @@ def _fit_layer_fused(
     envelope: tuple[int, int, int],
     epochs: int,
     plan_sink: Optional[list] = None,
+    rng: Optional[jax.Array] = None,
 ) -> jnp.ndarray:
     """Train one layer's columns on the fused path.  [c,p,q],[N,c,p] -> [c,p,q].
 
@@ -256,7 +258,9 @@ def _fit_layer_fused(
     lowering comes from ``backend.padded_lowering``: the Mosaic kernel on
     TPU (the layer's threshold / window / live-q / mus ride along as
     runtime operands), the jnp reference body elsewhere — and fusability is
-    checked against that lowering.
+    checked against that lowering.  A stochastic layer draws column k's
+    stream under ``_column_stream_keys(rng, c)[k]``, as its solver twin
+    does.
     """
     lowering = backend_lib.padded_lowering(cfg.neuron.response)
     fused_column.check_fusable(cfg, lowering)
@@ -282,8 +286,11 @@ def _fit_layer_fused(
         "fit", lowering, c, p_env, q_env, t_window, hc.shape[0], epochs,
         w_max=cfg.neuron.w_max, response=cfg.neuron.response,
     )
+    keys = None
+    if cfg.stdp.mode == "stochastic":
+        keys = _column_stream_keys(rng, c)
     w_new = backend_lib.fit_padded(
-        w_pad, xs, thresholds, t_maxes, q_actives,
+        w_pad, xs, thresholds, t_maxes, q_actives, keys=keys,
         t_window=t_window, w_max=cfg.neuron.w_max, wta_k=cfg.wta.k,
         mu_capture=cfg.stdp.mu_capture, mu_backoff=cfg.stdp.mu_backoff,
         mu_search=cfg.stdp.mu_search,
@@ -294,6 +301,12 @@ def _fit_layer_fused(
     if plan_sink is not None:
         plan_sink.append(plan.meta())
     return w_new[:, : cfg.p, : cfg.q]
+
+
+def _column_stream_keys(rng: jax.Array, columns: int) -> jnp.ndarray:
+    """[columns, 2] i32 stochastic-STDP stream keys of a layer's columns,
+    from the layer's training key: ``stdp.stream_key`` of its split."""
+    return jax.vmap(stdp.stream_key)(jax.random.split(rng, columns))
 
 
 @functools.partial(
@@ -320,23 +333,27 @@ def _layer_solver_fit_scan(
     """
     n = xs.shape[0]
     c = w.shape[0]
+    s_keys = _column_stream_keys(rng, c)
 
     def volley(carry, inp):
         wc, key = carry
-        xt, i = inp  # xt: [c, p]
+        xt, i, v = inp  # xt: [c, p]
         kv = jax.random.fold_in(key, i)
         keys = jax.random.split(kv, c)
         w2, _ = jax.vmap(
-            lambda wi, xi, ki: backend_lib.solver_volley_step(
-                wi, xi, ki, cfg, solver_mode
+            lambda wi, xi, ki, si: backend_lib.solver_volley_step(
+                wi, xi, ki, cfg, solver_mode, stream=(si, v)
             )
-        )(wc, xt, keys)
+        )(wc, xt, keys, s_keys)
         return (w2, key), None
 
     def epoch(carry, e):
         wc, key = carry
         ke = jax.random.fold_in(key, e)
-        (w2, _), _ = jax.lax.scan(volley, (wc, ke), (xs, jnp.arange(n)))
+        idx = jnp.arange(n, dtype=jnp.int32)
+        (w2, _), _ = jax.lax.scan(
+            volley, (wc, ke), (xs, idx, e * n + idx)
+        )
         return (w2, key), None
 
     (w, _), _ = jax.lax.scan(epoch, (w, rng), jnp.arange(epochs))
@@ -367,7 +384,8 @@ def fit_greedy(
       mode: 'auto' | 'event' | 'cycle' | 'pallas', resolved *per layer*
         through ``backend.resolve`` — 'auto' routes each layer to the fused
         padded scan whenever its config fits the fused contract (RNL,
-        expected STDP, index tie-break) and to the event/cycle solvers
+        expected or stochastic STDP, index tie-break) and to the event/cycle
+        solvers
         otherwise; explicit names force that backend for every layer and
         raise on layers outside its contract.  Under 'pallas' the padded
         scan lowers via ``backend.padded_lowering`` (Mosaic kernel on TPU,
@@ -375,8 +393,9 @@ def fit_greedy(
       rng: PRNG key.  Required whenever any layer's config is stochastic —
         ``wta.tie_break == 'random'`` or ``stdp.mode == 'stochastic'`` —
         and never silently defaulted for those (a loud ValueError instead);
-        deterministic configs may omit it.  Fused layers are deterministic
-        by contract and consume no randomness.
+        deterministic configs may omit it.  A stochastic layer draws its
+        columns' streams from its split of ``rng`` on the fused and the
+        solver path alike.
       plan_sink: optional list; each fused layer appends its
         ``ExecutionPlan.meta()`` dict (in layer order) so callers can
         record which blocking policy trained the weights without changing
@@ -418,7 +437,7 @@ def fit_greedy(
         if name == "pallas":
             w = _fit_layer_fused(
                 lp["w"], hc, layer.column, env_by_layer[li], epochs,
-                plan_sink=plan_sink,
+                plan_sink=plan_sink, rng=sub,
             )
         else:
             # copy: the scan donates its weight buffer; the caller keeps params

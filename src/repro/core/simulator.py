@@ -46,6 +46,7 @@ from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
 from repro.core import encoding
+from repro.core import stdp as stdp_lib
 from repro.core.types import ColumnConfig, NetworkConfig, TIME_DTYPE
 from repro.kernels import fused_column
 
@@ -260,6 +261,31 @@ def assign_time_series(
 
 
 # --------------------------------------------------- batched design sweep
+# Counters the sweep records (``repro.obs.count``, while a profiler runs):
+# designs whose batched assign took the Mosaic kernel / the reference
+# body, counted per bucket where the lowering is chosen, and designs the
+# degradation ladder evaluated on the 'cycle' solver rung.
+ASSIGN_MOSAIC = "sim.assign_mosaic"
+ASSIGN_REFERENCE = "sim.assign_reference"
+SOLVER_DESIGNS = "sim.solver_designs"
+SWEEP_COUNTERS = (ASSIGN_MOSAIC, ASSIGN_REFERENCE, SOLVER_DESIGNS)
+
+
+def _fit_sharded(w, xs, thresholds, t_maxes, q_actives, keys, **statics):
+    """``fused_column.fit_scan_padded`` with the stream keys positional
+    (None for expected-mode STDP), so ``backend.shard_designs`` shards
+    them with their designs (a module-level function: the shard wrapper
+    memoizes on it)."""
+    return fused_column.fit_scan_padded(
+        w, xs, thresholds, t_maxes, q_actives, keys=keys,
+        stochastic=keys is not None, **statics,
+    )
+
+
+# the sharded fit's program is named like the unsharded one's in profiles
+_fit_sharded.__name__ = _fit_sharded.__qualname__ = "fit_scan_padded"
+
+
 def _sweep_bucket(
     cfgs: Sequence[ColumnConfig],
     idxs: Sequence[int],
@@ -268,6 +294,7 @@ def _sweep_bucket(
     w_init: Sequence[np.ndarray],
     epochs: int,
     lowering: str,
+    stream_keys: Optional[Sequence] = None,
 ) -> tuple[np.ndarray, list[jnp.ndarray], int, dict]:
     """Train + assign one envelope bucket of a design sweep.
 
@@ -288,6 +315,9 @@ def _sweep_bucket(
     (``backend.execution_plan``; the documented constants when no device
     calibration is active) — observability rides along in the returned
     plan metadata.
+
+    ``stream_keys`` (stochastic STDP: one [2] i32 stream key per design
+    of the sweep, None otherwise) ride the design axis like ``w_init``.
 
     Returns (assignments [Db, N], cropped per-design weights, shard
     count, plan metadata dict).
@@ -327,6 +357,11 @@ def _sweep_bucket(
                 [cfgs[i].t_max for i in idxs], TIME_DTYPE
             )
             q_actives = jnp.asarray([cfgs[i].q for i in idxs], TIME_DTYPE)
+            keys = None
+            if stream_keys is not None:
+                keys = jnp.asarray(
+                    np.stack([np.asarray(stream_keys[i]) for i in idxs])
+                )
 
             # the bucket's execution plan: blocking + sharding for this
             # envelope (cost model when calibrated, the documented constants
@@ -356,6 +391,8 @@ def _sweep_bucket(
             thresholds = backend_lib.shard_design_axis(mesh, thresholds)
             t_maxes = backend_lib.shard_design_axis(mesh, t_maxes)
             q_actives = backend_lib.shard_design_axis(mesh, q_actives)
+            if keys is not None:
+                keys = backend_lib.shard_design_axis(mesh, keys)
 
         fit_kw = dict(
             t_window=t_window, w_max=c0.neuron.w_max, wta_k=c0.wta.k,
@@ -372,16 +409,17 @@ def _sweep_bucket(
                 # executable across sweep calls (and across processes under
                 # backend.compile_cache)
                 w = backend_lib.fit_padded(
-                    w0, xs, thresholds, t_maxes, q_actives, **fit_kw
+                    w0, xs, thresholds, t_maxes, q_actives, keys=keys,
+                    **fit_kw
                 )
             else:
                 # sharded operands: each device runs the jitted scan on its
                 # own designs (shard_map — Mosaic kernels cannot be
                 # auto-partitioned); the plan rides along as a hashable static
                 w = backend_lib.shard_designs(
-                    mesh, fused_column.fit_scan_padded, (0, 1, 0, 0, 0),
+                    mesh, _fit_sharded, (0, 1, 0, 0, 0, 0),
                     plan=fit_plan, **fit_kw,
-                )(w0, xs, thresholds, t_maxes, q_actives)
+                )(w0, xs, thresholds, t_maxes, q_actives, keys)
         # assignment batches volleys (kernel grid / vmapped blocks); the
         # kernel fires on the integer weight grid, so it is only
         # auto-selected when the trained weights concretely sit on that grid
@@ -391,6 +429,10 @@ def _sweep_bucket(
             asg_lowering = backend_lib.assign_lowering(
                 c0.neuron.response, w
             )
+        obs.count(
+            ASSIGN_REFERENCE if asg_lowering == "reference" else ASSIGN_MOSAIC,
+            db,
+        )
         asg_kw = dict(
             t_window=t_window, wta_k=c0.wta.k,
             response=c0.neuron.response, lowering=asg_lowering,
@@ -418,17 +460,27 @@ def _sweep_bucket(
 
 
 def _eval_design_solver(
-    cfg: ColumnConfig, volleys: jnp.ndarray, w0: np.ndarray, epochs: int
+    cfg: ColumnConfig, volleys: jnp.ndarray, w0: np.ndarray, epochs: int,
+    stream_key=None,
 ) -> tuple[np.ndarray, jnp.ndarray]:
     """Bottom-rung ('cycle') evaluation of ONE design on the solver scan.
 
     Only reached when ``backend.cycle_exact`` holds for the design, i.e.
-    the solver is bit-identical to the fused path (integer STDP steps, no
-    stabilizer, integer init weights) — the ladder never trades semantics
-    for availability.
+    the solver is bit-identical to the fused path (integer init weights
+    and integer steps: stochastic STDP on the design's stream key, or
+    integer-mu expected STDP with no stabilizer) — the ladder never trades
+    semantics for availability.
     """
+    rng = None
+    if stream_key is not None:
+        # a raw key whose words are the stream key: the solver draws from
+        # stdp.stream_key(rng), the very key the fused rungs were given
+        rng = jax.lax.bitcast_convert_type(
+            jnp.asarray(stream_key, jnp.int32), jnp.uint32
+        )
     params = column_lib.fit(
-        {"w": jnp.asarray(w0)}, volleys, cfg, epochs=epochs, mode="cycle"
+        {"w": jnp.asarray(w0)}, volleys, cfg, epochs=epochs, mode="cycle",
+        rng=rng,
     )
     asg = np.asarray(
         column_lib.cluster_assignments(params, volleys, cfg, "cycle")
@@ -472,6 +524,7 @@ def _eval_bucket_guarded(
     w_init: Sequence[np.ndarray],
     epochs: int,
     lowering: str,
+    stream_keys: Optional[Sequence] = None,
 ) -> list:
     """Fault-isolated evaluation of one envelope bucket.
 
@@ -494,7 +547,7 @@ def _eval_bucket_guarded(
     for low in ladder:
         try:
             asg_b, w_b, shards, plan_meta = _sweep_bucket(
-                cfgs, idxs, envelope, enc, w_init, epochs, low
+                cfgs, idxs, envelope, enc, w_init, epochs, low, stream_keys
             )
             return [
                 ("ok", asg_b[j], w_b[j], shards, low, len(attempts),
@@ -518,13 +571,15 @@ def _eval_bucket_guarded(
             try:
                 if low == "cycle":
                     asg_i, w_i = _eval_design_solver(
-                        c, enc[i], w_init[i], epochs
+                        c, enc[i], w_init[i], epochs,
+                        None if stream_keys is None else stream_keys[i],
                     )
+                    obs.count(SOLVER_DESIGNS)
                     plan_i = None
                 else:
                     asg_1, w_1, _, plan_i = _sweep_bucket(
                         cfgs, [i], (c.p, c.q, c.t_max), enc, w_init,
-                        epochs, low,
+                        epochs, low, stream_keys,
                     )
                     asg_i, w_i = asg_1[0], w_1[0]
                 done = ("ok", asg_i, w_i, 1, low, len(d_attempts), plan_i)
@@ -560,6 +615,7 @@ def cluster_time_series_many(
     w_init: Optional[Sequence[np.ndarray]] = None,
     bucket_callback: Optional[Callable] = None,
     monitor=None,
+    stream_keys: Optional[Sequence] = None,
 ) -> list[SweepOutcome]:
     """Sweep several column designs over one stream, envelope-bucketed.
 
@@ -590,12 +646,13 @@ def cluster_time_series_many(
     ``ClusteringResult.shards``.
 
     This front-end always trains on the fused path (there is no ``mode``
-    knob): every design must fit the fused contract — expected-mode STDP,
-    index tie-break WTA, and a response the selected lowering supports —
-    or the sweep raises up front.  The fused path is deterministic, so
-    ``seed`` only feeds weight initialization — split per design BEFORE
-    bucketing, so equal seeds reproduce the sweep bit-for-bit on every
-    host under every bucketing/sharding.  An empty stream (N=0) raises a
+    knob): every design must fit the fused contract — expected- or
+    stochastic-mode STDP, index tie-break WTA, and a response the selected
+    lowering supports — or the sweep raises up front.  ``seed`` feeds
+    weight initialization and, under stochastic STDP, each design's
+    stream key — both split per design BEFORE bucketing, so equal seeds
+    reproduce the sweep bit-for-bit on every host under every
+    bucketing/sharding.  An empty stream (N=0) raises a
     ValueError up front; ``epochs=0`` is well-defined and returns the
     designs' init weights with assignments from those weights.
 
@@ -624,7 +681,11 @@ def cluster_time_series_many(
     ``w_init`` overrides the seed-derived per-design init weights (one
     ``[p, q]`` array per config) — ``dse.explore`` uses it to key inits
     by *candidate* rather than by position, so journal-resumed partial
-    sweeps reproduce the full run exactly.  ``bucket_callback(idxs,
+    sweeps reproduce the full run exactly.  ``stream_keys`` does the same
+    for stochastic STDP's per-design stream keys (one [2] i32 array per
+    config, ``stdp.stream_key``); by default design i draws under
+    ``stream_key(fold_in(rng, i))``, ``rng`` being the half of
+    ``split(key(seed))`` that init does not use.  ``bucket_callback(idxs,
     results)`` fires after each bucket's outcomes are final (the journal
     hook: a kill loses at most one bucket); ``monitor`` is an optional
     ``distributed.straggler.StepMonitor`` whose ``start``/``stop``
@@ -692,6 +753,19 @@ def cluster_time_series_many(
                     raise ValueError(
                         f"w_init shape {w.shape} != design shape {(c.p, c.q)}"
                     )
+        if c0.stdp.mode != "stochastic":
+            stream_keys = None
+        elif stream_keys is None:
+            root, _ = jax.random.split(jax.random.key(seed))
+            stream_keys = [
+                np.asarray(stdp_lib.stream_key(jax.random.fold_in(root, i)))
+                for i in range(d)
+            ]
+        elif len(stream_keys) != d:
+            raise ValueError(
+                f"stream_keys must provide one key per config "
+                f"({len(stream_keys)} != {d})"
+            )
 
         buckets = backend_lib.envelope_buckets(
             [(c.p, c.q, c.t_max) for c in cfgs],
@@ -710,11 +784,13 @@ def cluster_time_series_many(
                 monitor.start()
             if on_error == "isolate":
                 evals = _eval_bucket_guarded(
-                    cfgs, idxs, envelope, enc, w_init, epochs, lowering
+                    cfgs, idxs, envelope, enc, w_init, epochs, lowering,
+                    stream_keys,
                 )
             else:
                 asg_b, w_b, shards, plan_meta = _sweep_bucket(
-                    cfgs, idxs, envelope, enc, w_init, epochs, lowering
+                    cfgs, idxs, envelope, enc, w_init, epochs, lowering,
+                    stream_keys,
                 )
                 evals = [
                     ("ok", asg_b[j], w_b[j], shards, lowering, 0, plan_meta)

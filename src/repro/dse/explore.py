@@ -33,6 +33,7 @@ from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
 from repro.core import simulator
+from repro.core import stdp as stdp_lib
 from repro.distributed.straggler import StepMonitor
 from repro.dse import journal as journal_lib
 from repro.dse.pareto import DesignPoint, pareto_front
@@ -123,11 +124,12 @@ def explore(
         uniform draws from it, deterministic per ``seed``).
       budget: candidate cap; required for 'random', optional for 'grid'
         (truncates the deterministic grid order).
-      seed: feeds both candidate sampling and per-design weight init,
-        so equal seeds reproduce the exploration exactly.  Init weights
+      seed: feeds candidate sampling, per-design weight init and, for a
+        stochastic-STDP space, each design's stream key, so equal seeds
+        reproduce the exploration exactly.  Init weights and stream keys
         are keyed by (seed, candidate index) — never by sweep position —
-        so results are invariant to grouping, bucketing, and resume
-        subsets.
+        so results are invariant to grouping, bucketing, sharding and
+        resume subsets.
       forecaster: any object with ``area_um2(synapses)`` /
         ``leakage_uw(synapses)`` — ``hwgen.forecast.PaperForecaster``
         (TNN7 regression) by default; pass a refit
@@ -184,7 +186,10 @@ def explore(
     with obs.span("dse.explore", candidates=len(candidates)):
         series = np.asarray(series)
         n_cand = len(candidates)
-        cfgs_all = [candidate_config(c, series.shape[1]) for c in candidates]
+        cfgs_all = [
+            candidate_config(c, series.shape[1], space.stdp)
+            for c in candidates
+        ]
         fps = [
             journal_lib.candidate_fingerprint(cfg, c.encoder, seed, epochs)
             for cfg, c in zip(cfgs_all, candidates)
@@ -196,7 +201,7 @@ def explore(
         restored: dict = {}
         if jr is not None:
             restored = jr.begin(
-                {"seed": int(seed), "epochs": int(epochs), "search": search},
+                journal_lib.run_meta(seed, epochs, search, space.stdp),
                 resume=resume,
             )
             # journaled runs are the long-lived ones: enable the persistent
@@ -261,10 +266,11 @@ def explore(
                     }
                 )
 
-        # init weights keyed per CANDIDATE index (fold_in), not per sweep
-        # position: a resumed partial sweep hands every design the same init
-        # the full sweep would have, so resume is bit-identical
-        _, init_key = jax.random.split(jax.random.key(seed))
+        # init weights (and stochastic STDP's stream keys) keyed per
+        # CANDIDATE index (fold_in), not per sweep position: a resumed
+        # partial sweep hands every design the same init and stream the
+        # full sweep would have, so resume is bit-identical
+        stream_root, init_key = jax.random.split(jax.random.key(seed))
 
         t0 = time.perf_counter()
         for encoder in dict.fromkeys(candidates[i].encoder for i in pending):
@@ -279,6 +285,14 @@ def explore(
                     )
                     for i in idxs
                 ]
+                stream_keys = None
+                if space.stdp.mode == "stochastic":
+                    stream_keys = [
+                        np.asarray(stdp_lib.stream_key(
+                            jax.random.fold_in(stream_root, i)
+                        ))
+                        for i in idxs
+                    ]
 
             def on_bucket(local_idxs, results, idxs=idxs, encoder=encoder):
                 with obs.span("dse.record"):
@@ -345,7 +359,7 @@ def explore(
                 series, labels, cfgs, epochs=epochs, seed=seed,
                 encoder=encoder, waste_cap=waste_cap, max_bucket=max_bucket,
                 on_error=on_error, w_init=w_init, bucket_callback=on_bucket,
-                monitor=mon,
+                monitor=mon, stream_keys=stream_keys,
             )
         seconds = time.perf_counter() - t0
 

@@ -39,6 +39,19 @@ from repro.core.types import ColumnConfig
 JOURNAL_VERSION = 1
 
 
+def run_meta(seed: int, epochs: int, search: str, stdp) -> dict:
+    """The run header ``explore`` publishes and resume validates: seed,
+    epochs, search mode and — for a space whose STDP rule is not the
+    default — the rule itself (``dataclasses.asdict`` of the
+    ``STDPConfig``, exact in JSON), so a journal written under one rule
+    is never resumed under another.  A default-rule header is the one
+    journals have always carried, so they resume as before."""
+    meta = {"seed": int(seed), "epochs": int(epochs), "search": search}
+    if stdp != type(stdp)():
+        meta["stdp"] = dataclasses.asdict(stdp)
+    return meta
+
+
 def candidate_fingerprint(
     cfg: ColumnConfig, encoder: str, seed: int, epochs: int
 ) -> str:
@@ -67,8 +80,9 @@ class Journal:
 
     Record kinds (one JSON object per line):
 
-    * ``{"kind": "meta", "version", "seed", "epochs", "search"}`` — the
-      run header, written by ``begin`` and validated on resume.
+    * ``{"kind": "meta", "version", "seed", "epochs", "search"[,
+      "stdp"]}`` — the run header (``run_meta``), written by ``begin``
+      and validated on resume.
     * ``{"kind": "point", "fp", "index", "encoder", "cand", "rand_index",
       "synapses", "area_um2", "leakage_uw", "lowering", "buckets",
       "shards", "retries", "w"}`` — one scored design; ``w`` is the
@@ -139,8 +153,10 @@ class Journal:
                     f"journal {self.path!r} has no meta header — not an "
                     "explore journal?"
                 )
-            for key, want in meta.items():
-                have = head.get(key)
+            # every key either header states: a rule one of them records
+            # and the other leaves at its default is a mismatch too
+            for key in sorted(set(meta) | set(head) - {"kind", "version"}):
+                have, want = head.get(key), meta.get(key)
                 if have != want:
                     raise ValueError(
                         f"journal {self.path!r} was written by a run with "

@@ -5,9 +5,10 @@ sensory processing unit for a stream: neuron count ``q`` (cluster
 capacity), temporal window ``t_max`` (gamma-cycle length), firing
 threshold (as a scale on the simulator's operating-point suggestion,
 so one scale means the same thing across geometries), and the spike
-encoder.  ``grid`` enumerates the full cross product; ``sample`` draws a
-random subset for large spaces — the two search modes ``dse.explore``
-offers.
+encoder; the STDP rule every candidate learns with is one field of the
+space (expected-mode by default, or the hardware's stochastic rule).
+``grid`` enumerates the full cross product; ``sample`` draws a random
+subset for large spaces — the two search modes ``dse.explore`` offers.
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ import random
 from typing import Sequence
 
 from repro.core import simulator
-from repro.core.types import ColumnConfig
+from repro.core.types import ColumnConfig, STDPConfig
 
 ENCODERS = ("latency", "onoff")
 
@@ -48,12 +49,16 @@ class DesignSpace:
       encoder: spike encoders ('latency' and/or 'onoff'); 'onoff' doubles
         the input width p, so candidates with different encoders sweep in
         separate compiled programs.
+      stdp: the STDP rule of every candidate (not an axis): expected-mode
+        by default, ``STDPConfig(mode='stochastic')`` for the LFSR-gated
+        unit-counter rule the TNN7 silicon implements.
     """
 
     q: Sequence[int]
     t_max: Sequence[int]
     threshold_scale: Sequence[float] = (1.0,)
     encoder: Sequence[str] = ("latency",)
+    stdp: STDPConfig = STDPConfig()
 
     def __post_init__(self):
         for axis in ("q", "t_max", "threshold_scale", "encoder"):
@@ -89,15 +94,17 @@ class DesignSpace:
         return rng.sample(grid, n)
 
 
-def candidate_config(cand: Candidate, series_len: int) -> ColumnConfig:
+def candidate_config(
+    cand: Candidate, series_len: int, stdp: STDPConfig = STDPConfig()
+) -> ColumnConfig:
     """Materialize a candidate into a ``ColumnConfig`` for an [N, L] stream.
 
     The encoder pins the input width (latency: p == L, on/off: p == 2L);
     the threshold is ``threshold_scale`` times the suggested operating
-    point for the resulting geometry.
+    point for the resulting geometry; ``stdp`` is the space's rule.
     """
     p = series_len if cand.encoder == "latency" else 2 * series_len
-    cfg = ColumnConfig(p=p, q=cand.q, t_max=cand.t_max)
+    cfg = ColumnConfig(p=p, q=cand.q, t_max=cand.t_max, stdp=stdp)
     return cfg.with_threshold(
         cand.threshold_scale * simulator.suggest_threshold(cfg)
     )

@@ -26,9 +26,15 @@ step exists in two lowerings behind the same semantics:
   for validation via ``lowering='interpret'``.
 
 Scope (enforced by ``check_fusable``): ``response in ('rnl', 'snl')``
-(``'rnl'`` only for the Pallas lowering), expected-mode STDP, index
-tie-break WTA.  Other configs take the generic per-solver scan in
-``repro.core.backend``.
+(``'rnl'`` only for the Pallas lowering), expected- or stochastic-mode
+STDP, index tie-break WTA.  Other configs take the generic per-solver scan
+in ``repro.core.backend``.  Stochastic STDP is a static flag of the
+envelope: the STDP stage then draws each synapse's uniform from the
+counter-based stream of ``repro.core.stdp`` (per-design stream keys and
+the global volley index ride as runtime operands beside the design
+operands) and applies ``stdp.stochastic_update`` — the same bits and the
+same rule as the solvers, so every lowering stays bit-identical to
+``mode='cycle'`` from integer initial counters.
 
 The per-design quantities (threshold, t_max, active q, STDP mus) are traced
 values in *both* lowerings — the reference ``vmap``s over them, the kernel
@@ -61,6 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import stdp as stdp_lib
 from repro.core.types import ColumnConfig, TIME_DTYPE
 from repro.kernels import ref
 
@@ -133,8 +140,8 @@ def check_fusable(cfg: ColumnConfig, lowering: str) -> None:
             f"fused step ({lowering}) supports response {ok_resp}, got "
             f"{cfg.neuron.response!r}"
         )
-    if cfg.stdp.mode != "expected":
-        raise ValueError("fused step supports expected-mode STDP only")
+    if cfg.stdp.mode not in ("expected", "stochastic"):
+        raise ValueError(f"unknown STDP mode: {cfg.stdp.mode!r}")
     if cfg.wta.tie_break != "index":
         raise ValueError("fused step supports index tie-break WTA only")
 
@@ -257,6 +264,7 @@ def fused_step_ref(
     response: str = "rnl",
     integer_fire: bool = False,
     q_active=None,
+    stream=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One fused column step, jnp lowering.  Returns (w_new, y).
 
@@ -269,6 +277,8 @@ def fused_step_ref(
       t_window: static dense evaluation length (>= t_max).
       integer_fire: round weights to the hardware integer grid for the fire
         step (the Pallas lowering always does; planes need w in {0..w_max}).
+      stream: None for expected STDP, else ``(key, volley)`` — the design's
+        stream key and the global volley index of stochastic STDP.
     """
     if t_max is None:
         t_max = t_window
@@ -278,14 +288,36 @@ def fused_step_ref(
         qi = jnp.arange(w.shape[1], dtype=TIME_DTYPE)
         t_fire = jnp.where(qi < q_active, t_fire, t_max)
     y = ref.wta_ref(t_fire[None], wta_k, t_max)[0]
-    w_new = ref.stdp_ref(
-        w, t_in, y, mu_capture, mu_backoff, mu_search, w_max, t_max,
-        stabilize=stabilize,
+    w_new = _stdp_ref(
+        w, t_in, y, t_max, mu_capture, mu_backoff, mu_search, stream,
+        w_max=w_max, stabilize=stabilize,
     )
     if q_active is not None:
         qi = jnp.arange(w.shape[1], dtype=TIME_DTYPE)
         w_new = jnp.where(qi[None, :] < q_active, w_new, w)
     return w_new, y
+
+
+def _stdp_ref(
+    w, t_in, y, t_max, mu_capture, mu_backoff, mu_search, stream, *,
+    w_max, stabilize,
+):
+    """STDP of one volley on [p, q] weights, jnp lowering: expected mode
+    (``ref.stdp_ref``) when ``stream`` is None, else stochastic mode with
+    ``stream`` = ``(key, volley)``."""
+    if stream is None:
+        return ref.stdp_ref(
+            w, t_in, y, mu_capture, mu_backoff, mu_search, w_max, t_max,
+            stabilize=stabilize,
+        )
+    key, volley = stream
+    u = stdp_lib.stream_uniform(
+        key, volley, stdp_lib.synapse_counter(w.shape)
+    )
+    return stdp_lib.stochastic_update(
+        w, t_in[:, None], y[None, :], t_max, mu_capture, mu_backoff,
+        mu_search, u, w_max=w_max, stabilize=stabilize,
+    )
 
 
 def _block_step_ref(
@@ -295,6 +327,7 @@ def _block_step_ref(
     threshold,
     t_max,
     q_active,
+    stream=None,
     *,
     t_window: int,
     w_max: int,
@@ -315,7 +348,8 @@ def _block_step_ref(
     without a sort in the hot loop).  ``valid`` (traced bool OK) marks
     silent-padded block-tail volleys, which must fold nothing for ANY
     design; it rides the existing out-of-envelope mask, costing no extra
-    op.  [p, q], [p, T], [p] -> [p, q].
+    op.  ``stream`` as in ``fused_step_ref``.  [p, q], [p, T], [p] ->
+    [p, q].
     """
     q = w.shape[1]
     qi = jnp.arange(q, dtype=TIME_DTYPE)
@@ -328,9 +362,9 @@ def _block_step_ref(
     y = _kernel_wta(
         t_fire, qi, t_max, wta_k=wta_k, t_window=t_window
     ).astype(TIME_DTYPE)
-    w_new = ref.stdp_ref(
-        w, xt, y, mu_capture, mu_backoff, mu_search, w_max, t_max,
-        stabilize=stabilize,
+    w_new = _stdp_ref(
+        w, xt, y, t_max, mu_capture, mu_backoff, mu_search, stream,
+        w_max=w_max, stabilize=stabilize,
     )
     return jnp.where((qi[None, :] < q_active) & valid, w_new, w)
 
@@ -447,10 +481,25 @@ def _kernel_wta(t_fire, qi, t_max, *, wta_k, t_window):
 
 def _kernel_stdp(
     w, ti_col, y, qi, t_max, q_live,
-    mu_capture, mu_backoff, mu_search, *, w_max, stabilize,
+    mu_capture, mu_backoff, mu_search, *, w_max, stabilize, stream=None,
 ):
     """Expected STDP on the resident float weights (same algebra as
-    ``kernels/ref.stdp_ref``), padded neurons (>= ``q_live``) frozen."""
+    ``kernels/ref.stdp_ref``), padded neurons (>= ``q_live``) frozen.
+
+    With ``stream`` — ``(key, volley, counter)``: the design's (k0, k1)
+    SMEM scalars, the global volley index and the resident
+    ``stdp.synapse_counter`` block — it is stochastic STDP instead: the
+    stream's Threefry rounds in int32 VPU ops, then
+    ``stdp.stochastic_update``.
+    """
+    if stream is not None:
+        key, volley, counter = stream
+        u = stdp_lib.stream_uniform(key, volley, counter)
+        w_new = stdp_lib.stochastic_update(
+            w, ti_col, y, t_max, mu_capture, mu_backoff, mu_search, u,
+            w_max=w_max, stabilize=stabilize,
+        )
+        return jnp.where(qi < q_live, w_new, w)
     xs = ti_col < t_max
     ys = y < t_max
     if stabilize:
@@ -470,19 +519,29 @@ def _kernel_stdp(
     return jnp.clip(w + delta, 0.0, float(w_max))
 
 
+def _stream_operands(keys, volley):
+    """The stochastic kernels' extra SMEM operands and their specs: [D, 2]
+    i32 stream keys and the [1] i32 volley index; none for expected STDP
+    (``keys`` None)."""
+    if keys is None:
+        return (), []
+    ops = (
+        keys.astype(jnp.int32),
+        jnp.asarray(volley, jnp.int32).reshape((1,)),
+    )
+    return ops, [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+
+
 def _fused_kernel(
     scal_ref,  # [D, N_OPERANDS] f32 SMEM runtime design operands
-    t_ref,  # [1, 1, p_pad]      f32 input volley (silent >= design t_max)
-    w_ref,  # [1, p_pad, q_pad]  f32 resident weights
-    w_out,  # [1, p_pad, q_pad]  f32 updated weights
-    y_out,  # [1, 1, q_pad]      f32 counts accumulator -> winner times
-    *,
+    *refs,
     t_blk: int,
     t_window: int,
     n_planes: int,
     wta_k: int,
     w_max: int,
     stabilize: bool,
+    stochastic: bool = False,
 ):
     """Fused fire + k-WTA + expected-STDP body, grid = (designs, time blocks).
 
@@ -492,7 +551,17 @@ def _fused_kernel(
     ``q_active``, STDP mus — is read from ``scal_ref`` at run time and
     masked against the envelope, so one compiled kernel serves a whole
     heterogeneous design batch.
+
+    ``refs``: ``t_ref`` [1, 1, p_pad] f32 input volley (silent >= design
+    t_max), ``w_ref`` / ``w_out`` [1, p_pad, q_pad] f32 resident and
+    updated weights, ``y_out`` [1, 1, q_pad] f32 counts accumulator ->
+    winner times; under ``stochastic`` they follow ``key_ref`` [D, 2] i32
+    (SMEM stream keys) and ``vb_ref`` [1] i32 (SMEM volley index).
     """
+    if stochastic:
+        key_ref, vb_ref, t_ref, w_ref, w_out, y_out = refs
+    else:
+        t_ref, w_ref, w_out, y_out = refs
     _, p_pad, q_pad = w_ref.shape
     d = pl.program_id(0)
     i = pl.program_id(1)
@@ -527,10 +596,14 @@ def _fused_kernel(
         t_fire = jnp.where(qi < q_live, t_fire, t_max)  # pad neurons silent
         y = _kernel_wta(t_fire, qi, t_max, wta_k=wta_k, t_window=t_window)
         y_out[0] = y
+        stream = None
+        if stochastic:
+            stream = ((key_ref[d, 0], key_ref[d, 1]), vb_ref[0],
+                      stdp_lib.synapse_counter((p_pad, q_pad)))
         w_out[0] = _kernel_stdp(
             w, ti, y, qi, t_max, q_live,
             mu_capture, mu_backoff, mu_search,
-            w_max=w_max, stabilize=stabilize,
+            w_max=w_max, stabilize=stabilize, stream=stream,
         )
 
     @pl.when(i != last)
@@ -549,6 +622,8 @@ def fused_step_pallas_padded(
     stabilize: bool,
     t_blk: int = 128,
     interpret: bool = False,
+    keys=None,
+    volley=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One fused Pallas step for a whole padded design batch.
 
@@ -562,12 +637,15 @@ def fused_step_pallas_padded(
         ``t_max``); padded up to a ``t_blk`` multiple.
       interpret: run under the Pallas interpreter — pass the value from
         ``repro.core.backend.pallas_interpret()``; do not hardcode.
+      keys / volley: stochastic STDP only — [D, 2] i32 stream keys and the
+        i32 global volley index (None: expected STDP).
 
     Returns:
       (w_new [D, p_pad, q_pad], y [D, q_pad] post-WTA winner times, f32).
     """
     d, p_pad, q_pad = w.shape
     t_pad = _pad_to(t_window, t_blk)
+    stochastic = keys is not None
     kern = functools.partial(
         _fused_kernel,
         t_blk=t_blk,
@@ -576,7 +654,9 @@ def fused_step_pallas_padded(
         wta_k=wta_k,
         w_max=w_max,
         stabilize=stabilize,
+        stochastic=stochastic,
     )
+    stream_ops, stream_specs = _stream_operands(keys, volley)
     # volleys and counts ride a unit middle axis, so every block's last
     # two dims are whole array dims (the Mosaic (8, 128) tiling rule)
     w_new, y = pl.pallas_call(
@@ -584,6 +664,7 @@ def fused_step_pallas_padded(
         grid=(d, t_pad // t_blk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            *stream_specs,
             pl.BlockSpec((1, 1, p_pad), lambda di, i: (di, 0, 0)),
             pl.BlockSpec((1, p_pad, q_pad), lambda di, i: (di, 0, 0)),
         ],
@@ -597,17 +678,14 @@ def fused_step_pallas_padded(
         ],
         interpret=interpret,
         name="fused_column_step",
-    )(operands, t_in[:, None, :], w)
+    )(operands, *stream_ops, t_in[:, None, :], w)
     return w_new, y[:, 0, :]
 
 
 def _fused_block_kernel(
     scal_ref,  # [D, N_OPERANDS] f32 SMEM runtime design operands
     nv_ref,  # [1] i32 SMEM      valid volleys in this block (tail masking)
-    t_ref,  # [1, v_blk, p_pad]  f32 volley block (silent >= design t_max)
-    w_ref,  # [1, p_pad, q_pad]  f32 resident weights
-    w_out,  # [1, p_pad, q_pad]  f32 updated weights
-    *,
+    *refs,
     v_blk: int,
     t_blk: int,
     t_window: int,
@@ -615,6 +693,7 @@ def _fused_block_kernel(
     wta_k: int,
     w_max: int,
     stabilize: bool,
+    stochastic: bool = False,
 ):
     """Volley-blocked fused body: fire + k-WTA + STDP x ``v_blk`` volleys.
 
@@ -629,7 +708,18 @@ def _fused_block_kernel(
     runtime SMEM operands against the one static envelope.  Volleys at or
     past the runtime valid count (the silent-padded block tail) fold
     nothing.
+
+    ``refs``: ``t_ref`` [1, v_blk, p_pad] f32 volley block (silent >=
+    design t_max), ``w_ref`` / ``w_out`` [1, p_pad, q_pad] f32 resident and
+    updated weights; under ``stochastic`` they follow ``key_ref`` [D, 2]
+    i32 (SMEM stream keys) and ``vb_ref`` [1] i32 (SMEM global index of
+    the block's first volley), and volley ``vi`` of the block draws at
+    index ``vb + vi``.
     """
+    if stochastic:
+        key_ref, vb_ref, t_ref, w_ref, w_out = refs
+    else:
+        t_ref, w_ref, w_out = refs
     _, p_pad, q_pad = w_ref.shape
     d = pl.program_id(0)
     nv = nv_ref[0]
@@ -643,6 +733,10 @@ def _fused_block_kernel(
 
     qi = _lane_iota(q_pad)
     n_tb = t_window // t_blk
+    if stochastic:
+        key = (key_ref[d, 0], key_ref[d, 1])
+        vb = vb_ref[0]
+        counter = stdp_lib.synapse_counter((p_pad, q_pad))
 
     def volley(vi, w):
         ti = t_ref[0, pl.ds(vi, 1), :]  # [1, p_pad]
@@ -665,6 +759,7 @@ def _fused_block_kernel(
             w, ti_col, y, qi, t_max, q_live,
             mu_capture, mu_backoff, mu_search,
             w_max=w_max, stabilize=stabilize,
+            stream=(key, vb + vi, counter) if stochastic else None,
         )
         return jnp.where(vi < nv, w_new, w)  # tail volleys fold nothing
 
@@ -684,6 +779,8 @@ def fused_block_pallas_padded(
     v_blk: int,
     t_blk: int = 128,
     interpret: bool = False,
+    keys=None,
+    v_base=None,
 ) -> jnp.ndarray:
     """One volley-blocked fused Pallas step for a whole padded design batch.
 
@@ -698,6 +795,9 @@ def fused_block_pallas_padded(
         ``v_blk``); volleys at or past it fold nothing (tail masking).
       interpret: run under the Pallas interpreter — pass the value from
         ``repro.core.backend.pallas_interpret()``; do not hardcode.
+      keys / v_base: stochastic STDP only — [D, 2] i32 stream keys and
+        the i32 global index of the block's first volley (None: expected
+        STDP, and the kernel is the expected-mode program).
 
     Returns:
       w_new [D, p_pad, q_pad] — the weights after the block's ``v_blk``
@@ -707,6 +807,7 @@ def fused_block_pallas_padded(
     t_pad = _pad_to(t_window, t_blk)
     if n_valid is None:
         n_valid = jnp.full((1,), v_blk, TIME_DTYPE)
+    stochastic = keys is not None
     kern = functools.partial(
         _fused_block_kernel,
         v_blk=v_blk,
@@ -716,13 +817,16 @@ def fused_block_pallas_padded(
         wta_k=wta_k,
         w_max=w_max,
         stabilize=stabilize,
+        stochastic=stochastic,
     )
+    stream_ops, stream_specs = _stream_operands(keys, v_base)
     return pl.pallas_call(
         kern,
         grid=(d,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            *stream_specs,
             pl.BlockSpec((1, v_blk, p_pad), lambda di: (di, 0, 0)),
             pl.BlockSpec((1, p_pad, q_pad), lambda di: (di, 0, 0)),
         ],
@@ -730,7 +834,7 @@ def fused_block_pallas_padded(
         out_shape=jax.ShapeDtypeStruct((d, p_pad, q_pad), jnp.float32),
         interpret=interpret,
         name="fit_block",
-    )(operands, n_valid.astype(TIME_DTYPE), t_in, w)
+    )(operands, n_valid.astype(TIME_DTYPE), *stream_ops, t_in, w)
 
 
 def fused_step_pallas(
@@ -739,6 +843,7 @@ def fused_step_pallas(
     cfg: ColumnConfig,
     t_blk: int = 128,
     interpret: bool = False,
+    stream=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One fused Pallas column step on pre-padded single-column operands.
 
@@ -751,6 +856,8 @@ def fused_step_pallas(
       t_in_pad: [1, p_pad] volley (padding/silent >= cfg.t_max).
       interpret: run under the Pallas interpreter — pass the value from
         ``repro.core.backend.pallas_interpret()``; do not hardcode.
+      stream: stochastic STDP only — ``(key, volley)``, the [2] i32 stream
+        key and the global volley index.
 
     Returns:
       (w_new [p_pad, q_pad], y [1, q_pad] post-WTA winner times, float).
@@ -763,11 +870,14 @@ def fused_step_pallas(
         cfg.stdp.mu_backoff,
         cfg.stdp.mu_search,
     )
+    keys, volley = (None, None) if stream is None else stream
     w_new, y = fused_step_pallas_padded(
         w_pad[None], t_in_pad, operands,
         t_window=cfg.t_max, w_max=cfg.neuron.w_max, wta_k=cfg.wta.k,
         stabilize=cfg.stdp.stabilizer == "half",
         t_blk=t_blk, interpret=interpret,
+        keys=None if keys is None else jnp.reshape(keys, (1, 2)),
+        volley=volley,
     )
     return w_new[0], y
 
@@ -786,40 +896,63 @@ def _fused_fit_scan(
     lowering: str,
     trace: bool,
     t_blk: int = 128,
+    key=None,
 ):
     """One compiled program for the whole fit: scan(epochs) o scan(volleys).
 
     ``w`` is donated — the weight buffer is updated in place across the
-    entire training run instead of round-tripping per volley.
+    entire training run instead of round-tripping per volley.  ``key``
+    ([2] i32 stream key) is given exactly for stochastic STDP; volley n of
+    epoch e then draws at index e * N + n.
     """
     if lowering == "reference":
 
-        def volley(wc, xt):
+        def step(wc, xt, stream):
             # integer_fire mirrors the Pallas lowering (planes need the
             # hardware integer grid) so results agree across lowerings.
-            w2, y = fused_step_ref(
+            return fused_step_ref(
                 wc, xt, cfg.neuron.threshold, cfg.t_max, cfg.neuron.w_max,
                 cfg.wta.k, cfg.stdp.mu_capture, cfg.stdp.mu_backoff,
                 cfg.stdp.mu_search, cfg.stdp.stabilizer == "half",
                 response=cfg.neuron.response, integer_fire=True,
+                stream=stream,
             )
-            return w2, (y if trace else None)
 
     else:
 
-        def volley(wc, xt):
+        def step(wc, xt, stream):
             w2, y = fused_step_pallas(
                 wc, xt[None], cfg, t_blk=t_blk,
-                interpret=lowering == "interpret",
+                interpret=lowering == "interpret", stream=stream,
             )
-            yq = y[0, : cfg.q].astype(TIME_DTYPE)
-            return w2, (yq if trace else None)
+            return w2, y[0, : cfg.q].astype(TIME_DTYPE)
 
-    def epoch(wc, _):
-        return jax.lax.scan(volley, wc, xs)
+    if key is None:
 
-    w, ys = jax.lax.scan(epoch, w, None, length=epochs)
-    return w, ys
+        def volley(wc, xt):
+            w2, y = step(wc, xt, None)
+            return w2, (y if trace else None)
+
+        def epoch(wc, _):
+            return jax.lax.scan(volley, wc, xs)
+
+        w, ys = jax.lax.scan(epoch, w, None, length=epochs)
+        return w, ys
+
+    n = xs.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+
+    def volley_s(wc, inp):
+        xt, v = inp
+        w2, y = step(wc, xt, (key, v))
+        return w2, (y if trace else None)
+
+    def epoch_s(wc, e):
+        return jax.lax.scan(volley_s, wc, (xs, e * n + idx))
+
+    return jax.lax.scan(
+        epoch_s, w, jnp.arange(epochs, dtype=jnp.int32)
+    )
 
 
 # ----------------------------------------------------- padded envelope scan
@@ -827,7 +960,7 @@ def _fused_fit_scan(
     jax.jit,
     static_argnames=(
         "t_window", "w_max", "wta_k", "stabilize", "response", "epochs",
-        "lowering", "t_blk", "v_blk", "plan",
+        "lowering", "t_blk", "v_blk", "plan", "stochastic",
     ),
     donate_argnums=(0,),
 )
@@ -850,6 +983,8 @@ def fit_scan_padded(
     t_blk: int | None = None,
     v_blk: int | None = None,
     plan=None,
+    stochastic: bool = False,
+    keys=None,  # [D, 2] i32 stream keys (stochastic only)
 ):
     """All designs x all epochs x all volleys in ONE compiled program.
 
@@ -899,9 +1034,13 @@ def fit_scan_padded(
         where GSPMD needs the jit trace.  A plan changes blocking only,
         never results (value-equal plans share one trace).
 
-    This entry point is deterministic — expected-mode STDP and index
-    tie-break WTA need no PRNG key (that is part of the fused contract;
-    stochastic configs take the solver path via ``backend.resolve``).
+    Expected-mode STDP with index tie-break WTA needs no PRNG key.
+    ``stochastic=True`` (a static flag of the envelope; the expected-mode
+    program is untouched by it) selects stochastic STDP: ``keys`` carries
+    each design's stream key, sharded and bucketed with its design, and
+    volley n of epoch e draws at global index ``e * N + n`` — the
+    same bits ``mode='cycle'`` draws, whatever the envelope, block size,
+    bucket or shard.
 
     ``w`` is donated: the weight buffer stays resident across the whole
     epochs x volleys scan.
@@ -930,6 +1069,10 @@ def fit_scan_padded(
         from repro.core import backend  # late: backend imports this module
 
         v_blk = backend.volley_block(lowering, xs.shape[0], d=w.shape[0])
+    if stochastic:
+        if keys is None:
+            raise ValueError("stochastic STDP needs per-design stream keys")
+        keys = keys.astype(jnp.int32)
     # a stable name for the fit's operations in profiles
     with jax.named_scope("fit_scan_padded"):
         if lowering != "reference":
@@ -943,6 +1086,7 @@ def fit_scan_padded(
                 w, xs, thresholds, t_maxes, q_actives,
                 t_window, w_max, wta_k, mu_capture, mu_backoff, mu_search,
                 stabilize, epochs, lowering, t_blk, v_blk,
+                keys=keys if stochastic else None,
             )
 
         # [S, v_blk, D, p]
@@ -954,7 +1098,7 @@ def fit_scan_padded(
         )
 
         def block(wc, inp):  # wc: [D, p, q]; xt_blk: [v_blk, D, p]
-            xt_blk, nv = inp
+            xt_blk, nv = inp[:2]
             # the input-side step transient of the whole block at once — the
             # reference analogue of the kernel's VMEM-resident volley block:
             # only the cumulative weight planes, one GEMM and the plane delays
@@ -964,24 +1108,52 @@ def fit_scan_padded(
             )  # [v_blk, D, p, T]
             for i in range(v_blk):  # static unroll: one fused XLA body
                 valid = i < nv  # tail volleys fold nothing
+                vb = inp[2] + i if stochastic else None
                 wc = jax.vmap(
-                    lambda wd, sd, xd, th, tm, qa: _block_step_ref(
-                        wd, sd, xd, th, tm, qa, valid=valid, **kw
+                    lambda wd, sd, xd, th, tm, qa, kd: _block_step_ref(
+                        wd, sd, xd, th, tm, qa,
+                        None if kd is None else (kd, vb), valid=valid, **kw
                     )
-                )(wc, s[i], xt_blk[i], thresholds, t_maxes, q_actives)
+                )(wc, s[i], xt_blk[i], thresholds, t_maxes, q_actives,
+                  keys if stochastic else None)
             return wc, None
+
+        return _scan_epochs(
+            block, w, xsb, n_valid, epochs,
+            _block_bases(epochs, xs.shape[0], v_blk) if stochastic
+            else None,
+        )
+
+
+def _scan_epochs(block, w, xsb, n_valid, epochs: int, bases=None):
+    """Fold ``block`` over the volley blocks of every epoch.  ``bases``
+    ([epochs, S] i32, stochastic STDP only) rides as the third block input:
+    each block's global index of its first volley."""
+    if bases is None:
 
         def epoch(wc, _):
             return jax.lax.scan(block, wc, (xsb, n_valid))
 
-        w, _ = jax.lax.scan(epoch, w, None, length=epochs)
-        return w
+        return jax.lax.scan(epoch, w, None, length=epochs)[0]
+
+    def epoch_s(wc, vb_row):
+        return jax.lax.scan(block, wc, (xsb, n_valid, vb_row))
+
+    return jax.lax.scan(epoch_s, w, bases)[0]
+
+
+def _block_bases(epochs: int, n: int, v_blk: int):
+    """[epochs, S] global index of each volley block's first volley:
+    ``e * N + b * v_blk`` (the stochastic stream's volley index)."""
+    e = jnp.arange(epochs, dtype=jnp.int32)[:, None]
+    b = jnp.arange(-(-n // v_blk), dtype=jnp.int32)[None, :]
+    return e * n + b * v_blk
 
 
 def _fit_scan_padded_kernel(
     w, xs, thresholds, t_maxes, q_actives,
     t_window, w_max, wta_k, mu_capture, mu_backoff, mu_search,
-    stabilize, epochs, lowering, t_blk, v_blk,
+    stabilize, epochs, lowering, t_blk, v_blk, keys=None,
 ):
     """Kernel-lowering body of ``fit_scan_padded`` (called inside its jit).
 
@@ -1011,19 +1183,20 @@ def _fit_scan_padded_kernel(
     xsb = jnp.swapaxes(xsb, 1, 2)  # [S, D, v_blk, p_pad]: design axis leads
 
     def block(wc, inp):  # wc: [D, p_pad, q_pad]; xt: [D, v_blk, p_pad]
-        xt, nv = inp
+        xt, nv = inp[:2]
         w2 = fused_block_pallas_padded(
             wc, xt, operands, nv.reshape((1,)),
             t_window=t_window, w_max=w_max, wta_k=wta_k,
             stabilize=stabilize, v_blk=v_blk, t_blk=t_blk,
             interpret=lowering == "interpret",
+            keys=keys, v_base=None if keys is None else inp[2],
         )
         return w2, None
 
-    def epoch(wc, _):
-        return jax.lax.scan(block, wc, (xsb, n_valid))
-
-    w_k, _ = jax.lax.scan(epoch, w_k, None, length=epochs)
+    w_k = _scan_epochs(
+        block, w_k, xsb, n_valid, epochs,
+        None if keys is None else _block_bases(epochs, xs.shape[0], v_blk),
+    )
     return w_k[:, :p_env, :q_env]
 
 
@@ -1233,8 +1406,11 @@ def assign_padded(
 # very programs the jit path would build: bit-identical results, same
 # donation (``tests/test_aot_cache.py``).
 
-def _fit_scan_padded_specs(d: int, p_pad: int, q_pad: int, n_volleys: int):
-    """(args, mu kwargs) abstract specs mirroring one fit call exactly."""
+def _fit_scan_padded_specs(
+    d: int, p_pad: int, q_pad: int, n_volleys: int, stochastic: bool = False
+):
+    """(args, dynamic kwargs) abstract specs mirroring one fit call
+    exactly: the mus, and the stream keys of a stochastic fit."""
     f32 = jnp.float32
     args = (
         jax.ShapeDtypeStruct((d, p_pad, q_pad), f32),          # w
@@ -1247,6 +1423,8 @@ def _fit_scan_padded_specs(d: int, p_pad: int, q_pad: int, n_volleys: int):
         name: jax.ShapeDtypeStruct((), f32)
         for name in ("mu_capture", "mu_backoff", "mu_search")
     }
+    if stochastic:
+        mus["keys"] = jax.ShapeDtypeStruct((d, 2), jnp.int32)
     return args, mus
 
 
@@ -1265,26 +1443,29 @@ def precompile_fit_scan_padded(
     lowering: str = "reference",
     t_blk: int = 128,
     v_blk: int | None = None,
+    stochastic: bool = False,
 ):
     """AOT-compile ``fit_scan_padded`` for one envelope; no operands needed.
 
     Returns a ``jax.stages.Compiled`` executable.  Call it exactly like
     the dynamic half of the jitted entry point — five positional arrays
     ``(w, xs, thresholds, t_maxes, q_actives)`` matching the spec shapes
-    plus the three STDP mus by keyword as f32 scalars (the call's
-    args/kwargs pytree must mirror the lowering's) — and it behaves
+    plus the three STDP mus by keyword as f32 scalars, and for a
+    ``stochastic`` envelope ``keys`` ([D, 2] i32) by keyword too (the
+    call's args/kwargs pytree must mirror the lowering's) — and it behaves
     bit-for-bit like the jit path, including donating ``w``.
     """
     if v_blk is None:
         from repro.core import backend  # late: backend imports this module
 
         v_blk = backend.volley_block(lowering, n_volleys, d=d)
-    args, mus = _fit_scan_padded_specs(d, p_pad, q_pad, n_volleys)
+    args, dyn = _fit_scan_padded_specs(d, p_pad, q_pad, n_volleys, stochastic)
+    statics = {"stochastic": True} if stochastic else {}
     return fit_scan_padded.lower(
         *args,
-        t_window=t_window, w_max=w_max, wta_k=wta_k, **mus,
+        t_window=t_window, w_max=w_max, wta_k=wta_k, **dyn,
         stabilize=stabilize, response=response, epochs=epochs,
-        lowering=lowering, t_blk=t_blk, v_blk=v_blk,
+        lowering=lowering, t_blk=t_blk, v_blk=v_blk, **statics,
     ).compile()
 
 
@@ -1328,18 +1509,27 @@ def fit_fused(
     lowering: str = "reference",
     trace: bool = False,
     t_blk: int = 128,
+    rng=None,
 ) -> tuple[dict, jnp.ndarray | None]:
     """Online STDP over [N, p] volleys as ONE jitted, donated scan.
 
     Weight padding / plane setup happens here, once per fit — never per
     volley.  Returns (params, ys) where ys is [epochs, N, q] winner times
-    when ``trace`` else None.
+    when ``trace`` else None.  Stochastic STDP needs ``rng``: its stream
+    key is ``stdp.stream_key(rng)``, the one the solvers draw from.
     """
     check_fusable(cfg, lowering)
+    key = None
+    if cfg.stdp.mode == "stochastic":
+        if rng is None:
+            raise ValueError("stochastic STDP requires a PRNG key")
+        key = stdp_lib.stream_key(rng)
     # copy: the scan donates its weight buffer; the caller keeps params.
     w = jnp.array(params["w"], jnp.float32, copy=True)
     if lowering == "reference":
-        w_new, ys = _fused_fit_scan(w, x, cfg, epochs, lowering, trace)
+        w_new, ys = _fused_fit_scan(
+            w, x, cfg, epochs, lowering, trace, key=key
+        )
         return {"w": w_new}, ys
 
     p_pad = _pad_to(cfg.p, LANE)
@@ -1348,5 +1538,7 @@ def fit_fused(
     w_pad = jnp.zeros((p_pad, q_pad), jnp.float32).at[: cfg.p, : cfg.q].set(w)
     xs = _pad_volleys_silent(x, p_pad, 2.0 * t_pad)
     xs = jnp.where(xs >= cfg.t_max, 2.0 * t_pad, xs)
-    w_new, ys = _fused_fit_scan(w_pad, xs, cfg, epochs, lowering, trace, t_blk)
+    w_new, ys = _fused_fit_scan(
+        w_pad, xs, cfg, epochs, lowering, trace, t_blk, key=key
+    )
     return {"w": w_new[: cfg.p, : cfg.q]}, ys

@@ -350,6 +350,13 @@ class ClusteringService:
             fused_column.check_fusable(
                 c, backend_lib.padded_lowering(c.neuron.response)
             )
+            if c.stdp.mode != "expected":
+                # a stochastic re-fit must resume its stream where the
+                # last one stopped, and the WAL keeps no volley index yet
+                raise ValueError(
+                    f"design {n!r}: the service supports expected-mode "
+                    "STDP only"
+                )
             if c.neuron.threshold <= 0:
                 raise ValueError(
                     f"design {n!r}: threshold must be > 0 — the service "

@@ -108,14 +108,24 @@ def test_wta_tie_break_all_shares():
     mode=st.sampled_from(["expected", "stochastic"]),
 )
 def test_stdp_weights_stay_bounded(p, q, seed, mode):
+    """Weights stay in [0, w_max]; under the stochastic stream, integer
+    counters stay integers and move at most one LSB per volley."""
     rng = np.random.default_rng(seed)
-    w = jnp.asarray(rng.uniform(0, 7, (p, q)), jnp.float32)
+    if mode == "stochastic":
+        w = jnp.asarray(rng.integers(0, 8, (p, q)), jnp.float32)
+    else:
+        w = jnp.asarray(rng.uniform(0, 7, (p, q)), jnp.float32)
     x = jnp.asarray(rng.integers(0, 20, (p,)), jnp.int32)
     y = jnp.asarray(rng.integers(0, 20, (q,)), jnp.int32)
     cfg = STDPConfig(mode=mode)
-    w2 = stdp.stdp_update(w, x, y, cfg, 7, 16, rng=jax.random.key(seed))
+    w2 = stdp.stdp_update(
+        w, x, y, cfg, 7, 16, rng=jax.random.key(seed), volley=seed % 4096
+    )
     w2 = np.asarray(w2)
     assert np.all(w2 >= 0) and np.all(w2 <= 7)
+    if mode == "stochastic":
+        assert np.array_equal(w2, np.round(w2))
+        assert np.max(np.abs(w2 - np.asarray(w))) <= 1
 
 
 def test_stdp_capture_increases_weight():

@@ -196,15 +196,22 @@ def test_fused_rejects_unsupported_configs():
     with pytest.raises(ValueError):
         fused_column.check_fusable(lif, "reference")
     assert backend.resolve("auto", lif, training=True) == "cycle"
+    # stochastic STDP is inside the fused contract: it trains fused
     stoch = ColumnConfig(p=8, q=2, t_max=16, stdp=STDPConfig(mode="stochastic"))
-    assert backend.resolve("auto", stoch, training=True) == "event"
+    fused_column.check_fusable(stoch, "mosaic")
+    assert backend.resolve("auto", stoch, training=True) == "pallas"
     # forcing the pallas forward on LIF must raise, not silently run RNL/SNL
     params = {"w": jnp.ones((8, 2), jnp.float32)}
     x = jnp.zeros((3, 8), jnp.int32)
     with pytest.raises(ValueError, match="pallas forward"):
         column.apply(params, x, lif, "pallas")
-    # a single-design sweep must validate its (only) config too
+    # a single-design sweep must validate its (only) config too: LIF is
+    # refused, a stochastic design is swept
     rng = np.random.default_rng(8)
     series = rng.normal(size=(6, 8))
     with pytest.raises(ValueError):
-        simulator.cluster_time_series_many(series, None, [stoch], epochs=1)
+        simulator.cluster_time_series_many(series, None, [lif], epochs=1)
+    (res,) = simulator.cluster_time_series_many(
+        series, None, [stoch], epochs=1
+    )
+    assert res.lowering == backend.padded_lowering("rnl")

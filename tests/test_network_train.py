@@ -177,21 +177,29 @@ def test_network_resolves_per_layer_and_rejects_bad_pallas():
 
 
 def test_network_solver_layer_handles_stochastic_stdp():
-    """The solver layer scan carries the config surface the fused step
-    rejects (stochastic STDP needs per-volley PRNG plumbing per column)."""
+    """A stochastic-STDP layer trains on the fused path, each column on
+    its own stream from the layer's key — bit-identical to the solver
+    layer scan, which draws the same streams."""
     col = ColumnConfig(
         p=6, q=3, t_max=16,
         neuron=NeuronConfig(threshold=4.0),
         stdp=STDPConfig(mode="stochastic"),
     )
     net = NetworkConfig(layers=(LayerConfig(columns=2, column=col),))
-    assert backend.resolve("auto", col, training=True) == "event"
+    assert backend.resolve("auto", col, training=True) == "pallas"
     params, x = int_net_data(net, in_width=6, n=5, seed=3)
     t1 = network.fit_greedy(params, x, net, epochs=2, rng=jax.random.key(7))
     t2 = network.fit_greedy(params, x, net, epochs=2, rng=jax.random.key(7))
     np.testing.assert_array_equal(
         np.asarray(t1[0]["w"]), np.asarray(t2[0]["w"]),
         err_msg="same PRNG key must reproduce stochastic training exactly",
+    )
+    solver = network.fit_greedy(
+        params, x, net, epochs=2, mode="cycle", rng=jax.random.key(7)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(t1[0]["w"]), np.asarray(solver[0]["w"]),
+        err_msg="fused and solver layers must draw the same streams",
     )
     # no key may not be silently replaced by a fixed one (column parity)
     with pytest.raises(ValueError, match="PRNG key"):

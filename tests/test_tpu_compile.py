@@ -87,6 +87,19 @@ def test_fit_scan_padded_compiles_for_v5e(spec):
     _assert_mosaic(compiled)
 
 
+def test_stochastic_fit_scan_padded_compiles_for_v5e(spec):
+    """The stochastic envelope: the stream's Threefry rounds in int32 VPU
+    ops inside the fit kernel, with the keys and volley base in SMEM."""
+    mu = spec((), F32)
+    compiled = fused_column.fit_scan_padded.lower(
+        *_padded_operands(spec), t_window=T_WINDOW, w_max=7, wta_k=1,
+        mu_capture=mu, mu_backoff=mu, mu_search=mu, stabilize=True,
+        response="rnl", epochs=1, lowering="mosaic", t_blk=128, v_blk=32,
+        stochastic=True, keys=spec((D, 2), jnp.int32),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 def test_assign_padded_compiles_for_v5e(spec):
     compiled = fused_column.assign_padded.lower(
         *_padded_operands(spec), t_window=T_WINDOW, wta_k=1,
