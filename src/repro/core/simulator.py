@@ -138,15 +138,18 @@ def suggest_threshold(cfg: ColumnConfig) -> float:
     return max(1.0, 0.25 * cfg.p * cfg.neuron.w_max / 2.0)
 
 
-def _encode_width(
-    x: jnp.ndarray, t_max: int, width: int, encoder: str
-) -> jnp.ndarray:
-    volleys = encoding.encode(x, t_max, encoder)
+def _check_width(volleys: jnp.ndarray, width: int) -> jnp.ndarray:
     if volleys.shape[-1] != width:
         raise ValueError(
             f"encoded width {volleys.shape[-1]} != design input width {width}"
         )
     return volleys
+
+
+def _encode_width(
+    x: jnp.ndarray, t_max: int, width: int, encoder: str
+) -> jnp.ndarray:
+    return _check_width(encoding.encode(x, t_max, encoder), width)
 
 
 def _encode(x: jnp.ndarray, cfg: ColumnConfig, encoder: str) -> jnp.ndarray:
@@ -264,11 +267,18 @@ def assign_time_series(
 # Counters the sweep records (``repro.obs.count``, while a profiler runs):
 # designs whose batched assign took the Mosaic kernel / the reference
 # body, counted per bucket where the lowering is chosen, and designs the
-# degradation ladder evaluated on the 'cycle' solver rung.
+# degradation ladder evaluated on the 'cycle' solver rung; per sweep, the
+# designs the stream was encoded for and the encodes computed (one per
+# distinct t_max).
 ASSIGN_MOSAIC = "sim.assign_mosaic"
 ASSIGN_REFERENCE = "sim.assign_reference"
 SOLVER_DESIGNS = "sim.solver_designs"
-SWEEP_COUNTERS = (ASSIGN_MOSAIC, ASSIGN_REFERENCE, SOLVER_DESIGNS)
+ENCODE_DESIGNS = "sim.encode_designs"
+ENCODE_RUNS = "sim.encode_runs"
+SWEEP_COUNTERS = (
+    ASSIGN_MOSAIC, ASSIGN_REFERENCE, SOLVER_DESIGNS, ENCODE_DESIGNS,
+    ENCODE_RUNS,
+)
 
 
 def _fit_sharded(w, xs, thresholds, t_maxes, q_actives, keys, **statics):
@@ -659,10 +669,11 @@ def cluster_time_series_many(
     Designs must share the response function, STDP rule, WTA config and
     w_max (they are compile-time constants of the fused step); q, t_max and
     threshold may vary freely.  p is pinned by the encoder — every design
-    sees the same stream, so ``cfg.p`` must equal the encoded width for all
-    of them.  ``train_seconds`` on every result is the wall time of the
-    whole sweep (all buckets), not a per-design share; ``lowering`` records
-    the lowering that ran, ``buckets``/``shards`` the bucket count and the
+    sees the same stream, encoded once per distinct t_max within the call,
+    so ``cfg.p`` must equal the encoded width for all of them.
+    ``train_seconds`` on every result is the wall time of the whole sweep
+    (all buckets), not a per-design share; ``lowering`` records the
+    lowering that ran, ``buckets``/``shards`` the bucket count and the
     design's bucket shard count.
 
     **Fault isolation** (``on_error``): the default ``'raise'`` propagates
@@ -730,9 +741,18 @@ def cluster_time_series_many(
 
         # Encode + init per design BEFORE bucketing: the per-design PRNG key
         # assignment (and with it every result) is a function of the input
-        # order alone, never of how designs were bucketed.
+        # order alone, never of how designs were bucketed.  The encode
+        # depends on the stream, the encoder and t_max alone, so designs
+        # sharing a t_max share one array; each checks its own width.
         with obs.span("sim.encode"):
-            enc = [_encode(x, c, encoder) for c in cfgs]  # D x [N, p]
+            by_t_max: dict = {}
+            enc = []  # D x [N, p]
+            for c in cfgs:
+                if c.t_max not in by_t_max:
+                    by_t_max[c.t_max] = encoding.encode(x, c.t_max, encoder)
+                enc.append(_check_width(by_t_max[c.t_max], c.p))
+            obs.count(ENCODE_DESIGNS, d)
+            obs.count(ENCODE_RUNS, len(by_t_max))
         if w_init is None:
             rng = jax.random.key(seed)
             rng, init_key = jax.random.split(rng)

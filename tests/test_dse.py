@@ -7,6 +7,8 @@ The contract under test:
     never semantic ones;
   * buckets with equal envelope shapes share ONE compiled trace (the jit
     cache keys on the envelope, not the bucket);
+  * the sweep encodes its stream once per distinct t_max, with every
+    design bit-identical to the same design swept alone;
   * the central bucket policy (``backend.envelope_buckets``) respects the
     waste cap and ``max_bucket``, and covers every design exactly once;
   * the shard policy falls back cleanly on a single device, and on a
@@ -128,6 +130,104 @@ def test_equal_envelope_buckets_share_one_trace(compile_counter):
         "executable"
     )
     assert backend.aot_cache_size() == aot_before + 2  # one fit + one assign
+
+
+# ------------------------------------------------------ shared encodes
+def _counting_encode(monkeypatch):
+    """Wrap ``encoding.encode`` as the sweep sees it; returns the list of
+    the t_max each call encoded for."""
+    calls = []
+    real = simulator.encoding.encode
+
+    def encode(x, t_max, encoder="latency"):
+        calls.append(t_max)
+        return real(x, t_max, encoder)
+
+    monkeypatch.setattr(simulator.encoding, "encode", encode)
+    return calls
+
+
+def _each_alone(x, y, cfgs, seed, **kw):
+    """Every design swept by itself (one encode each), with the init
+    weights and stream key the whole sweep gives it."""
+    from repro.core import column as column_lib
+    from repro.core import stdp as stdp_lib
+
+    rng, init_key = jax.random.split(jax.random.key(seed))
+    keys = jax.random.split(init_key, len(cfgs))
+    out = []
+    for i, (k, c) in enumerate(zip(keys, cfgs)):
+        w0 = np.asarray(column_lib.init_params(k, c)["w"])
+        sk = None
+        if c.stdp.mode == "stochastic":
+            sk = [np.asarray(stdp_lib.stream_key(jax.random.fold_in(rng, i)))]
+        out += simulator.cluster_time_series_many(
+            x, y, [c], seed=seed, w_init=[w0], stream_keys=sk, **kw
+        )
+    return out
+
+
+def _assert_same_outcomes(got, want):
+    for i, (a, b) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(
+            a.assignments, b.assignments, err_msg=f"design {i}: assignments"
+        )
+        np.testing.assert_array_equal(
+            np.asarray(a.params["w"]), np.asarray(b.params["w"]),
+            err_msg=f"design {i}: trained weights",
+        )
+        assert a.rand_index == b.rand_index
+
+
+@pytest.mark.parametrize("on_error", ["raise", "isolate"])
+def test_sweep_encodes_once_per_distinct_t_max(on_error, monkeypatch):
+    """A grid over t_max (8, 32) x 3 threshold scales encodes the stream
+    twice, and every design trains and is assigned bit-identically to the
+    same design swept alone."""
+    x, y = _stream(n=14, length=10, seed=11)
+    cfgs = [_cfg(10, 3, t, s) for t in (8, 32) for s in (0.8, 1.0, 1.2)]
+    calls = _counting_encode(monkeypatch)
+    res = simulator.cluster_time_series_many(
+        x, y, cfgs, epochs=2, seed=7, on_error=on_error
+    )
+    assert calls == [8, 32]
+    alone = _each_alone(x, y, cfgs, 7, epochs=2, on_error=on_error)
+    assert len(calls) == 2 + len(cfgs), "alone, each design encodes once"
+    assert all(isinstance(r, simulator.ClusteringResult) for r in res)
+    _assert_same_outcomes(res, alone)
+
+
+def test_stochastic_sweep_keeps_its_stream_keys_with_shared_encodes(
+    monkeypatch,
+):
+    """Under stochastic STDP each design keeps the stream key of its
+    position: the shared-encode sweep matches each design swept alone
+    under that key."""
+    from repro.core.types import STDPConfig
+
+    def cfg(t_max, scale):
+        c = ColumnConfig(p=10, q=3, t_max=t_max,
+                         stdp=STDPConfig(mode="stochastic"))
+        return c.with_threshold(scale * simulator.suggest_threshold(c))
+
+    x, y = _stream(n=14, length=10, seed=12)
+    cfgs = [cfg(t, s) for t in (16, 8) for s in (0.9, 1.1)]
+    calls = _counting_encode(monkeypatch)
+    res = simulator.cluster_time_series_many(x, y, cfgs, epochs=2, seed=5)
+    assert calls == [16, 8]
+    _assert_same_outcomes(res, _each_alone(x, y, cfgs, 5, epochs=2))
+
+
+@pytest.mark.parametrize("bad_first", [False, True])
+def test_wrong_width_raises_when_sharing_a_t_max(bad_first):
+    """A design whose p is not the encoded width raises, even when a valid
+    design shares its t_max and so its encode."""
+    x, y = _stream(n=8, length=10)
+    good, bad = _cfg(10, 2, 16), _cfg(12, 2, 16)
+    cfgs = [bad, good] if bad_first else [good, bad]
+    with pytest.raises(ValueError, match="encoded width 10 != design input "
+                                         "width 12"):
+        simulator.cluster_time_series_many(x, y, cfgs, epochs=1)
 
 
 # ------------------------------------------------------------ shard policy
@@ -407,3 +507,22 @@ def test_traced_explore_gives_the_span_tree(monkeypatch, tmp_path):
         assert b.attrs["volleys"] == len(x)
     assert sum(b.attrs["designs"] for b in buckets) == 4
 
+
+def test_traced_explore_counts_the_encodes(tmp_path):
+    """Traced, an exploration counts one encode per distinct t_max against
+    the designs encoded for; untraced, it counts nothing."""
+    x, y = _stream(n=12, length=10, seed=3)
+    space = dse.DesignSpace(q=(2, 3), t_max=(8, 32),
+                            threshold_scale=(0.9, 1.1))
+    names = (simulator.ENCODE_DESIGNS, simulator.ENCODE_RUNS)
+    assert set(names) <= set(simulator.SWEEP_COUNTERS)
+    obs.reset()
+    dse.explore(x, y, space, epochs=1, seed=4)
+    assert not set(names) & set(obs.snapshot().counters)
+
+    with jax.profiler.trace(str(tmp_path)):
+        dse.explore(x, y, space, epochs=1, seed=4)
+    counters = obs.snapshot().counters
+    obs.reset()
+    assert counters[simulator.ENCODE_DESIGNS] == space.size() == 8
+    assert counters[simulator.ENCODE_RUNS] == 2
