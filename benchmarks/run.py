@@ -14,7 +14,6 @@ Emits ``name,us_per_call,derived`` CSV rows (plus human tables) for:
              vs legacy loops (BENCH_train.json)
   dse      — fault-isolation + journal overhead of the design sweep
   serve    — streaming clustering service req/s + latency (BENCH_serve.json)
-  roofline — §Roofline report from dry-run artifacts (if present)
   costmodel — device-calibrated cost model: predicted vs measured step time
 
 ``--check`` imports every registered benchmark and exits nonzero if any
@@ -39,7 +38,6 @@ MODULES = {
     "train": "benchmarks.train_bench",
     "dse": "benchmarks.dse_bench",
     "serve": "benchmarks.serve_bench",
-    "roofline": "benchmarks.roofline",
     "costmodel": "benchmarks.costmodel_bench",
 }
 

@@ -1,125 +1,1 @@
-"""Config registry: ``--arch <id>`` resolution for the 10 assigned
-architectures (plus the paper's own TNN column designs in tnn_columns).
-
-Each arch module exposes ``full()`` (exact published config) and ``smoke()``
-(reduced CPU-testable config).  ``input_specs`` builds the ShapeDtypeStruct
-stand-ins each (arch x shape) dry-run cell lowers against.
-"""
-from __future__ import annotations
-
-import dataclasses
-from typing import Optional
-
-import jax
-import jax.numpy as jnp
-
-from repro.configs import (
-    deepseek_coder_33b,
-    granite_3_8b,
-    kimi_k2_1t_a32b,
-    mamba2_370m,
-    olmoe_1b_7b,
-    qwen2_vl_7b,
-    qwen3_14b,
-    starcoder2_15b,
-    whisper_medium,
-    zamba2_7b,
-)
-from repro.models.config import ArchConfig
-
-_MODULES = (
-    kimi_k2_1t_a32b,
-    olmoe_1b_7b,
-    qwen3_14b,
-    granite_3_8b,
-    starcoder2_15b,
-    deepseek_coder_33b,
-    whisper_medium,
-    qwen2_vl_7b,
-    zamba2_7b,
-    mamba2_370m,
-)
-
-REGISTRY = {m.ARCH_ID: m for m in _MODULES}
-ARCH_IDS = tuple(REGISTRY)
-
-
-def get_arch(arch_id: str, smoke: bool = False) -> ArchConfig:
-    if arch_id not in REGISTRY:
-        raise KeyError(f"unknown arch {arch_id!r}; known: {ARCH_IDS}")
-    mod = REGISTRY[arch_id]
-    return mod.smoke() if smoke else mod.full()
-
-
-# --------------------------------------------------------------------------
-# shapes (assigned per-arch input-shape set)
-# --------------------------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str  # 'train' | 'prefill' | 'decode'
-    seq_len: int
-    global_batch: int
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
-}
-
-# long_500k needs sub-quadratic attention: run only for SSM/hybrid (their
-# attention state is O(1) / sharded-KV); skip for the 8 pure full-attention
-# archs, per the brief (also recorded in DESIGN.md §5).
-_LONG_OK_FAMILIES = ("ssm", "hybrid")
-
-
-def applicable(cfg: ArchConfig, shape: str) -> bool:
-    if shape == "long_500k":
-        return cfg.family in _LONG_OK_FAMILIES
-    return True
-
-
-def skip_reason(cfg: ArchConfig, shape: str) -> Optional[str]:
-    if applicable(cfg, shape):
-        return None
-    return (
-        f"{cfg.name} is pure full-attention; long_500k (seq 524288) requires "
-        "sub-quadratic attention (run for ssm/hybrid only)"
-    )
-
-
-def input_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict:
-    """ShapeDtypeStruct stand-ins for every model input of this cell.
-
-    train:   {'tokens','labels'} [B, S]  (+ 'frames' for audio,
-             + 'positions' [3, B, S] for M-RoPE VLM)
-    prefill: {'tokens'} [B, S] (+ 'frames')
-    decode:  {'tokens'} [B, 1] + the cache built by init_cache(B, S).
-    """
-    B, S = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-
-    def tok(b, s):
-        return jax.ShapeDtypeStruct((b, s), i32)
-
-    if shape.kind == "train":
-        specs = {"tokens": tok(B, S), "labels": tok(B, S)}
-        if cfg.family == "audio":
-            specs["frames"] = jax.ShapeDtypeStruct(
-                (B, cfg.enc_seq, cfg.d_model), jnp.bfloat16
-            )
-        if cfg.mrope:
-            specs["positions"] = jax.ShapeDtypeStruct((3, B, S), i32)
-        return specs
-    if shape.kind == "prefill":
-        specs = {"tokens": tok(B, S)}
-        if cfg.family == "audio":
-            specs["frames"] = jax.ShapeDtypeStruct(
-                (B, cfg.enc_seq, cfg.d_model), jnp.bfloat16
-            )
-        return specs
-    if shape.kind == "decode":
-        return {"tokens": tok(B, 1)}
-    raise ValueError(shape.kind)
+"""The paper's TNN column designs (Table II): ``tnn_columns``."""

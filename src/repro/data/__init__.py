@@ -1,4 +1,3 @@
 # Data substrate: UCR archive access (real format or synthetic doubles) for
-# the TNN clustering pillar, and the deterministic token pipeline for the
-# LM-architecture pillar.
-from repro.data import tokens, ucr  # noqa: F401
+# the TNN clustering pillar.
+from repro.data import ucr  # noqa: F401

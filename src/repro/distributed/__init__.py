@@ -1,11 +1,3 @@
-# Distributed runtime: sharding rules (DP/FSDP/TP/EP/SP over pod/data/model),
-# optimizers (AdamW, factored Adafactor, int8 error-feedback compression),
-# async checkpointing with elastic restore, straggler monitoring, trainer.
-from repro.distributed import (  # noqa: F401
-    checkpoint,
-    elastic,
-    optimizer,
-    sharding,
-    straggler,
-    train_loop,
-)
+# Distributed runtime: async checkpointing (checkpoint) and straggler
+# monitoring (straggler), used by the service, the DSE and serve/durability.
+from repro.distributed import checkpoint, straggler  # noqa: F401

@@ -1,4 +1,4 @@
-"""Silicon-metric forecasting (paper §III-D) and its roofline generalization.
+"""Silicon-metric forecasting (paper §III-D).
 
 The paper trains linear-regression models on accumulated TNNGen flow runs so
 that users without EDA access can predict post-layout area/leakage from the
@@ -12,17 +12,10 @@ synapse count alone:
 ``FlowResult`` runs (the paper: "trained on many TNNGen runs with varying
 TNN sizes ... can be continually refined with more actual design data
 points").
-
-``RooflineForecaster`` is the beyond-paper generalization described in
-DESIGN.md §5: the identical predict-silicon-from-size idea applied to the LM
-dry-run — it regresses the compiled roofline terms (compute/memory/
-collective seconds) on analytic model descriptors (params, FLOPs/token,
-bytes moved), so new configs get cost estimates without re-lowering.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
 from typing import Optional, Sequence
 
 import numpy as np
@@ -98,44 +91,3 @@ class Forecaster:
     @staticmethod
     def error_pct(forecast: float, actual: float) -> float:
         return 100.0 * (forecast - actual) / actual
-
-
-class RooflineForecaster:
-    """Beyond-paper: predict dry-run roofline terms from arch descriptors.
-
-    Features per (arch, shape) cell: [params_B, flops_per_step_P,
-    activation_bytes_G, seq_len_k].  Targets: the three roofline terms in
-    seconds.  Fitted on the dry-run table (benchmarks/roofline.py) the same
-    way the paper fits silicon models on flow runs.
-    """
-
-    TERMS = ("compute_s", "memory_s", "collective_s")
-
-    def __init__(self):
-        self.models: dict = {}
-
-    def fit(self, feats: np.ndarray, targets: dict) -> None:
-        for term in self.TERMS:
-            self.models[term] = LinearModel.fit(feats, np.asarray(targets[term]))
-
-    def predict(self, feats: np.ndarray) -> dict:
-        if not self.models:
-            raise RuntimeError("fit() first")
-        return {t: self.models[t].predict(feats) for t in self.TERMS}
-
-    def save(self, path: str) -> None:
-        blob = {
-            t: {"coef": m.coef.tolist(), "intercept": m.intercept}
-            for t, m in self.models.items()
-        }
-        with open(path, "w") as f:
-            json.dump(blob, f, indent=2)
-
-    @classmethod
-    def load(cls, path: str) -> "RooflineForecaster":
-        with open(path) as f:
-            blob = json.load(f)
-        fc = cls()
-        for t, m in blob.items():
-            fc.models[t] = LinearModel(np.asarray(m["coef"]), m["intercept"])
-        return fc

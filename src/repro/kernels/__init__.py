@@ -5,12 +5,9 @@
 #                     with resident weights (in-kernel plane decomposition)
 #   rnl_response    — fused RNL potential + first-crossing (one-hot plane MXU matmuls)
 #   stdp_update     — fused per-synapse STDP case-select/stabilize/clamp (VPU)
-#   flash_attention — fused causal flash attention (the §Perf structural fix
-#                     for the LM pillar's memory-bound attention cells)
 # Each has a pure-jnp oracle in ref.py; ops.py holds the jit'd wrappers.
 # Execution policy (Mosaic vs interpreter vs reference lowering) is decided
 # in ONE place: repro.core.backend — kernels never default interpret=True.
 from repro.kernels import fused_column, ops, ref  # noqa: F401
-from repro.kernels.flash_attention import flash_attention_pallas  # noqa: F401
 from repro.kernels.rnl_response import rnl_fire_pallas  # noqa: F401
 from repro.kernels.stdp_update import stdp_update_pallas  # noqa: F401

@@ -1,4 +1,1 @@
-# Launchers: production mesh construction (mesh.py), the multi-pod dry-run
-# (dryrun.py — sets XLA_FLAGS before importing jax; import it first or run
-# as __main__), training (train.py) and serving (serve.py) drivers.
-from repro.launch import mesh  # noqa: F401
+# Launchers: the TNN clustering service (serve_tnn.py; run as __main__).

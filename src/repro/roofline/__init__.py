@@ -1,1 +1,1 @@
-from repro.roofline import analysis  # noqa: F401
+# The device-calibrated cost model behind backend.execution_plan (costmodel.py).

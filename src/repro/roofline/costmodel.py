@@ -10,8 +10,8 @@ largest-divisor shard policy.  This module replaces the *numbers* with a
   bandwidth, inter-device link bandwidth, per-dispatch launch overhead,
   per-trace compile cost, and the on-chip footprint bound (VMEM on TPU,
   a cache-resident working-set bound on CPU).  Named default profiles
-  ship for TPU v5e (the numbers ``roofline/analysis.py`` used to
-  hard-code) and a generic host CPU.
+  ship for TPU v5e (Google Cloud's published peaks) and a generic host
+  CPU.
 * **calibrate()** — measures the peaks once per host/platform with a
   tiny probe suite (a jitted matmul for FLOP/s, a streaming add for
   bandwidth, a no-op dispatch loop for launch overhead, one fresh

@@ -10,7 +10,8 @@ The contract under test:
     ``fori_loop``, VMEM-resident weights — matches the reference blocked
     body exactly on heterogeneous padded design batches;
   * a padded D=1 blocked fit stays bit-identical to ``mode='cycle'`` on
-    integer weights (the fused contract, end to end through blocking);
+    integer weights (the fused contract, end to end through blocking),
+    also at each of the paper's seven Table II designs;
   * the batched assignment pass (``assign_padded``) equals per-design,
     per-volley assignment — blocked reference body on float weights,
     grid-batched kernel on integer-grid weights;
@@ -18,11 +19,14 @@ The contract under test:
     weight-grid-aware assignment lowering (``backend.assign_lowering``)
     pick sane, clamped values.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import tnn_columns
 from repro.core import backend, column
 from repro.core.types import ColumnConfig, NeuronConfig, STDPConfig, TIME_DTYPE
 from repro.kernels import fused_column
@@ -150,6 +154,39 @@ def test_blocked_scan_matches_cycle_on_integer_weights():
             np.asarray(p_cyc["w"]), np.asarray(w_blk[0]),
             err_msg=f"v_blk={v_blk} diverges from mode='cycle'",
         )
+
+
+@pytest.mark.parametrize("name", tnn_columns.all_benchmarks())
+def test_table2_design_fit_matches_cycle(name):
+    """The padded fused fit == mode='cycle' at each Table II design's
+    published p x q and t_max, on the integer grid."""
+    cfg = dataclasses.replace(
+        tnn_columns.column_config(name),
+        stdp=STDPConfig(
+            mu_capture=1.0, mu_backoff=1.0, mu_search=1.0, stabilizer="none"
+        ),
+    )
+    rng = np.random.default_rng(17)
+    w0 = jnp.asarray(
+        rng.integers(0, cfg.neuron.w_max + 1, (cfg.p, cfg.q)), jnp.float32
+    )
+    x = jnp.asarray(rng.integers(0, cfg.t_max + 4, (6, cfg.p)), jnp.int32)
+
+    p_cyc, _ = backend.get("cycle").fit(
+        {"w": w0}, x, cfg, "cycle", 1, None, False, None
+    )
+    w_fused = fused_column.fit_scan_padded(
+        w0[None], x[:, None, :].astype(TIME_DTYPE),
+        jnp.asarray([cfg.neuron.threshold], jnp.float32),
+        jnp.asarray([cfg.t_max], TIME_DTYPE),
+        jnp.asarray([cfg.q], TIME_DTYPE),
+        t_window=cfg.t_max, w_max=cfg.neuron.w_max, wta_k=cfg.wta.k,
+        mu_capture=1.0, mu_backoff=1.0, mu_search=1.0, stabilize=False,
+        response="rnl", epochs=1, lowering="reference",
+    )
+    w_cyc = np.asarray(p_cyc["w"])
+    assert (w_cyc != np.asarray(w0)).any(), "STDP left the weights unchanged"
+    np.testing.assert_array_equal(w_cyc, np.asarray(w_fused[0]))
 
 
 def _assign_single_volley(w, xs, th, tm, qa, t_window, n):

@@ -228,17 +228,15 @@ def test_plan_blocking_is_bit_identical_to_constants(geom):
 def test_peaks_are_keyed_by_device_kind():
     """Peaks come from the profile of the device kind JAX reports; a
     device with no profile is an error, never another device's peaks."""
-    from repro.roofline import analysis
-
     v5e = costmodel.default_profile("TPU v5 lite")
     assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
-    assert analysis.peaks(device_kind="TPU v5 lite")[:2] == (197e12, 819e9)
+    assert v5e.device_kind == "TPU v5 lite"
     with costmodel.override(None):  # no calibration: the host's own kind
-        assert analysis.peaks()[3] == costmodel.default_profile(
-            jax.devices()[0].device_kind
-        ).name
+        assert costmodel.profile() is None
+        kind = jax.devices()[0].device_kind
+        assert costmodel.default_profile(kind).device_kind == kind
         with pytest.raises(KeyError, match="TPU v99"):
-            analysis.peaks(device_kind="TPU v99")
+            costmodel.default_profile("TPU v99")
 
 
 def test_calibration_round_trip(tmp_path):
