@@ -22,6 +22,7 @@ uninterrupted run.  See ``docs/dse.md``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
 from typing import Optional, Union
@@ -39,6 +40,14 @@ from repro.dse import journal as journal_lib
 from repro.dse.pareto import DesignPoint, pareto_front
 from repro.dse.space import Candidate, DesignSpace, candidate_config
 from repro.roofline import costmodel
+
+# Counters ``explore`` records (``repro.obs.count``, while a profiler
+# runs): per encoder group, the candidates whose initial weights (and
+# stream keys) were drawn, and the programs that drew them (one per
+# candidate shape).
+INIT_DESIGNS = "dse.init_designs"
+INIT_RUNS = "dse.init_runs"
+DSE_COUNTERS = (INIT_DESIGNS, INIT_RUNS)
 
 
 @dataclasses.dataclass
@@ -94,6 +103,52 @@ class DSEResult:
                 + detail
             )
         return max(self.pareto, key=lambda p: p.rand_index / p.area_um2)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _draw(init_key, stream_root, idxs, cfg):
+    """Initial weights [k, p, q] of candidates ``idxs`` [k] of one shape,
+    ``init_params`` of ``fold_in(init_key, i)``, and under stochastic STDP
+    their stream keys [k, 2], ``stream_key`` of ``fold_in(stream_root, i)``
+    (None otherwise): per candidate exactly the draws made one by one."""
+
+    def one(i):
+        w = column_lib.init_params(jax.random.fold_in(init_key, i), cfg)["w"]
+        if cfg.stdp.mode != "stochastic":
+            return w, None
+        return w, stdp_lib.stream_key(jax.random.fold_in(stream_root, i))
+
+    return jax.vmap(one)(idxs)
+
+
+def _draw_inits(init_key, stream_root, idxs, cfgs):
+    """The initial weights of candidates ``idxs`` (configs ``cfgs``) and,
+    under stochastic STDP, their stream keys (else None), as host arrays in
+    ``idxs`` order: one ``_draw`` program per candidate shape, one fetch.
+
+    The program is keyed on the config with threshold and window zeroed,
+    which the draw never reads, so candidates that differ only there share
+    it."""
+    groups: dict = {}
+    for j, c in enumerate(cfgs):
+        key = dataclasses.replace(c.with_threshold(0.0), t_max=0)
+        groups.setdefault(key, []).append(j)
+    drawn = jax.device_get([
+        _draw(
+            init_key, stream_root,
+            np.asarray([idxs[j] for j in js], np.int32), cfg,
+        )
+        for cfg, js in groups.items()
+    ])
+    obs.count(INIT_DESIGNS, len(idxs))
+    obs.count(INIT_RUNS, len(groups))
+    w_init: list = [None] * len(idxs)
+    keys: list = [None] * len(idxs)
+    for js, (w, k) in zip(groups.values(), drawn):
+        for r, j in enumerate(js):
+            w_init[j] = w[r]
+            keys[j] = None if k is None else k[r]
+    return w_init, None if keys[0] is None else keys
 
 
 def explore(
@@ -277,22 +332,9 @@ def explore(
             idxs = [i for i in pending if candidates[i].encoder == encoder]
             cfgs = [cfgs_all[i] for i in idxs]
             with obs.span("dse.init"):
-                w_init = [
-                    np.asarray(
-                        column_lib.init_params(
-                            jax.random.fold_in(init_key, i), cfgs_all[i]
-                        )["w"]
-                    )
-                    for i in idxs
-                ]
-                stream_keys = None
-                if space.stdp.mode == "stochastic":
-                    stream_keys = [
-                        np.asarray(stdp_lib.stream_key(
-                            jax.random.fold_in(stream_root, i)
-                        ))
-                        for i in idxs
-                    ]
+                w_init, stream_keys = _draw_inits(
+                    init_key, stream_root, idxs, cfgs
+                )
 
             def on_bucket(local_idxs, results, idxs=idxs, encoder=encoder):
                 with obs.span("dse.record"):

@@ -526,3 +526,96 @@ def test_traced_explore_counts_the_encodes(tmp_path):
     obs.reset()
     assert counters[simulator.ENCODE_DESIGNS] == space.size() == 8
     assert counters[simulator.ENCODE_RUNS] == 2
+
+
+# ---------------------------------------------------------- initial draws
+def _explore_module():
+    # ``repro.dse.explore`` the attribute is the function; the module
+    # holds the draw and the counters
+    import importlib
+
+    return importlib.import_module("repro.dse.explore")
+
+
+def _space(mode, **axes):
+    from repro.core.types import STDPConfig
+
+    axes = {"q": (2, 3), "t_max": (8, 16), "threshold_scale": (0.9, 1.1),
+            **axes}
+    return dse.DesignSpace(**axes, stdp=STDPConfig(mode=mode))
+
+
+@pytest.mark.parametrize("mode", ["expected", "stochastic"])
+def test_batched_init_draws_match_the_per_candidate_draws(mode):
+    """The batched draw gives every candidate of a grid over two q (two
+    shape groups), in any order, the weights of ``init_params`` of
+    ``fold_in(init_key, i)`` and, under stochastic STDP, the stream key of
+    ``stream_key(fold_in(stream_root, i))``, bit for bit."""
+    from repro.core import column, stdp
+
+    space = _space(mode)
+    cfgs = [dse.candidate_config(c, 10, space.stdp) for c in space.grid()]
+    idxs = [5, 0, 3, 7, 2, 6]
+    stream_root, init_key = jax.random.split(jax.random.key(9))
+    w, keys = _explore_module()._draw_inits(
+        init_key, stream_root, idxs, [cfgs[i] for i in idxs]
+    )
+    assert len(w) == len(idxs)
+    for j, i in enumerate(idxs):
+        want = column.init_params(jax.random.fold_in(init_key, i), cfgs[i])
+        assert w[j].dtype == want["w"].dtype
+        np.testing.assert_array_equal(w[j], np.asarray(want["w"]))
+    if mode == "expected":
+        assert keys is None
+        return
+    assert len(keys) == len(idxs)
+    for j, i in enumerate(idxs):
+        np.testing.assert_array_equal(
+            keys[j], np.asarray(stdp.stream_key(
+                jax.random.fold_in(stream_root, i)
+            ))
+        )
+
+
+def test_traced_explore_counts_the_init_draws(tmp_path):
+    """Traced, an exploration counts the candidates drawn and one draw
+    program per candidate shape; untraced, it counts nothing."""
+    mod = _explore_module()
+    x, y = _stream(n=12, length=10, seed=3)
+    space = _space("expected", t_max=(8, 32))
+    names = (mod.INIT_DESIGNS, mod.INIT_RUNS)
+    assert names == mod.DSE_COUNTERS
+    obs.reset()
+    dse.explore(x, y, space, epochs=1, seed=4)
+    assert not set(names) & set(obs.snapshot().counters)
+
+    with jax.profiler.trace(str(tmp_path)):
+        dse.explore(x, y, space, epochs=1, seed=4)
+    counters = obs.snapshot().counters
+    obs.reset()
+    assert counters[mod.INIT_DESIGNS] == space.size() == 8
+    assert counters[mod.INIT_RUNS] == len(space.q) == 2
+
+
+@pytest.mark.parametrize("mode", ["expected", "stochastic"])
+def test_resumed_partial_explore_matches_the_full_run(mode, tmp_path):
+    """A journaled exploration of the grid's first three candidates, then
+    resumed over the whole grid, draws the rest in two shape groups and
+    ends bit-identical to an uninterrupted run."""
+    x, y = _stream(n=12, length=10, seed=14)
+    space = _space(mode)
+    full = dse.explore(x, y, space, epochs=1, seed=6)
+    path = str(tmp_path / "dse.jsonl")
+    dse.explore(x, y, space, epochs=1, seed=6, budget=3, journal=path)
+    again = dse.explore(
+        x, y, space, epochs=1, seed=6, journal=path, resume=True
+    )
+    assert again.meta["resumed"] == 3
+    assert [p.index for p in again.points] == [p.index for p in full.points]
+    for a, b in zip(full.points, again.points):
+        assert a.rand_index == b.rand_index or (
+            np.isnan(a.rand_index) and np.isnan(b.rand_index)
+        )
+        np.testing.assert_array_equal(
+            np.asarray(a.params["w"]), np.asarray(b.params["w"])
+        )
