@@ -717,7 +717,7 @@ def _fit_key(
     return (
         "fit", tuple(w_shape), tuple(xs_shape), t_window, w_max, wta_k,
         bool(stabilize), response, epochs, lowering, t_blk, v_blk,
-    ) + (("stochastic", "keys") if stochastic else ())
+    ) + (("stochastic", "keys", "v0") if stochastic else ())
 
 
 def _assign_key(
@@ -761,6 +761,7 @@ def warm_fit_padded(
     lowering: str,
     t_blk: Optional[int] = None,
     v_blk: Optional[int] = None,
+    stochastic: bool = False,
 ) -> bool:
     """Make one envelope's fit executable resident *before* traffic.
 
@@ -775,7 +776,9 @@ def warm_fit_padded(
     ``fit_padded`` with the same shapes+statics is then dispatch-only).
     When the module entry point has been replaced by a plain callable
     (the fault-injection seam — see ``fit_padded``) there is nothing to
-    compile and this is a no-op returning False.
+    compile and this is a no-op returning False.  ``stochastic`` warms
+    the stochastic envelope's executable (the one ``fit_padded`` runs when
+    given ``keys``).
     """
     if not hasattr(fused_column.fit_scan_padded, "lower"):
         return False
@@ -785,7 +788,7 @@ def warm_fit_padded(
     )
     key = _fit_key(
         (d, p_pad, q_pad), (n_volleys, d, p_pad), t_window, w_max, wta_k,
-        stabilize, response, epochs, lowering, t_blk, v_blk,
+        stabilize, response, epochs, lowering, t_blk, v_blk, stochastic,
     )
     hot = key in _AOT_CACHE
     _resolve_executable(
@@ -795,6 +798,7 @@ def warm_fit_padded(
             t_window=t_window, w_max=w_max, wta_k=wta_k,
             stabilize=bool(stabilize), response=response, epochs=epochs,
             lowering=lowering, t_blk=t_blk, v_blk=v_blk,
+            stochastic=stochastic,
         ),
     )
     return hot
@@ -848,6 +852,13 @@ def _f32_scalar(v: float):
     return jnp.float32(v)
 
 
+@functools.lru_cache(maxsize=1)
+def _i32_zero():
+    """The stream offset of a fit that starts its stream (a sweep), put on
+    the device once, like the mus above."""
+    return jnp.int32(0)
+
+
 def fit_padded(
     w,
     xs,
@@ -868,6 +879,7 @@ def fit_padded(
     t_blk: Optional[int] = None,
     v_blk: Optional[int] = None,
     keys=None,
+    v0: int = 0,
 ):
     """Envelope-cached AOT front door to ``fused_column.fit_scan_padded``.
 
@@ -890,9 +902,15 @@ def fit_padded(
     path lets GSPMD propagate the design partitioning at trace time.
 
     ``keys`` ([D, 2] i32 stream keys, one per design) selects stochastic
-    STDP — the envelope's static flag.
+    STDP — the envelope's static flag — and ``v0`` (an int, default 0) is
+    the stream index of the window's first volley: volley n of epoch e
+    draws at ``v0 + e * N + n``.  ``v0`` is a runtime operand of the
+    stochastic executable, so a caller that advances it (the streaming
+    service, re-fit after re-fit) never recompiles.
     """
     stochastic = keys is not None
+    if v0 and not stochastic:
+        raise ValueError("v0 is a stream index: stochastic STDP only")
     w = _coerce(w, jnp.float32)
     xs = _coerce(xs, TIME_DTYPE)
     thresholds = _coerce(thresholds, jnp.float32)
@@ -905,7 +923,10 @@ def fit_padded(
     )
     stream = {}
     if stochastic:
-        stream = dict(keys=_coerce(keys, jnp.int32))
+        stream = dict(
+            keys=_coerce(keys, jnp.int32),
+            v0=_i32_zero() if not v0 else jnp.int32(v0),
+        )
     if not hasattr(fused_column.fit_scan_padded, "lower"):
         # the module entry point has been replaced by a plain callable —
         # the fault-injection / instrumentation seam the fault tests (and
@@ -935,7 +956,7 @@ def fit_padded(
         ),
     )
     # the call must mirror the precompile specs exactly: five positional
-    # arrays, mus (and a stochastic fit's keys) by keyword
+    # arrays, mus (and a stochastic fit's keys and v0) by keyword
     return exe(
         w, xs, thresholds, t_maxes, q_actives,
         mu_capture=_f32_scalar(float(mu_capture)),
@@ -1055,13 +1076,15 @@ def _solver_fit_scan(
     epochs: int,
     trace: bool,
     supervised: bool,
+    v0=0,
 ):
     """Online STDP as one compiled scan using the event/cycle solvers.
 
     Handles the full config surface (LIF, stochastic STDP, random/all WTA
     tie-breaks, supervised targets).  Stochastic STDP draws volley n of
-    epoch e at stream index e * N + n under ``stdp.stream_key(rng)`` — the
-    stream the fused path draws — so 'cycle' is its bit-exact reference.
+    epoch e at stream index v0 + e * N + n under ``stdp.stream_key(rng)``
+    — the stream the fused path draws — so 'cycle' is its bit-exact
+    reference.
     """
     n = xs.shape[0]
     s_key = stdp.stream_key(rng)
@@ -1083,7 +1106,7 @@ def _solver_fit_scan(
         ke = jax.random.fold_in(key, e)
         idx = jnp.arange(n, dtype=jnp.int32)
         (w2, _), ys = jax.lax.scan(
-            volley, (wc, ke), (xs, yts, idx, e * n + idx)
+            volley, (wc, ke), (xs, yts, idx, v0 + e * n + idx)
         )
         return (w2, key), ys
 
@@ -1113,6 +1136,29 @@ def _solver_fit(
         trace, y_target is not None,
     )
     return {"w": w_new}, ys
+
+
+def solver_fit_stream(w, xs, cfg: ColumnConfig, *, epochs: int, stream_key,
+                      v0: int = 0):
+    """One design's online STDP on the 'cycle' solver, resuming a
+    stochastic stream.
+
+    ``w`` [p, q], ``xs`` [N, p] volleys; stochastic STDP draws volley n of
+    epoch e at stream index ``v0 + e * N + n`` under ``stream_key`` ([2]
+    i32) — the bits ``fit_padded(keys=..., v0=...)`` draws for the same
+    design, so with ``cycle_exact`` this is the bit-exact bottom rung of
+    a resumed fused fit.  Returns the new weights [p, q].
+    """
+    # a raw key whose words are the stream key: the solver draws from
+    # stdp.stream_key(rng), the very key the fused rungs were given
+    rng = jax.lax.bitcast_convert_type(
+        jnp.asarray(stream_key, jnp.int32), jnp.uint32
+    )
+    w_new, _ = _solver_fit_scan(
+        jnp.array(w, jnp.float32, copy=True), jnp.asarray(xs, TIME_DTYPE),
+        None, rng, cfg, "cycle", epochs, False, False, jnp.int32(v0),
+    )
+    return w_new
 
 
 def _solver_fire(mode: str):

@@ -985,6 +985,7 @@ def fit_scan_padded(
     plan=None,
     stochastic: bool = False,
     keys=None,  # [D, 2] i32 stream keys (stochastic only)
+    v0=0,  # i32 stream index of the first volley (stochastic only)
 ):
     """All designs x all epochs x all volleys in ONE compiled program.
 
@@ -1038,9 +1039,13 @@ def fit_scan_padded(
     ``stochastic=True`` (a static flag of the envelope; the expected-mode
     program is untouched by it) selects stochastic STDP: ``keys`` carries
     each design's stream key, sharded and bucketed with its design, and
-    volley n of epoch e draws at global index ``e * N + n`` — the
+    volley n of epoch e draws at global index ``v0 + e * N + n`` — the
     same bits ``mode='cycle'`` draws, whatever the envelope, block size,
-    bucket or shard.
+    bucket or shard.  ``v0`` (an i32 scalar, traced or not) is where
+    the stream resumes: a caller that trains one design in several calls
+    (the streaming service's re-fits) passes the index the last call
+    stopped at, so the calls together draw what one call over the joined
+    volleys would.
 
     ``w`` is donated: the weight buffer stays resident across the whole
     epochs x volleys scan.
@@ -1086,7 +1091,7 @@ def fit_scan_padded(
                 w, xs, thresholds, t_maxes, q_actives,
                 t_window, w_max, wta_k, mu_capture, mu_backoff, mu_search,
                 stabilize, epochs, lowering, t_blk, v_blk,
-                keys=keys if stochastic else None,
+                keys=keys if stochastic else None, v0=v0,
             )
 
         # [S, v_blk, D, p]
@@ -1120,7 +1125,7 @@ def fit_scan_padded(
 
         return _scan_epochs(
             block, w, xsb, n_valid, epochs,
-            _block_bases(epochs, xs.shape[0], v_blk) if stochastic
+            _block_bases(epochs, xs.shape[0], v_blk, v0) if stochastic
             else None,
         )
 
@@ -1142,18 +1147,18 @@ def _scan_epochs(block, w, xsb, n_valid, epochs: int, bases=None):
     return jax.lax.scan(epoch_s, w, bases)[0]
 
 
-def _block_bases(epochs: int, n: int, v_blk: int):
+def _block_bases(epochs: int, n: int, v_blk: int, v0=0):
     """[epochs, S] global index of each volley block's first volley:
-    ``e * N + b * v_blk`` (the stochastic stream's volley index)."""
+    ``v0 + e * N + b * v_blk`` (the stochastic stream's volley index)."""
     e = jnp.arange(epochs, dtype=jnp.int32)[:, None]
     b = jnp.arange(-(-n // v_blk), dtype=jnp.int32)[None, :]
-    return e * n + b * v_blk
+    return v0 + e * n + b * v_blk
 
 
 def _fit_scan_padded_kernel(
     w, xs, thresholds, t_maxes, q_actives,
     t_window, w_max, wta_k, mu_capture, mu_backoff, mu_search,
-    stabilize, epochs, lowering, t_blk, v_blk, keys=None,
+    stabilize, epochs, lowering, t_blk, v_blk, keys=None, v0=0,
 ):
     """Kernel-lowering body of ``fit_scan_padded`` (called inside its jit).
 
@@ -1195,7 +1200,8 @@ def _fit_scan_padded_kernel(
 
     w_k = _scan_epochs(
         block, w_k, xsb, n_valid, epochs,
-        None if keys is None else _block_bases(epochs, xs.shape[0], v_blk),
+        None if keys is None
+        else _block_bases(epochs, xs.shape[0], v_blk, v0),
     )
     return w_k[:, :p_env, :q_env]
 
@@ -1410,7 +1416,8 @@ def _fit_scan_padded_specs(
     d: int, p_pad: int, q_pad: int, n_volleys: int, stochastic: bool = False
 ):
     """(args, dynamic kwargs) abstract specs mirroring one fit call
-    exactly: the mus, and the stream keys of a stochastic fit."""
+    exactly: the mus, and the stream keys and first stream index of a
+    stochastic fit."""
     f32 = jnp.float32
     args = (
         jax.ShapeDtypeStruct((d, p_pad, q_pad), f32),          # w
@@ -1425,6 +1432,7 @@ def _fit_scan_padded_specs(
     }
     if stochastic:
         mus["keys"] = jax.ShapeDtypeStruct((d, 2), jnp.int32)
+        mus["v0"] = jax.ShapeDtypeStruct((), jnp.int32)
     return args, mus
 
 
@@ -1451,7 +1459,8 @@ def precompile_fit_scan_padded(
     the dynamic half of the jitted entry point — five positional arrays
     ``(w, xs, thresholds, t_maxes, q_actives)`` matching the spec shapes
     plus the three STDP mus by keyword as f32 scalars, and for a
-    ``stochastic`` envelope ``keys`` ([D, 2] i32) by keyword too (the
+    ``stochastic`` envelope ``keys`` ([D, 2] i32) and ``v0`` (i32
+    scalar) by keyword too (the
     call's args/kwargs pytree must mirror the lowering's) — and it behaves
     bit-for-bit like the jit path, including donating ``w``.
     """

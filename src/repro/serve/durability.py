@@ -18,6 +18,12 @@ kill-and-resume story, see ``docs/dse.md``):
   fused scan is deterministic, so replaying the WAL on top of the
   snapshot restores weights **bit-identical** to the uninterrupted
   service.  A kill at any instant loses at most the re-fit in flight.
+* Under stochastic STDP a re-fit's result also depends on where its
+  design's random stream stood: each WAL record carries the stream
+  ``keys`` and the ``v_base`` its window drew from, and each snapshot
+  holds, after the weight blocks, one more leaf — every bucket's next
+  volley base — so replay draws the same bits and recovery restores the
+  bases too.
 * Snapshots publish via the ``Checkpointer`` write-then-rename protocol
   (a preempted snapshot is never visible); the WAL is truncated only
   after its covering snapshot has published, so every committed re-fit
@@ -209,12 +215,14 @@ class DurableStore:
 
     def log_refit(
         self, seq: int, bucket: int, epochs: int, lowering: str,
-        xs: np.ndarray,
+        xs: np.ndarray, stream: dict | None = None,
     ) -> None:
+        """Append one committed re-fit; ``stream`` (stochastic STDP) holds
+        the ``keys`` and ``v_base`` its window drew from."""
         self.wal.append({
             "kind": "refit", "seq": int(seq), "bucket": int(bucket),
             "epochs": int(epochs), "lowering": lowering,
-            "xs": np.asarray(xs).tolist(),
+            "xs": np.asarray(xs).tolist(), **(stream or {}),
         })
         self.pending += 1
 

@@ -40,11 +40,25 @@ The serving pipeline, stage by stage (each independently testable):
   the positive thresholds the service enforces, a silent volley is an
   exact weight no-op, so the re-fit is bit-identical to an offline
   ``fit_padded`` resume on the same volleys.
+* **stochastic STDP** — designs that learn with the hardware rule
+  (``stdp.mode='stochastic'``: integer counters moved one LSB at a time,
+  gated by a counter-based random stream) are served on the same path.
+  Each design draws under its own stream key (from the service seed and
+  its index); a bucket's designs share a next stream index, the
+  *volley base*.  A committed re-fit of ``refit_epochs`` passes over the
+  padded window of ``refit_window`` volleys draws indices ``base + e * W
+  + n`` and advances the base by ``refit_epochs * W``, so a design's
+  re-fits together draw what one fit over the joined windows would.  A
+  failed, degraded or warm-up re-fit leaves the base where it was.
+  Integer counters keep every batch's assign on the integer-grid kernel.
 * **durability** — with ``durable_dir`` set, every committed re-fit is
   appended to a volley WAL and every ``snapshot_every`` re-fits the live
   weights snapshot atomically; ``ClusteringService.recover(dir)``
   replays WAL re-fits on top of the latest snapshot and restores weights
-  bit-identical to the uninterrupted service (``serve.durability``).
+  (and, under stochastic STDP, the volley bases: each WAL record carries
+  the stream keys and base its re-fit drew from, each snapshot the next
+  bases) bit-identical to the uninterrupted service
+  (``serve.durability``).
 
 Failures quarantine per request: if a batch raises, each live request
 re-runs alone against the same executable (assignment is per-volley
@@ -75,10 +89,20 @@ from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import column as column_lib
 from repro.core import encoding
+from repro.core import stdp as stdp_lib
 from repro.core.types import ColumnConfig, TIME_DTYPE, column_config_from_dict
 from repro.distributed.straggler import StepMonitor
 from repro.kernels import fused_column
 from repro.serve import durability
+
+# Counters the service records (``repro.obs.count``, while a profiler
+# runs): batches by the lowering their assign took (the Mosaic kernel on
+# integer weights, else the reference body), and the live design-volleys
+# (times epochs) each committed re-fit trained on.
+ASSIGN_MOSAIC = "serve.assign_mosaic"
+ASSIGN_REFERENCE = "serve.assign_reference"
+REFIT_DESIGN_VOLLEYS = "serve.refit_design_volleys"
+SERVE_COUNTERS = (ASSIGN_MOSAIC, ASSIGN_REFERENCE, REFIT_DESIGN_VOLLEYS)
 
 
 class RequestRejected(Exception):
@@ -202,7 +226,7 @@ class _Bucket:
     """One envelope bucket: live weights + compiled-shape metadata + queue
     + degraded-mode state."""
 
-    def __init__(self, index, envelope, names, cfgs, w0):
+    def __init__(self, index, envelope, names, cfgs, w0, keys=None):
         self.index = index
         self.envelope = envelope  # (p_env, q_env, t_window)
         self.names = list(names)
@@ -238,6 +262,11 @@ class _Bucket:
         self.failed_refits = 0
         self.cooldown = 0
         self.last_refit_errors: list[str] = []
+        # stochastic STDP: [Db, 2] i32 stream keys and the stream index the
+        # next committed re-fit starts at (None: expected STDP)
+        self.keys = keys
+        self.v_base = 0
+        self.cycle_exact = False
 
 
 def _design_map(
@@ -257,7 +286,8 @@ class ClusteringService:
         designs must share the fused statics (response, ``w_max``, WTA k,
         STDP mus/mode) — the same constraint as the sweep front-end — and
         every threshold must be positive (the silent-volley no-op that
-        partial batches and ragged re-fits rely on).
+        partial batches and ragged re-fits rely on).  Expected- and
+        stochastic-mode STDP are both served.
       encoder: ``'latency'`` or ``'onoff'`` (admission uses
         ``encoding.encoded_width`` to pin series length to design width).
       batch_size: requests per compiled assignment micro-batch; a full
@@ -270,6 +300,7 @@ class ClusteringService:
       weights: optional ``{name: [p, q] array}`` initial weights (e.g.
         from an offline ``cluster_time_series`` fit); designs without an
         entry draw ``column.init_params`` from ``fold_in(seed, index)``.
+        Stochastic designs take integer counters only.
       max_pending: bound on the total queued (unexecuted) requests across
         all buckets; beyond it ``submit`` sheds with
         ``RequestRejected(reason='overloaded')`` and a retry-after hint.
@@ -350,13 +381,6 @@ class ClusteringService:
             fused_column.check_fusable(
                 c, backend_lib.padded_lowering(c.neuron.response)
             )
-            if c.stdp.mode != "expected":
-                # a stochastic re-fit must resume its stream where the
-                # last one stopped, and the WAL keeps no volley index yet
-                raise ValueError(
-                    f"design {n!r}: the service supports expected-mode "
-                    "STDP only"
-                )
             if c.neuron.threshold <= 0:
                 raise ValueError(
                     f"design {n!r}: threshold must be > 0 — the service "
@@ -383,6 +407,8 @@ class ClusteringService:
             stabilize=c0.stdp.stabilizer == "half",
             response=c0.neuron.response,
         )
+        self._rule = c0.stdp.mode
+        self.stochastic = self._rule == "stochastic"
 
         # ---- bucket construction: pack design shapes into envelopes and
         # assemble each bucket's live weight block host-side (the sweep
@@ -390,6 +416,7 @@ class ClusteringService:
         shapes = [(c.p, c.q, c.t_max) for c in cfgs]
         buckets = backend_lib.envelope_buckets(shapes, waste_cap, max_bucket)
         key = jax.random.key(seed)
+        stream_root, _ = jax.random.split(key)
         self._buckets: list[_Bucket] = []
         self._route: dict[str, tuple[_Bucket, int]] = {}
         for bi, (env, members) in enumerate(buckets):
@@ -404,6 +431,14 @@ class ClusteringService:
                             f"weights[{names[i]!r}]: expected shape "
                             f"{(c.p, c.q)}, got {wi.shape}"
                         )
+                    if self.stochastic and not np.array_equal(
+                        wi, np.round(wi)
+                    ):
+                        raise ValueError(
+                            f"weights[{names[i]!r}]: stochastic STDP moves "
+                            "integer counters — initial weights must be "
+                            "integral"
+                        )
                 else:
                     wi = np.asarray(
                         column_lib.init_params(
@@ -411,9 +446,23 @@ class ClusteringService:
                         )["w"]
                     )
                 w0[lane, : c.p, : c.q] = wi
+            keys = None
+            if self.stochastic:
+                keys = jnp.stack([
+                    stdp_lib.stream_key(jax.random.fold_in(stream_root, i))
+                    for i in members
+                ])
             bucket = _Bucket(
                 bi, env, [names[i] for i in members],
-                [cfgs[i] for i in members], jnp.asarray(w0),
+                [cfgs[i] for i in members], jnp.asarray(w0), keys,
+            )
+            # the 'cycle' solver joins a stochastic bucket's ladder as its
+            # last rung: unit steps keep integer counters integral, so it
+            # stays bit-identical to the fused rungs
+            bucket.cycle_exact = self.stochastic and all(
+                backend_lib.cycle_exact(cfgs[i], w0[lane, : cfgs[i].p,
+                                                    : cfgs[i].q])
+                for lane, i in enumerate(members)
             )
             # record which blocking policy this bucket's executables will
             # resolve to (cost-model plan when a calibration is active,
@@ -471,11 +520,14 @@ class ClusteringService:
             store = durability.DurableStore(durable_dir)
             if _attach:
                 step, records = store.attach(fingerprint)
-                blocks, _ = store.ckpt.restore(
-                    [b.w for b in self._buckets], step=step
+                tree, _ = store.ckpt.restore(
+                    self._snapshot_tree(), step=step
                 )
-                for b, wb in zip(self._buckets, blocks):
+                for b, wb in zip(self._buckets, tree):
                     self._commit_weights(b, wb)
+                if self.stochastic:
+                    for b, base in zip(self._buckets, np.asarray(tree[-1])):
+                        b.v_base = int(base)
                 self._store = store
                 self._refit_seq = step
                 for rec in records:
@@ -495,7 +547,7 @@ class ClusteringService:
                             "refit_budget_s": self.refit_budget_s,
                         },
                     },
-                    [b.w for b in self._buckets],
+                    self._snapshot_tree(),
                 )
                 self._store = store
 
@@ -561,13 +613,17 @@ class ClusteringService:
     def _replay(self, rec: dict) -> None:
         """Apply one WAL re-fit record — same ladder, same commit path as
         the live re-fit, no budget (a recovering process pays compiles
-        here) and no re-logging."""
+        here) and no re-logging.  A stochastic record draws under the
+        keys and from the base it carries, and moves the bucket's base
+        past its window."""
         bucket = self._buckets[rec["bucket"]]
         xs = np.asarray(rec["xs"], np.int32)
+        stream = None
+        if self.stochastic:
+            stream = (jnp.asarray(rec["keys"], jnp.int32), int(rec["v_base"]))
+            bucket.v_base = stream[1] + self._stream_advance()
         w_new, _low, errors = self._attempt_window(
-            bucket, xs, ladder=backend_lib.lowering_ladder(
-                bucket.fit_lowering
-            ),
+            bucket, xs, ladder=self._ladder(bucket), stream=stream,
             label="replay", enforce_budget=False,
         )
         if w_new is None:
@@ -583,12 +639,19 @@ class ClusteringService:
         self._refit_seq = int(rec["seq"])
         self._replayed += 1
 
+    def _snapshot_tree(self) -> list:
+        """What a snapshot holds: each bucket's live block, and under
+        stochastic STDP one more leaf, the buckets' next volley bases."""
+        tree = [b.w for b in self._buckets]
+        if self.stochastic:
+            tree.append(np.asarray([b.v_base for b in self._buckets],
+                                   np.int32))
+        return tree
+
     def _snapshot(self) -> None:
         if self._store is None:
             return
-        self._store.snapshot(
-            self._refit_seq, [b.w for b in self._buckets]
-        )
+        self._store.snapshot(self._refit_seq, self._snapshot_tree())
         self._snapshots += 1
 
     def drain(self) -> ServeStats:
@@ -622,6 +685,7 @@ class ClusteringService:
                 "fit_lowering": b.fit_lowering,
                 "last_fit_lowering": b.last_fit_lowering,
                 "asg_lowering": b.asg_lowering,
+                "stream_base": b.v_base if self.stochastic else None,
                 "degraded": b.degraded,
                 "cooldown": b.cooldown,
                 "assign_plan": b.asg_plan.meta() if b.asg_plan else None,
@@ -680,7 +744,8 @@ class ClusteringService:
         all-silent re-fit run end-to-end through the real serving path —
         silent volleys assign to "unclustered" (discarded) and are exact
         weight no-ops, so warmup changes no answers and no weights while
-        exercising the same ops as live traffic.
+        exercising the same ops as live traffic.  Warm-up draws no
+        stream indices: a stochastic bucket's base stays where it was.
         """
         t0 = time.perf_counter()
         hot = 0
@@ -707,6 +772,7 @@ class ClusteringService:
                     stabilize=self._statics["stabilize"],
                     response=self._statics["response"],
                     epochs=self.refit_epochs, lowering=b.fit_lowering,
+                    stochastic=self.stochastic,
                 )
                 self._refit(b, warm=True)  # silent window: exact no-op
         return {
@@ -863,7 +929,12 @@ class ClusteringService:
             response=self._statics["response"],
             lowering=bucket.asg_lowering, w_max=self._statics["w_max"],
         )
-        return np.asarray(ids)  # [Db, B]
+        ids = np.asarray(ids)  # [Db, B]
+        obs.count(
+            ASSIGN_REFERENCE if bucket.asg_lowering == "reference"
+            else ASSIGN_MOSAIC
+        )
+        return ids
 
     def _shed_expired(self, reqs: list[_Request]) -> list[_Request]:
         """Drop deadline-expired requests from a popped batch BEFORE any
@@ -986,28 +1057,57 @@ class ClusteringService:
         return xs
 
     def _fit_window(self, bucket: _Bucket, xs_np: np.ndarray,
-                    lowering: str) -> jnp.ndarray:
+                    lowering: str, stream=None) -> jnp.ndarray:
         """One fused online-STDP pass over a host-side window, on a COPY
         of the live block — ``fit_padded`` donates its weight operand, and
         a failed or discarded attempt must never destroy the last-good
         weights (donation is a memory optimization; the copy is
         value-identical, so commit-on-success keeps the resume contract
-        bit-exact)."""
+        bit-exact).  ``stream`` is a stochastic window's ``(keys [Db, 2],
+        first volley index)``; the 'cycle' rung re-runs it design by
+        design on the solver, drawing the same bits."""
+        if lowering == "cycle":
+            return self._solver_window(bucket, xs_np, *stream)
+        keys, v0 = (None, 0) if stream is None else stream
         w_new = backend_lib.fit_padded(
             jnp.array(bucket.w, copy=True), jnp.asarray(xs_np),
             bucket.thresholds, bucket.t_maxes, bucket.q_actives,
             t_window=bucket.envelope[2],
             epochs=self.refit_epochs, lowering=lowering,
-            **self._statics,
+            keys=keys, v0=v0, **self._statics,
         )
         return jax.block_until_ready(w_new)
 
+    def _solver_window(self, bucket: _Bucket, xs_np: np.ndarray, keys,
+                       v0: int) -> jnp.ndarray:
+        """The 'cycle' rung of a stochastic window: each design's own
+        (p, q) block trained on its lane of the window, from the same
+        stream index; the envelope padding is carried over."""
+        w = np.array(bucket.w)
+        for lane, c in enumerate(bucket.cfgs):
+            w[lane, : c.p, : c.q] = np.asarray(backend_lib.solver_fit_stream(
+                w[lane, : c.p, : c.q], xs_np[:, lane, : c.p], c,
+                epochs=self.refit_epochs, stream_key=keys[lane], v0=v0,
+            ))
+        return jnp.asarray(w)
+
+    def _ladder(self, bucket: _Bucket) -> tuple[str, ...]:
+        return backend_lib.lowering_ladder(
+            bucket.fit_lowering, cycle_exact=bucket.cycle_exact
+        )
+
+    def _stream_advance(self) -> int:
+        """Stream indices one committed re-fit draws: every epoch over the
+        whole padded window."""
+        return self.refit_epochs * self.refit_window
+
     def _attempt_window(self, bucket: _Bucket, xs_np: np.ndarray, *,
-                        ladder, label: str = "refit",
+                        ladder, stream=None, label: str = "refit",
                         enforce_budget: bool = True):
         """Try one re-fit window down ``ladder``; a rung fails on raise,
         non-finite weights, or (with the watchdog budget enforced) a wall
-        time over ``refit_budget_s``.  Returns ``(w_new, lowering,
+        time over ``refit_budget_s``.  Every rung draws from the same
+        ``stream`` (stochastic STDP).  Returns ``(w_new, lowering,
         errors)`` — ``w_new`` is ``None`` when every rung failed."""
         errors: list[str] = []
 
@@ -1022,8 +1122,9 @@ class ClusteringService:
             self.monitor.start(label)
             t0 = time.perf_counter()
             try:
-                with obs.span("serve.fit", lowering=low):
-                    w_new = self._fit_window(bucket, xs_np, low)
+                with obs.span("serve.fit", lowering=low,
+                              rule=self._rule):
+                    w_new = self._fit_window(bucket, xs_np, low, stream)
             except Exception as e:
                 self.monitor.stop()
                 failed(low, repr(e))
@@ -1064,20 +1165,23 @@ class ClusteringService:
         with obs.span("serve.refit", bucket=bucket.index):
             with obs.span("serve.refit_xs"):
                 xs = self._refit_xs(bucket)
+            stream = None
+            if self.stochastic:
+                stream = (bucket.keys, bucket.v_base)
             if warm:
                 # warmup's all-silent window: single rung, no budget (first
-                # dispatch may still be cold), no WAL, no counters
+                # dispatch may still be cold), no WAL, no counters, and no
+                # stream indices drawn (the base stays)
                 w_new, _, _ = self._attempt_window(
                     bucket, xs, ladder=(bucket.fit_lowering,),
-                    enforce_budget=False,
+                    stream=stream, enforce_budget=False,
                 )
                 if w_new is not None:
                     with obs.span("serve.commit"):
                         self._commit_weights(bucket, w_new)
             else:
                 w_new, _low, errors = self._attempt_window(
-                    bucket, xs,
-                    ladder=backend_lib.lowering_ladder(bucket.fit_lowering),
+                    bucket, xs, ladder=self._ladder(bucket), stream=stream,
                 )
                 if w_new is None:
                     # degraded mode: keep serving from last-good weights;
@@ -1092,6 +1196,13 @@ class ClusteringService:
                 else:
                     with obs.span("serve.commit"):
                         self._commit_weights(bucket, w_new)
+                    obs.count(
+                        REFIT_DESIGN_VOLLEYS,
+                        self.refit_epochs
+                        * sum(len(buf) for buf in bucket.buffers),
+                    )
+                    if stream is not None:
+                        bucket.v_base += self._stream_advance()
                     bucket.last_fit_lowering = _low
                     self._refits += 1
                     self._refit_seq += 1
@@ -1106,6 +1217,10 @@ class ClusteringService:
                             self._store.log_refit(
                                 self._refit_seq, bucket.index,
                                 self.refit_epochs, _low, xs,
+                                stream=None if stream is None else {
+                                    "keys": np.asarray(stream[0]).tolist(),
+                                    "v_base": stream[1],
+                                },
                             )
                             if self._refit_seq % self.snapshot_every == 0:
                                 self._snapshot()

@@ -650,5 +650,13 @@ def test_traced_requests_give_the_span_tree(monkeypatch, tmp_path):
         ]
     assert {s.attrs["lowering"] for s in snap.spans
             if s.name == "serve.fit"} == {"interpret"}
-    assert snap.counters == {"serve.rows_live": 10, "serve.rows_slots": 12}
+    assert {s.attrs["rule"] for s in snap.spans
+            if s.name == "serve.fit"} == {"expected"}
+    # float weights keep every batch on the reference assign; each re-fit
+    # counts the live volleys its designs trained on: 3 + 3 (two served
+    # before the window, four in it), then 2 + 2
+    assert snap.counters == {
+        "serve.rows_live": 10, "serve.rows_slots": 12,
+        "serve.assign_reference": 3, "serve.refit_design_volleys": 10,
+    }
 

@@ -9,7 +9,10 @@ jnp reference body and the Pallas kernel under the interpreter), the
 (``bench/reference_stochastic.py``, which draws through JAX's own
 ``threefry2x32_p``) agree bit for bit — alone, bucketed with a larger
 design, sharded, and through ``dse.explore`` with a journal kill and
-resume.
+resume.  The streaming service serves such designs, each re-fit resuming
+its design's stream where the last one stopped, and agrees bit for bit
+with ``bench/reference_stochastic_serve.py``, through a kill and
+``recover`` too.
 """
 import os
 import subprocess
@@ -26,6 +29,7 @@ from repro import dse
 from repro.core import backend, column, simulator, stdp
 from repro.core.types import ColumnConfig, NeuronConfig, STDPConfig, TIME_DTYPE
 from repro.kernels import fused_column
+from repro.serve import durability
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "bench"))
@@ -245,11 +249,250 @@ def test_cycle_rung_reproduces_the_fused_sweep(monkeypatch):
     assert counted.count((simulator.SOLVER_DESIGNS, 1)) == 2
 
 
-def test_service_still_refuses_stochastic_designs():
+# ------------------------------------------------------------ serving
+SERVE_KW = dict(batch_size=4, refit_every=8, refit_window=6, seed=5)
+
+
+def _fleet():
+    """Three stochastic designs in two envelope buckets."""
+    return {"a": _cfg(12, 3), "b": _cfg(12, 4), "c": _cfg(40, 5)}
+
+
+def _counters(designs, seed=0):
+    rng = np.random.default_rng(seed)
+    return {n: rng.integers(0, 8, (c.p, c.q)).astype(np.float32)
+            for n, c in designs.items()}
+
+
+def _traffic(designs, n, seed=1):
+    """``n`` requests (design, series) and a flush after every fifth."""
+    rng = np.random.default_rng(seed)
+    streams = {d: rng.normal(size=(24, c.p)) for d, c in designs.items()}
+    names = list(designs)
+    events = []
+    for k in range(n):
+        d = names[int(rng.integers(len(names)))]
+        events.append((d, int(rng.integers(24))))
+        if k % 5 == 4:
+            events.append(None)
+    return streams, events
+
+
+def _base(svc, name):
+    """The next volley base of the bucket that serves ``name``."""
+    return next(b["stream_base"] for b in svc.buckets() if name in b["designs"])
+
+
+def _serve(svc, streams, events):
+    handles = []
+    for ev in events:
+        if ev is None:
+            svc.flush()
+        else:
+            handles.append(svc.submit(streams[ev[0]][ev[1]], ev[0]))
+    svc.flush()
+    return [h.result().cluster for h in handles]
+
+
+def test_service_equals_the_reference_replay_over_refits_and_buckets():
+    """Answers equal and counters bit-identical to the plain reference of
+    the service (``bench/reference_stochastic_serve.py``) over several
+    re-fits of two buckets, each re-fit resuming its design's stream."""
+    import reference_stochastic_serve
     from repro.serve import ClusteringService
 
-    with pytest.raises(ValueError, match="expected-mode STDP only"):
-        ClusteringService({"a": _cfg(24, 3)})
+    designs = _fleet()
+    w0 = _counters(designs)
+    streams, events = _traffic(designs, 60)
+    svc = ClusteringService(designs, weights=w0, **SERVE_KW)
+    assert len(svc.buckets()) == 2
+    svc.warmup()
+    assert all(_base(svc, n) == 0 for n in designs)  # warm-up draws nothing
+    got = _serve(svc, streams, events)
+    assert svc.stats().refits >= 4
+
+    encodes = {n: reference.encode(streams[n], c.t_max) for n, c in designs.items()}
+    sim = reference_stochastic_serve.ServiceReplay(
+        {n: (c.p, c.q, c.t_max, c.neuron.threshold) for n, c in designs.items()},
+        w0, encodes, seed=SERVE_KW["seed"], batch_size=4, refit_every=8,
+        refit_window=6, refit_epochs=1, statics=_statics(designs["a"]),
+    )
+    for ev in events:
+        sim.flush() if ev is None else sim.submit(*ev)
+    sim.flush()
+    want = []
+    for name, series, version in sim.requests:
+        c = designs[name]
+        want.append(int(reference.assign(
+            sim.versions[name][version], encodes[name][series][None],
+            jnp.float32(c.neuron.threshold), t_max=c.t_max, dtype=jnp.float32)[0]))
+    assert got == want
+    for n in designs:
+        assert len(sim.versions[n]) > 2, "every design re-fits more than once"
+        np.testing.assert_array_equal(svc.weights(n), np.asarray(sim.versions[n][-1]))
+        assert _base(svc, n) == sim.bases[n]
+
+
+@pytest.mark.parametrize("lowering", ["reference", "interpret"])
+@pytest.mark.parametrize("v0", [0, 37, 2**31 - 64])
+def test_fit_padded_from_a_stream_index_equals_the_solver_and_reference(lowering, v0):
+    """``fit_padded(v0=k)`` draws volley n of epoch e at index k + e N + n,
+    as the 'cycle' solver and the reference do; two chained fits equal
+    one over the joined volleys."""
+    import reference_stochastic_serve
+
+    cfg = _cfg(24, 3)
+    xs = _volleys(24, 4)
+    w0 = np.asarray(column.init_params(jax.random.key(6), cfg)["w"])
+    key = stdp.stream_key(jax.random.key(7))
+    ops = (jnp.asarray([cfg.neuron.threshold], jnp.float32),
+           jnp.asarray([T_MAX], TIME_DTYPE), jnp.asarray([3], TIME_DTYPE))
+    kw = dict(t_window=T_MAX, w_max=7, wta_k=1, mu_capture=0.5, mu_backoff=0.5,
+              mu_search=2.0 ** -10, stabilize=True, response="rnl", lowering=lowering)
+
+    def fused(w, x, v, epochs):
+        return np.asarray(backend.fit_padded(
+            jnp.array(w)[None], x[:, None, :], *ops, epochs=epochs, keys=key[None],
+            v0=v, **kw)[0])
+
+    got = fused(w0, xs, v0, EPOCHS)
+    cyc = np.asarray(backend.solver_fit_stream(
+        w0, xs, cfg, epochs=EPOCHS, stream_key=key, v0=v0))
+    ref = np.asarray(reference_stochastic_serve.fit(
+        jnp.asarray(w0), xs, jnp.float32(cfg.neuron.threshold),
+        jnp.asarray(key).view(jnp.uint32), jnp.uint32(v0), t_max=T_MAX, epochs=EPOCHS,
+        statics=_statics(cfg), dtype=jnp.float32))
+    assert np.abs(got - w0).sum() > 0, "training must move"
+    np.testing.assert_array_equal(got, cyc)
+    np.testing.assert_array_equal(got, ref)
+    # another start draws other bits
+    assert not np.array_equal(fused(w0, xs, v0 + 1, EPOCHS), got)
+    # two re-fits, the second resuming where the first stopped
+    half = N // 2
+    chained = fused(fused(w0, xs[:half], v0, 1), xs[half:], v0 + half, 1)
+    np.testing.assert_array_equal(chained, fused(w0, xs, v0, 1))
+
+
+def _durable(designs, w0, path, **kw):
+    from repro.serve import ClusteringService
+
+    return ClusteringService(designs, weights=w0, durable_dir=str(path),
+                             snapshot_every=2, **dict(SERVE_KW, **kw))
+
+
+def test_recover_restores_counters_and_stream_bases_bit_identical(tmp_path):
+    """Killed after two snapshots and a WAL tail, the recovered service
+    holds the live service's counters and bases, and goes on serving the
+    bucket that had just re-fit as the live service does."""
+    from repro.serve import ClusteringService
+
+    designs = _fleet()
+    w0 = _counters(designs)
+    streams, events = _traffic(designs, 90)
+    live = _durable(designs, w0, tmp_path / "live")
+    live.warmup()
+    _serve(live, streams, events)
+    # serve bucket {a, b} on until its re-fit is the WAL's newest record:
+    # it then holds no served-but-unrefit volleys, which a kill would lose
+    base = _base(live, "a")
+    for k in range(80):
+        if live.stats().wal_records and _base(live, "a") != base:
+            break
+        base = _base(live, "a")
+        live.submit(streams["a"][k % 24], "a")
+    st = live.stats()
+    assert st.snapshots >= 2 and st.wal_records >= 1, st
+    recs = durability.VolleyWAL(str(tmp_path / "live" / "wal.jsonl")).validate(
+        live._store.fingerprint)
+    assert all({"keys", "v_base"} <= set(r) for r in recs)
+    # the process dies here: nothing is drained or flushed
+    back = ClusteringService.recover(str(tmp_path / "live"))
+    assert back.stats().replayed == st.wal_records
+    for n in designs:
+        np.testing.assert_array_equal(back.weights(n), live.weights(n))
+        assert _base(back, n) == _base(live, n) > 0
+    back.warmup()
+    more_streams, more = _traffic({n: designs[n] for n in "ab"}, 30, seed=2)
+    assert _serve(back, more_streams, more) == _serve(live, more_streams, more)
+    for n in designs:
+        np.testing.assert_array_equal(back.weights(n), live.weights(n))
+        assert _base(back, n) == _base(live, n)
+
+
+def test_a_degraded_refit_leaves_the_stream_base_unchanged(monkeypatch):
+    """Every rung down: the bucket serves from its last counters and its
+    base stays.  Fused rungs down alone: the 'cycle' rung commits the
+    counters and base the unfaulted service reaches."""
+    from repro.serve import ClusteringService
+    from repro.testing import faults
+
+    designs = {"a": _cfg(12, 3), "b": _cfg(12, 4)}
+    w0 = _counters(designs)
+    streams, events = _traffic(designs, 12)
+    clean = ClusteringService(designs, weights=w0, **SERVE_KW)
+    want = _serve(clean, streams, events)
+    assert clean.stats().refits == 1
+
+    monkeypatch.setattr(fused_column, "fit_scan_padded",
+                        faults.fail_always(fused_column.fit_scan_padded))
+    fused_down = ClusteringService(designs, weights=w0, **SERVE_KW)
+    assert fused_down.buckets()[0]["fit_lowering"] == "reference"
+    with pytest.warns(RuntimeWarning):
+        assert _serve(fused_down, streams, events) == want
+    assert fused_down.buckets()[0]["last_fit_lowering"] == "cycle"
+    for n in designs:
+        np.testing.assert_array_equal(fused_down.weights(n), clean.weights(n))
+        assert _base(fused_down, n) == _base(clean, n) == 6
+
+    monkeypatch.setattr(backend, "solver_fit_stream",
+                        faults.fail_always(backend.solver_fit_stream))
+    down = ClusteringService(designs, weights=w0, **SERVE_KW)
+    with pytest.warns(RuntimeWarning):
+        _serve(down, streams, events)
+    assert down.stats().refit_failures == 1 and down.stats().degraded == 1
+    for n in designs:
+        np.testing.assert_array_equal(down.weights(n), w0[n])
+        assert _base(down, n) == 0
+
+
+def test_expected_mode_keeps_its_aot_key_and_results():
+    """An expected-mode fit keeps the key it had before the stream offset
+    (no stochastic operands), its result is the jit path's, a stream
+    offset without stream keys is refused, and the service keeps no
+    stream state for it."""
+    from repro.serve import ClusteringService
+
+    cfg = ColumnConfig(p=24, q=3, t_max=T_MAX)
+    cfg = cfg.with_threshold(simulator.suggest_threshold(cfg))
+    xs = _volleys(24, 5)
+    w0 = np.asarray(column.init_params(jax.random.key(8), cfg)["w"])
+    ops = (jnp.asarray([cfg.neuron.threshold], jnp.float32),
+           jnp.asarray([T_MAX], TIME_DTYPE), jnp.asarray([3], TIME_DTYPE))
+    kw = dict(t_window=T_MAX, w_max=7, wta_k=1, mu_capture=0.5, mu_backoff=0.5,
+              mu_search=2.0 ** -10, stabilize=True, response="rnl", epochs=EPOCHS,
+              lowering="reference")
+    got = backend.fit_padded(jnp.array(w0)[None], xs[:, None, :], *ops, **kw)
+    v_blk, t_blk = backend._plan_blocks("fit", "reference", 1, 24, 3, T_MAX, N, EPOCHS,
+                                        7, "rnl", None, None)
+    key = ("fit", (1, 24, 3), (N, 1, 24), T_MAX, 7, 1, True, "rnl", EPOCHS,
+           "reference", t_blk, v_blk)
+    assert backend._fit_key((1, 24, 3), (N, 1, 24), T_MAX, 7, 1, True, "rnl", EPOCHS,
+                            "reference", t_blk, v_blk) == key
+    assert key in backend._AOT_CACHE
+    jit = fused_column.fit_scan_padded(jnp.array(w0)[None], xs[:, None, :], *ops, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(jit))
+    with pytest.raises(ValueError, match="stochastic STDP only"):
+        backend.fit_padded(jnp.array(w0)[None], xs[:, None, :], *ops, v0=3, **kw)
+    svc = ClusteringService({"e": cfg}, batch_size=4, refit_every=8, refit_window=6)
+    assert svc.buckets()[0]["stream_base"] is None
+
+
+def test_service_refuses_fractional_counters_for_stochastic_designs():
+    from repro.serve import ClusteringService
+
+    cfg = _cfg(24, 3)
+    with pytest.raises(ValueError, match="integral"):
+        ClusteringService({"a": cfg}, weights={"a": np.full((24, 3), 2.5, np.float32)})
 
 
 # ------------------------------------------------------------ exploration

@@ -100,6 +100,22 @@ def test_stochastic_fit_scan_padded_compiles_for_v5e(spec):
     _assert_mosaic(compiled)
 
 
+def test_resumed_stochastic_fit_compiles_for_v5e(spec):
+    """The streaming service's re-fit: the stochastic envelope resumed at
+    a runtime stream index ``v0`` over a 32-volley window."""
+    mu = spec((), F32)
+    d, n = 3, 32
+    compiled = fused_column.fit_scan_padded.lower(
+        spec((d, P_PAD, Q_PAD), F32), spec((n, d, P_PAD), TIME_DTYPE),
+        spec((d,), F32), spec((d,), TIME_DTYPE), spec((d,), TIME_DTYPE),
+        t_window=T_WINDOW, w_max=7, wta_k=1, mu_capture=mu, mu_backoff=mu,
+        mu_search=mu, stabilize=True, response="rnl", epochs=1,
+        lowering="mosaic", t_blk=128, v_blk=32, stochastic=True,
+        keys=spec((d, 2), jnp.int32), v0=spec((), jnp.int32),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 def test_assign_padded_compiles_for_v5e(spec):
     compiled = fused_column.assign_padded.lower(
         *_padded_operands(spec), t_window=T_WINDOW, wta_k=1,
