@@ -59,7 +59,8 @@ class ClusteringResult:
     # downstream consumers (forecaster features, examples, DSE) can rely
     # on it: single-column front-ends (``cluster_time_series`` and each
     # sweep member of ``cluster_time_series_many``) return ``{'w': [p, q]}``
-    # cropped to the design's true size; the network front-end returns
+    # cropped to the design's true size (a sweep member's is a host
+    # float32 ``np.ndarray``); the network front-end returns
     # ``{'layers': [{'w': [columns, p, q]}, ...]}``.
     params: dict
     train_seconds: float
@@ -269,15 +270,18 @@ def assign_time_series(
 # body, counted per bucket where the lowering is chosen, and designs the
 # degradation ladder evaluated on the 'cycle' solver rung; per sweep, the
 # designs the stream was encoded for and the encodes computed (one per
-# distinct t_max).
+# distinct t_max); per bucket, the designs whose trained weights it
+# returned and the device-to-host fetches of its weight block (one).
 ASSIGN_MOSAIC = "sim.assign_mosaic"
 ASSIGN_REFERENCE = "sim.assign_reference"
 SOLVER_DESIGNS = "sim.solver_designs"
 ENCODE_DESIGNS = "sim.encode_designs"
 ENCODE_RUNS = "sim.encode_runs"
+WEIGHT_DESIGNS = "sim.weight_designs"
+WEIGHT_FETCHES = "sim.weight_fetches"
 SWEEP_COUNTERS = (
     ASSIGN_MOSAIC, ASSIGN_REFERENCE, SOLVER_DESIGNS, ENCODE_DESIGNS,
-    ENCODE_RUNS,
+    ENCODE_RUNS, WEIGHT_DESIGNS, WEIGHT_FETCHES,
 )
 
 
@@ -305,7 +309,7 @@ def _sweep_bucket(
     epochs: int,
     lowering: str,
     stream_keys: Optional[Sequence] = None,
-) -> tuple[np.ndarray, list[jnp.ndarray], int, dict]:
+) -> tuple[np.ndarray, list[np.ndarray], int, dict]:
     """Train + assign one envelope bucket of a design sweep.
 
     Pads the bucket's members into its shared (p_env, q_env, t_window)
@@ -329,8 +333,12 @@ def _sweep_bucket(
     ``stream_keys`` (stochastic STDP: one [2] i32 stream key per design
     of the sweep, None otherwise) ride the design axis like ``w_init``.
 
-    Returns (assignments [Db, N], cropped per-design weights, shard
-    count, plan metadata dict).
+    The assignments and the whole trained weight block leave the device
+    in one transfer; each design's weights are cropped from the block on
+    the host.
+
+    Returns (assignments [Db, N], cropped per-design host weights
+    [p, q] float32, shard count, plan metadata dict).
     """
     c0 = cfgs[idxs[0]]
     p_env, q_env, t_window = envelope
@@ -450,20 +458,21 @@ def _sweep_bucket(
         )
         with obs.span("sim.assign"):
             if mesh is None:
-                asg = np.asarray(
-                    backend_lib.assign_padded(
-                        w, xs, thresholds, t_maxes, q_actives, **asg_kw
-                    )
+                asg = backend_lib.assign_padded(
+                    w, xs, thresholds, t_maxes, q_actives, **asg_kw
                 )
             else:
-                asg = np.asarray(
-                    backend_lib.shard_designs(
-                        mesh, fused_column.assign_padded, (0, 1, 0, 0, 0),
-                        **asg_kw,
-                    )(w, xs, thresholds, t_maxes, q_actives)
-                )
+                asg = backend_lib.shard_designs(
+                    mesh, fused_column.assign_padded, (0, 1, 0, 0, 0),
+                    **asg_kw,
+                )(w, xs, thresholds, t_maxes, q_actives)
+            # one transfer for the bucket: device_get assembles a
+            # design-sharded block from its shards
+            asg, w_host = jax.device_get((asg, w))
+            obs.count(WEIGHT_DESIGNS, db)
+            obs.count(WEIGHT_FETCHES)
         w_out = [
-            jnp.asarray(w[j, : cfgs[i].p, : cfgs[i].q])
+            np.ascontiguousarray(w_host[j, : cfgs[i].p, : cfgs[i].q])
             for j, i in enumerate(idxs)
         ]
         return asg, w_out, shards, fit_plan.meta()
@@ -472,7 +481,7 @@ def _sweep_bucket(
 def _eval_design_solver(
     cfg: ColumnConfig, volleys: jnp.ndarray, w0: np.ndarray, epochs: int,
     stream_key=None,
-) -> tuple[np.ndarray, jnp.ndarray]:
+) -> tuple[np.ndarray, np.ndarray]:
     """Bottom-rung ('cycle') evaluation of ONE design on the solver scan.
 
     Only reached when ``backend.cycle_exact`` holds for the design, i.e.
@@ -495,7 +504,7 @@ def _eval_design_solver(
     asg = np.asarray(
         column_lib.cluster_assignments(params, volleys, cfg, "cycle")
     )
-    return asg, jnp.asarray(params["w"])
+    return asg, np.asarray(params["w"])
 
 
 def _design_guard(
